@@ -19,6 +19,7 @@ import sys
 import time
 
 from repro import benchlib
+from repro.compile_cache import enable_compile_cache
 
 from benchmarks import (bench_clusterwise, bench_kernels, bench_memory,
                         bench_obs, bench_overhead, bench_planner,
@@ -55,6 +56,7 @@ def main() -> None:
     ap.add_argument("--no-artifact", action="store_true",
                     help="skip writing the BENCH_<tier>_<sha>.json artifact")
     args = ap.parse_args()
+    enable_compile_cache()
 
     keys = list(TABLES) if not args.only else args.only.split(",")
     benchlib.load_cache()

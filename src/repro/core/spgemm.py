@@ -167,6 +167,14 @@ def spgemm_clusterwise_dense(a: CSRCluster, b: CSR,
 # ---------------------------------------------------------------------------
 
 
+# most scatter updates (slots × width) one bin pass may issue. A pass
+# materializes its (slots·width, cluster) update and (slots·width, 2)
+# index arrays, which the TPU lays out with their minor dimension padded
+# to 128 lanes: at this bound they take ~1 GiB of HBM, where one pass of
+# a Graph500 scale-14 hub bucket (2^24 updates) asked for 17.6 GB.
+MAX_PASS_UPDATES = 1 << 20
+
+
 def length_bins(fetch_lens: np.ndarray, *, floor: int = 8,
                 pad_sentinel: int | None = None
                 ) -> list[tuple[np.ndarray, int]]:
@@ -176,7 +184,8 @@ def length_bins(fetch_lens: np.ndarray, *, floor: int = 8,
     Returns [(slot_ids, width)] with slot_ids padded to a pow2 length using
     ``pad_sentinel`` (default: len(fetch_lens), i.e. one past the last slot
     — the kernels mask slots >= their cap). Zero-length fetches appear in
-    no bin.
+    no bin. A bucket with more than :data:`MAX_PASS_UPDATES` slot × width
+    updates is split, in slot order, into several bins of the same width.
     """
     fetch_lens = np.asarray(fetch_lens, dtype=np.int64)
     sentinel = (int(fetch_lens.shape[0]) if pad_sentinel is None
@@ -189,10 +198,14 @@ def length_bins(fetch_lens: np.ndarray, *, floor: int = 8,
     bins: list[tuple[np.ndarray, int]] = []
     for w in np.unique(buckets):
         slots = live[buckets == w]
-        cap = max(8, 1 << (int(slots.size) - 1).bit_length())
-        padded = np.full(cap, sentinel, dtype=np.int32)
-        padded[: slots.size] = slots
-        bins.append((padded, int(w)))
+        per = max(8, 1 << (max(MAX_PASS_UPDATES // int(w), 1)
+                           .bit_length() - 1))
+        for lo in range(0, slots.size, per):
+            part = slots[lo:lo + per]
+            cap = max(8, 1 << (int(part.size) - 1).bit_length())
+            padded = np.full(cap, sentinel, dtype=np.int32)
+            padded[: part.size] = part
+            bins.append((padded, int(w)))
     return bins
 
 

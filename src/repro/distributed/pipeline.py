@@ -102,13 +102,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array, *,
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
     in_specs = (pspec, P())           # x replicated; params pipe-sharded
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=P(), check_vma=False)
-    else:                             # jax < 0.5: experimental + check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(), check_vma=False)
     return mapped(stage_params, x)
 
 

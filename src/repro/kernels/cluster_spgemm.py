@@ -103,6 +103,15 @@ price is a wider C output window — ``window_blocks`` consecutive block
 strips, zero-initialized on window entry — and the loss of A-slab
 adjacency (A refetches rise; the ``live_pair_counters`` report both
 sides of that trade, and ``bench_kernels`` gates the B-refetch win).
+
+Chunked launches
+----------------
+
+A scalar-prefetched stream lives whole in SMEM (1 MiB on a v5e), so every
+pair-stream kernel runs its stream as launches of at most ``chunk`` steps
+(:mod:`repro.kernels.chunked`), bit-identical to one launch. The padded
+``(nnb, S)`` grid is not chunked: it prefetches B's whole tile table, and
+the ops layer refuses it when that does not fit.
 """
 from __future__ import annotations
 
@@ -114,13 +123,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-# jax < 0.5 spells the any-space constant via the TPUMemorySpace enum
-_ANY = getattr(pltpu, "ANY", None)
-if _ANY is None:                                      # pragma: no cover
-    _ANY = pltpu.TPUMemorySpace.ANY
+from repro.kernels.chunked import open_window, run_in_chunks
+
+# fp32 contractions at full fp32 precision: at the MXU's default precision
+# for fp32 operands, a Graph500 scale-14 A·A on a TPU v5e came back off
+# the fp32 product by up to 3.4e-4 of max|C|
+_FP32 = jax.lax.Precision.HIGHEST
 
 __all__ = ["cluster_spgemm_tiled", "cluster_spgemm_resident",
            "cluster_spgemm_pairs", "cluster_spgemm_pairs_resident",
@@ -152,8 +160,9 @@ def _spgemm_kernel_streamed(nnb, block_ids_ref, tile_ids_ref, table_ref,
 
     @pl.when(slot > 0)                     # dead B tile: no MXU issue
     def _acc():
-        o_ref[...] += jnp.dot(a_ref[0], b_ref[0],
-                              preferred_element_type=jnp.float32
+        o_ref[...] += jnp.dot(a_ref[0], b_ref[0].astype(jnp.float32),
+                              preferred_element_type=jnp.float32,
+                              precision=_FP32
                               ).astype(o_ref.dtype)
 
 
@@ -201,7 +210,7 @@ def cluster_spgemm_tiled(block_ids: jax.Array, tile_ids: jax.Array,
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((nblocks * block_r, nnb * bn),
                                        b_tiles.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_ids, tile_ids, table, a_values, b_tiles)
@@ -225,8 +234,9 @@ def _spgemm_kernel_resident(nnb, block_ids_ref, tile_ids_ref, table_ref,
 
     @pl.when(slot > 0)
     def _acc():
-        o_ref[...] += jnp.dot(a_ref[0], b_ref[slot],
-                              preferred_element_type=jnp.float32
+        o_ref[...] += jnp.dot(a_ref[0], b_ref[slot].astype(jnp.float32),
+                              preferred_element_type=jnp.float32,
+                              precision=_FP32
                               ).astype(o_ref.dtype)
 
 
@@ -263,7 +273,7 @@ def cluster_spgemm_resident(block_ids: jax.Array, tile_ids: jax.Array,
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((nblocks * block_r, nnb * bn),
                                        b_tiles.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_ids, tile_ids, table, a_values, b_tiles)
@@ -274,21 +284,61 @@ def cluster_spgemm_resident(block_ids: jax.Array, tile_ids: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _rows(c_hbm, rows):
+    """Window view of the aliased C for a window key: ``rows`` rows from
+    row ``key * rows``."""
+    return lambda key: c_hbm.at[pl.ds(pl.multiple_of(key * rows, rows),
+                                      rows)]
+
+
+def _stream_call(kernel, streams, a_values, b_tiles, *, slot_pos, chunk,
+                 in_specs, out_spec, out_shape, scratch=(), interpret):
+    """Run a 1-D pair-stream kernel over ``streams`` in chunk launches
+    (:func:`repro.kernels.chunked.run_in_chunks`). Each launch
+    scalar-prefetches ``(meta, *chunk streams)``, reads A and B through
+    ``in_specs`` and writes the fp32 ``out_shape`` output, which it takes
+    in again — unblocked, for :func:`repro.kernels.chunked.open_window` —
+    and aliases to its result. The kernel's last scratch operand is the
+    window-carry DMA semaphore."""
+    npre = 1 + len(streams)
+
+    def launch(meta, *args):
+        *chunks, c = args
+        spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=npre,
+            grid=(chunks[0].shape[0],),
+            in_specs=[*in_specs, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=out_spec,
+            scratch_shapes=[*scratch, pltpu.SemaphoreType.DMA((1,))],
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=spec,
+            out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
+            input_output_aliases={npre + 2: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(meta, *chunks, a_values, b_tiles, c)
+
+    return run_in_chunks(launch, streams, jnp.zeros(out_shape, jnp.float32),
+                         slot_pos=slot_pos, chunk=chunk)
+
+
 def _mxu_acc(a_slab, b_tile, o_ref, col, bn):
     """One contraction into the output row strip, fp32 accumulate; bf16 B
     tiles are upcast at the MXU input (their bytes were saved in HBM)."""
     prod = jnp.dot(a_slab, b_tile.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32,
+                   precision=_FP32)
     o_ref[:, pl.ds(col, bn)] += prod.astype(o_ref.dtype)
 
 
-def _spgemm_kernel_pairs(bn, blk_ref, j_ref, slot_ref, aidx_ref,
-                         a_ref, b_ref, o_ref):
+def _spgemm_kernel_pairs(bn, block_r, meta_ref, blk_ref, j_ref, slot_ref,
+                         aidx_ref, a_ref, b_ref, c_hbm, o_ref, sem):
     t = pl.program_id(0)
-
-    @pl.when(_is_block_start(blk_ref, t))
-    def _init():                     # one zero-fill per block: every
-        o_ref[...] = jnp.zeros_like(o_ref)   # (block, j) strip, dead or live
+    # one zero-fill per block: every (block, j) strip, dead or live
+    open_window(t, blk_ref, meta_ref, o_ref, _rows(c_hbm, block_r), sem)
 
     @pl.when(slot_ref[t] > 0)        # sentinels / tail pads: no MXU issue
     def _acc():
@@ -296,12 +346,23 @@ def _spgemm_kernel_pairs(bn, blk_ref, j_ref, slot_ref, aidx_ref,
         _mxu_acc(a_ref[0], b_ref[0], o_ref, col, bn)
 
 
+def _pair_specs(block_r, block_k, bn, nnb, b_spec):
+    """A-slab spec, the given B spec, and the block's C row-strip spec of
+    the (meta, blocks, js, slots, a_idx) prefetch layout."""
+    a_spec = pl.BlockSpec((1, block_r, block_k),
+                          lambda t, m, blks, js_, sl, ai: (ai[t], 0, 0))
+    out_spec = pl.BlockSpec((block_r, nnb * bn),
+                            lambda t, m, blks, js_, sl, ai: (blks[t], 0))
+    return [a_spec, b_spec], out_spec
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "block_r", "block_k", "bn", "nblocks", "nnb", "interpret"))
+    "block_r", "block_k", "bn", "nblocks", "nnb", "chunk", "interpret"))
 def cluster_spgemm_pairs(blocks: jax.Array, js: jax.Array, slots: jax.Array,
                          a_idx: jax.Array, a_values: jax.Array,
                          b_tiles: jax.Array, *, block_r: int, block_k: int,
                          bn: int, nblocks: int, nnb: int,
+                         chunk: int | None = None,
                          interpret: bool = False) -> jax.Array:
     """C = A_bcc @ B_tiled over the live-pair compacted grid, streaming
     one B tile per live contraction.
@@ -315,43 +376,30 @@ def cluster_spgemm_pairs(blocks: jax.Array, js: jax.Array, slots: jax.Array,
         stream's slab array; ``a_idx`` indexes it).
       b_tiles: (tile_cap, block_k, bn) fp32 or bf16 dense live tiles;
         slab 0 is the reserved zero tile.
+      chunk: most pairs per launch — the stream is scalar-prefetched into
+        SMEM, so long streams run as several launches
+        (:mod:`repro.kernels.chunked`); ``None`` is one launch.
 
     Returns: (nblocks * block_r, nnb * bn) dense fp32 C.
     """
-    t_total = blocks.shape[0]
     assert a_values.shape[1:] == (block_r, block_k)
     assert b_tiles.shape[1:] == (block_k, bn)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(t_total,),
-        in_specs=[
-            pl.BlockSpec((1, block_r, block_k),
-                         lambda t, blks, js_, sl, ai: (ai[t], 0, 0)),
-            pl.BlockSpec((1, block_k, bn),
-                         lambda t, blks, js_, sl, ai: (sl[t], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_r, nnb * bn),
-                               lambda t, blks, js_, sl, ai: (blks[t], 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_spgemm_kernel_pairs, bn),
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((nblocks * block_r, nnb * bn),
-                                       jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(blocks, js, slots, a_idx, a_values, b_tiles)
+    in_specs, out_spec = _pair_specs(
+        block_r, block_k, bn, nnb,
+        pl.BlockSpec((1, block_k, bn),
+                     lambda t, m, blks, js_, sl, ai: (sl[t], 0, 0)))
+    return _stream_call(
+        functools.partial(_spgemm_kernel_pairs, bn, block_r),
+        (blocks, js, slots, a_idx), a_values, b_tiles, slot_pos=2,
+        chunk=chunk, in_specs=in_specs, out_spec=out_spec,
+        out_shape=(nblocks * block_r, nnb * bn), interpret=interpret)
 
 
-def _spgemm_kernel_pairs_resident(bn, blk_ref, j_ref, slot_ref, aidx_ref,
-                                  a_ref, b_ref, o_ref):
+def _spgemm_kernel_pairs_resident(bn, block_r, meta_ref, blk_ref, j_ref,
+                                  slot_ref, aidx_ref, a_ref, b_ref, c_hbm,
+                                  o_ref, sem):
     t = pl.program_id(0)
-
-    @pl.when(_is_block_start(blk_ref, t))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
+    open_window(t, blk_ref, meta_ref, o_ref, _rows(c_hbm, block_r), sem)
     slot = slot_ref[t]
 
     @pl.when(slot > 0)
@@ -361,45 +409,33 @@ def _spgemm_kernel_pairs_resident(bn, blk_ref, j_ref, slot_ref, aidx_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_r", "block_k", "bn", "nblocks", "nnb", "interpret"))
+    "block_r", "block_k", "bn", "nblocks", "nnb", "chunk", "interpret"))
 def cluster_spgemm_pairs_resident(blocks: jax.Array, js: jax.Array,
                                   slots: jax.Array, a_idx: jax.Array,
                                   a_values: jax.Array, b_tiles: jax.Array,
                                   *, block_r: int, block_k: int, bn: int,
                                   nblocks: int, nnb: int,
+                                  chunk: int | None = None,
                                   interpret: bool = False) -> jax.Array:
     """Same contract as :func:`cluster_spgemm_pairs`, with the whole B
-    tile store pinned in VMEM (one HBM fetch total)."""
-    t_total = blocks.shape[0]
+    tile store pinned in VMEM (one HBM fetch per launch)."""
     assert a_values.shape[1:] == (block_r, block_k)
     assert b_tiles.shape[1:] == (block_k, bn)
     tile_cap = b_tiles.shape[0]
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(t_total,),
-        in_specs=[
-            pl.BlockSpec((1, block_r, block_k),
-                         lambda t, blks, js_, sl, ai: (ai[t], 0, 0)),
-            pl.BlockSpec((tile_cap, block_k, bn),
-                         lambda t, blks, js_, sl, ai: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_r, nnb * bn),
-                               lambda t, blks, js_, sl, ai: (blks[t], 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_spgemm_kernel_pairs_resident, bn),
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((nblocks * block_r, nnb * bn),
-                                       jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(blocks, js, slots, a_idx, a_values, b_tiles)
+    in_specs, out_spec = _pair_specs(
+        block_r, block_k, bn, nnb,
+        pl.BlockSpec((tile_cap, block_k, bn),
+                     lambda t, m, blks, js_, sl, ai: (0, 0, 0)))
+    return _stream_call(
+        functools.partial(_spgemm_kernel_pairs_resident, bn, block_r),
+        (blocks, js, slots, a_idx), a_values, b_tiles, slot_pos=2,
+        chunk=chunk, in_specs=in_specs, out_spec=out_spec,
+        out_shape=(nblocks * block_r, nnb * bn), interpret=interpret)
 
 
-def _spgemm_kernel_pairs_db(bn, blk_ref, j_ref, slot_ref, aidx_ref,
-                            a_ref, b_hbm, o_ref, b_buf, sem):
-    t = pl.program_id(0)
+def _prefetch_tiles(t, slot_ref, b_hbm, b_buf, sem):
+    """Two-slot B tile pipeline: the tile of step t+1 is in flight while
+    step t contracts. Returns step t's tile, waited on."""
     nt = pl.num_programs(0)
 
     def _tile_dma(pos, buf):
@@ -415,24 +451,31 @@ def _spgemm_kernel_pairs_db(bn, blk_ref, j_ref, slot_ref, aidx_ref,
         _tile_dma(t + 1, (t + 1) % 2).start()
 
     _tile_dma(t, t % 2).wait()
+    return b_buf[t % 2]
 
-    @pl.when(_is_block_start(blk_ref, t))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+
+def _spgemm_kernel_pairs_db(bn, block_r, meta_ref, blk_ref, j_ref, slot_ref,
+                            aidx_ref, a_ref, b_hbm, c_hbm, o_ref, b_buf,
+                            sem, carry_sem):
+    t = pl.program_id(0)
+    tile = _prefetch_tiles(t, slot_ref, b_hbm, b_buf, sem)
+    open_window(t, blk_ref, meta_ref, o_ref, _rows(c_hbm, block_r),
+                carry_sem)
 
     @pl.when(slot_ref[t] > 0)
     def _acc():
         col = pl.multiple_of(j_ref[t] * bn, bn)
-        _mxu_acc(a_ref[0], b_buf[t % 2], o_ref, col, bn)
+        _mxu_acc(a_ref[0], tile, o_ref, col, bn)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_r", "block_k", "bn", "nblocks", "nnb", "interpret"))
+    "block_r", "block_k", "bn", "nblocks", "nnb", "chunk", "interpret"))
 def cluster_spgemm_pairs_db(blocks: jax.Array, js: jax.Array,
                             slots: jax.Array, a_idx: jax.Array,
                             a_values: jax.Array, b_tiles: jax.Array,
                             *, block_r: int, block_k: int, bn: int,
                             nblocks: int, nnb: int,
+                            chunk: int | None = None,
                             interpret: bool = False) -> jax.Array:
     """Streamed variant with manual double-buffered tile prefetch: B stays
     in HBM (``ANY`` space) and each grid step DMAs the *next* step's tile
@@ -441,33 +484,18 @@ def cluster_spgemm_pairs_db(blocks: jax.Array, js: jax.Array,
     streamed variant serializes. Same contract as
     :func:`cluster_spgemm_pairs`.
     """
-    t_total = blocks.shape[0]
     assert a_values.shape[1:] == (block_r, block_k)
     assert b_tiles.shape[1:] == (block_k, bn)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(t_total,),
-        in_specs=[
-            pl.BlockSpec((1, block_r, block_k),
-                         lambda t, blks, js_, sl, ai: (ai[t], 0, 0)),
-            pl.BlockSpec(memory_space=_ANY),
-        ],
-        out_specs=pl.BlockSpec((block_r, nnb * bn),
-                               lambda t, blks, js_, sl, ai: (blks[t], 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, block_k, bn), b_tiles.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_spgemm_kernel_pairs_db, bn),
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((nblocks * block_r, nnb * bn),
-                                       jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(blocks, js, slots, a_idx, a_values, b_tiles)
+    in_specs, out_spec = _pair_specs(block_r, block_k, bn, nnb,
+                                     pl.BlockSpec(memory_space=pl.ANY))
+    return _stream_call(
+        functools.partial(_spgemm_kernel_pairs_db, bn, block_r),
+        (blocks, js, slots, a_idx), a_values, b_tiles, slot_pos=2,
+        chunk=chunk, in_specs=in_specs, out_spec=out_spec,
+        out_shape=(nblocks * block_r, nnb * bn),
+        scratch=(pltpu.VMEM((2, block_k, bn), b_tiles.dtype),
+                 pltpu.SemaphoreType.DMA((2,))),
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -475,14 +503,13 @@ def cluster_spgemm_pairs_db(blocks: jax.Array, js: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _spgemm_kernel_pairs_window(bn, block_r, window_blocks, win_ref,
-                                blk_ref, j_ref, slot_ref, aidx_ref,
-                                a_ref, b_ref, o_ref):
+def _spgemm_kernel_pairs_window(bn, block_r, window_blocks, meta_ref,
+                                win_ref, blk_ref, j_ref, slot_ref, aidx_ref,
+                                a_ref, b_ref, c_hbm, o_ref, sem):
     t = pl.program_id(0)
-
-    @pl.when(_is_block_start(win_ref, t))
-    def _init():                     # one zero-fill per *window* of strips
-        o_ref[...] = jnp.zeros_like(o_ref)
+    # one zero-fill per *window* of strips
+    open_window(t, win_ref, meta_ref, o_ref,
+                _rows(c_hbm, window_blocks * block_r), sem)
 
     @pl.when(slot_ref[t] > 0)        # sentinels / tail pads: no MXU issue
     def _acc():
@@ -490,13 +517,14 @@ def _spgemm_kernel_pairs_window(bn, block_r, window_blocks, win_ref,
         row = pl.multiple_of(
             (blk_ref[t] - win_ref[t] * window_blocks) * block_r, block_r)
         prod = jnp.dot(a_ref[0], b_ref[0].astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
+                       preferred_element_type=jnp.float32,
+                       precision=_FP32)
         o_ref[pl.ds(row, block_r), pl.ds(col, bn)] += prod.astype(
             o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_r", "block_k", "bn", "nblocks", "nnb", "window_blocks",
+    "block_r", "block_k", "bn", "nblocks", "nnb", "window_blocks", "chunk",
     "interpret"))
 def cluster_spgemm_pairs_window(wins: jax.Array, blocks: jax.Array,
                                 js: jax.Array, slots: jax.Array,
@@ -504,6 +532,7 @@ def cluster_spgemm_pairs_window(wins: jax.Array, blocks: jax.Array,
                                 b_tiles: jax.Array, *, block_r: int,
                                 block_k: int, bn: int, nblocks: int,
                                 nnb: int, window_blocks: int,
+                                chunk: int | None = None,
                                 interpret: bool = False) -> jax.Array:
     """C = A_bcc @ B_tiled over a revisit-ordered pair stream.
 
@@ -518,32 +547,24 @@ def cluster_spgemm_pairs_window(wins: jax.Array, blocks: jax.Array,
 
     Returns: (nblocks * block_r, nnb * bn) dense fp32 C.
     """
-    t_total = blocks.shape[0]
     assert a_values.shape[1:] == (block_r, block_k)
     assert b_tiles.shape[1:] == (block_k, bn)
     nwin = (nblocks + window_blocks - 1) // window_blocks
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(t_total,),
-        in_specs=[
-            pl.BlockSpec((1, block_r, block_k),
-                         lambda t, w, blks, js_, sl, ai: (ai[t], 0, 0)),
-            pl.BlockSpec((1, block_k, bn),
-                         lambda t, w, blks, js_, sl, ai: (sl[t], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((window_blocks * block_r, nnb * bn),
-                               lambda t, w, blks, js_, sl, ai: (w[t], 0)),
-    )
-    out = pl.pallas_call(
+    in_specs = [
+        pl.BlockSpec((1, block_r, block_k),
+                     lambda t, m, w, blks, js_, sl, ai: (ai[t], 0, 0)),
+        pl.BlockSpec((1, block_k, bn),
+                     lambda t, m, w, blks, js_, sl, ai: (sl[t], 0, 0)),
+    ]
+    out_spec = pl.BlockSpec((window_blocks * block_r, nnb * bn),
+                            lambda t, m, w, blks, js_, sl, ai: (w[t], 0))
+    out = _stream_call(
         functools.partial(_spgemm_kernel_pairs_window, bn, block_r,
                           window_blocks),
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (nwin * window_blocks * block_r, nnb * bn), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(wins, blocks, js, slots, a_idx, a_values, b_tiles)
+        (wins, blocks, js, slots, a_idx), a_values, b_tiles, slot_pos=3,
+        chunk=chunk, in_specs=in_specs, out_spec=out_spec,
+        out_shape=(nwin * window_blocks * block_r, nnb * bn),
+        interpret=interpret)
     return out[: nblocks * block_r]
 
 
@@ -569,7 +590,8 @@ def _stack_shard_streams(shard_pairs) -> tuple:
 
 def _shard_local_call(blocks, js, slots, a_idx, a_values, b_tiles, *,
                       start, block_r, block_k, bn, max_blocks, nnb,
-                      window_blocks, resident, double_buffer, interpret):
+                      window_blocks, resident, double_buffer, chunk,
+                      interpret):
     """One core's kernel launch: localize block ids to the shard's range
     and run the flat pair grid (windowed when revisit-ordered)."""
     local = blocks - start
@@ -583,12 +605,13 @@ def _shard_local_call(blocks, js, slots, a_idx, a_values, b_tiles, *,
         return kernel(
             local, js, slots, a_idx, a_values, b_tiles,
             block_r=block_r, block_k=block_k, bn=bn,
-            nblocks=max_blocks, nnb=nnb, interpret=interpret)
+            nblocks=max_blocks, nnb=nnb, chunk=chunk, interpret=interpret)
     wins = local // window_blocks
     return cluster_spgemm_pairs_window(
         wins, local, js, slots, a_idx, a_values, b_tiles,
         block_r=block_r, block_k=block_k, bn=bn, nblocks=max_blocks,
-        nnb=nnb, window_blocks=window_blocks, interpret=interpret)
+        nnb=nnb, window_blocks=window_blocks, chunk=chunk,
+        interpret=interpret)
 
 
 def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
@@ -598,6 +621,7 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
                                  window_blocks: int | None = None,
                                  resident: bool = False,
                                  double_buffer: bool = False,
+                                 chunk: int | None = None,
                                  interpret: bool = False,
                                  use_shard_map: bool | None = None
                                  ) -> jax.Array:
@@ -621,6 +645,8 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
       double_buffer: run each core's streamed sub-stream through the
         two-slot manual-DMA prefetch kernel (unordered shards only;
         ignored when ``resident`` or ``window_blocks`` applies).
+      chunk: most pairs per launch of each core's sub-stream (see
+        :func:`cluster_spgemm_pairs`).
       use_shard_map: force the ``shard_map`` dispatch (needs one device
         per shard) or the serial loop; default auto — shard_map when the
         backend has enough devices and compilation is real (interpret
@@ -641,7 +667,7 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
     kw = dict(block_r=block_r, block_k=block_k, bn=bn,
               max_blocks=max_blocks, nnb=nnb,
               window_blocks=window_blocks, resident=resident,
-              double_buffer=double_buffer, interpret=interpret)
+              double_buffer=double_buffer, chunk=chunk, interpret=interpret)
     if not use_shard_map:
         # serial fallback: the same partition, one launch per shard
         outs = []
@@ -665,15 +691,11 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
                                 start=starts[0, 0], **kw)
         return out[None]
 
-    in_specs = (P("cores"), P("cores"), P("cores"), P("cores"),
-                P("cores"), P(), P())
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=P("cores"), check_vma=False)
-    else:                             # jax < 0.5: experimental + check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=P("cores"), check_rep=False)
+    mapped = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("cores"), P("cores"), P("cores"), P("cores"),
+                  P("cores"), P(), P()),
+        out_specs=P("cores"), check_vma=False)
     stacked = mapped(blk, js_, sl, ai, starts, a_values, b_tiles)
     # reassemble: shard i's first (end-start) block strips are its C rows
     outs = [stacked[i, : (int(e) - int(s)) * block_r]
@@ -686,27 +708,42 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
 # ---------------------------------------------------------------------------
 
 
-def _spgemm_kernel_pairs_sparse(cw_ref, slot_ref, aidx_ref,
-                                a_ref, b_ref, o_ref):
-    t = pl.program_id(0)
+def _slab(c_hbm):
+    """Window view of the aliased slab store: slab ``key``."""
+    return lambda key: c_hbm.at[pl.ds(key, 1)]
 
-    @pl.when(_is_block_start(cw_ref, t))
-    def _init():                     # one zero-fill per live C window
-        o_ref[...] = jnp.zeros_like(o_ref)
+
+def _spgemm_kernel_pairs_sparse(meta_ref, cw_ref, slot_ref, aidx_ref,
+                                a_ref, b_ref, c_hbm, o_ref, sem):
+    t = pl.program_id(0)
+    # one zero-fill per live C window
+    open_window(t, cw_ref, meta_ref, o_ref, _slab(c_hbm), sem)
 
     @pl.when(slot_ref[t] > 0)        # slab-0 sentinel / tail pads: no MXU
     def _acc():
         prod = jnp.dot(a_ref[0], b_ref[0].astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
+                       preferred_element_type=jnp.float32,
+                       precision=_FP32)
         o_ref[0] += prod.astype(o_ref.dtype)
 
 
+def _sparse_specs(block_r, block_k, bn, b_spec):
+    """A-slab spec, the given B spec, and the slab spec of the (meta,
+    c_slots, slots, a_idx) prefetch layout."""
+    a_spec = pl.BlockSpec((1, block_r, block_k),
+                          lambda t, m, cw, sl, ai: (ai[t], 0, 0))
+    out_spec = pl.BlockSpec((1, block_r, bn),
+                            lambda t, m, cw, sl, ai: (cw[t], 0, 0))
+    return [a_spec, b_spec], out_spec
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "block_r", "block_k", "bn", "nslabs", "interpret"))
+    "block_r", "block_k", "bn", "nslabs", "chunk", "interpret"))
 def cluster_spgemm_pairs_sparse(c_slots: jax.Array, slots: jax.Array,
                                 a_idx: jax.Array, a_values: jax.Array,
                                 b_tiles: jax.Array, *, block_r: int,
                                 block_k: int, bn: int, nslabs: int,
+                                chunk: int | None = None,
                                 interpret: bool = False) -> jax.Array:
     """Numeric phase of the sparse-C pipeline: accumulate each live
     ``(blk, j)`` C window in VMEM and write it back once as a packed
@@ -724,100 +761,59 @@ def cluster_spgemm_pairs_sparse(c_slots: jax.Array, slots: jax.Array,
       slots: (T,) int32 — B tile slot per pair, 0 = no MXU issue (the
         sentinel and tail pads).
       a_idx: (T,) int32 — A stream index per pair.
-      a_values / b_tiles: as in :func:`cluster_spgemm_pairs`.
+      a_values / b_tiles / chunk: as in :func:`cluster_spgemm_pairs`.
 
     Returns: (nslabs, block_r, bn) fp32 slab store — ``CompactedC.slabs``.
     """
-    t_total = c_slots.shape[0]
     assert a_values.shape[1:] == (block_r, block_k)
     assert b_tiles.shape[1:] == (block_k, bn)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(t_total,),
-        in_specs=[
-            pl.BlockSpec((1, block_r, block_k),
-                         lambda t, cw, sl, ai: (ai[t], 0, 0)),
-            pl.BlockSpec((1, block_k, bn),
-                         lambda t, cw, sl, ai: (sl[t], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_r, bn),
-                               lambda t, cw, sl, ai: (cw[t], 0, 0)),
-    )
-    return pl.pallas_call(
-        _spgemm_kernel_pairs_sparse,
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((nslabs, block_r, bn), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(c_slots, slots, a_idx, a_values, b_tiles)
+    in_specs, out_spec = _sparse_specs(
+        block_r, block_k, bn,
+        pl.BlockSpec((1, block_k, bn),
+                     lambda t, m, cw, sl, ai: (sl[t], 0, 0)))
+    return _stream_call(
+        _spgemm_kernel_pairs_sparse, (c_slots, slots, a_idx), a_values,
+        b_tiles, slot_pos=1, chunk=chunk, in_specs=in_specs,
+        out_spec=out_spec, out_shape=(nslabs, block_r, bn),
+        interpret=interpret)
 
 
-def _spgemm_kernel_pairs_sparse_db(cw_ref, slot_ref, aidx_ref,
-                                   a_ref, b_hbm, o_ref, b_buf, sem):
+def _spgemm_kernel_pairs_sparse_db(meta_ref, cw_ref, slot_ref, aidx_ref,
+                                   a_ref, b_hbm, c_hbm, o_ref, b_buf, sem,
+                                   carry_sem):
     t = pl.program_id(0)
-    nt = pl.num_programs(0)
-
-    def _tile_dma(pos, buf):
-        return pltpu.make_async_copy(b_hbm.at[slot_ref[pos]],
-                                     b_buf.at[buf], sem.at[buf])
-
-    @pl.when(t == 0)
-    def _warm():                      # prime the pipeline
-        _tile_dma(0, 0).start()
-
-    @pl.when(t + 1 < nt)
-    def _ahead():                     # overlap: fetch t+1 while t computes
-        _tile_dma(t + 1, (t + 1) % 2).start()
-
-    _tile_dma(t, t % 2).wait()
-
-    @pl.when(_is_block_start(cw_ref, t))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    tile = _prefetch_tiles(t, slot_ref, b_hbm, b_buf, sem)
+    open_window(t, cw_ref, meta_ref, o_ref, _slab(c_hbm), carry_sem)
 
     @pl.when(slot_ref[t] > 0)
     def _acc():
-        prod = jnp.dot(a_ref[0], b_buf[t % 2].astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
+        prod = jnp.dot(a_ref[0], tile.astype(jnp.float32),
+                       preferred_element_type=jnp.float32,
+                       precision=_FP32)
         o_ref[0] += prod.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_r", "block_k", "bn", "nslabs", "interpret"))
+    "block_r", "block_k", "bn", "nslabs", "chunk", "interpret"))
 def cluster_spgemm_pairs_sparse_db(c_slots: jax.Array, slots: jax.Array,
                                    a_idx: jax.Array, a_values: jax.Array,
                                    b_tiles: jax.Array, *, block_r: int,
                                    block_k: int, bn: int, nslabs: int,
+                                   chunk: int | None = None,
                                    interpret: bool = False) -> jax.Array:
     """Sparse-C variant with manual double-buffered B tile prefetch: B
     stays in HBM (``ANY`` space) and step t+1's tile is in flight while
     step t contracts — :func:`cluster_spgemm_pairs_db`'s pipeline on the
     sparse-C output path. Same contract as
     :func:`cluster_spgemm_pairs_sparse`."""
-    t_total = c_slots.shape[0]
     assert a_values.shape[1:] == (block_r, block_k)
     assert b_tiles.shape[1:] == (block_k, bn)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(t_total,),
-        in_specs=[
-            pl.BlockSpec((1, block_r, block_k),
-                         lambda t, cw, sl, ai: (ai[t], 0, 0)),
-            pl.BlockSpec(memory_space=_ANY),
-        ],
-        out_specs=pl.BlockSpec((1, block_r, bn),
-                               lambda t, cw, sl, ai: (cw[t], 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, block_k, bn), b_tiles.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        _spgemm_kernel_pairs_sparse_db,
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((nslabs, block_r, bn), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(c_slots, slots, a_idx, a_values, b_tiles)
+    in_specs, out_spec = _sparse_specs(block_r, block_k, bn,
+                                       pl.BlockSpec(memory_space=pl.ANY))
+    return _stream_call(
+        _spgemm_kernel_pairs_sparse_db, (c_slots, slots, a_idx), a_values,
+        b_tiles, slot_pos=1, chunk=chunk, in_specs=in_specs,
+        out_spec=out_spec, out_shape=(nslabs, block_r, bn),
+        scratch=(pltpu.VMEM((2, block_k, bn), b_tiles.dtype),
+                 pltpu.SemaphoreType.DMA((2,))),
+        interpret=interpret)

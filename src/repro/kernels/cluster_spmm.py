@@ -39,9 +39,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.chunked import open_window, run_in_chunks
+
+# fp32 contractions at full fp32 precision: at the MXU's default precision
+# for fp32 operands, a Graph500 scale-14 A·A on a TPU v5e came back off
+# the fp32 product by up to 3.4e-4 of max|C|
+_FP32 = jax.lax.Precision.HIGHEST
 
 __all__ = ["cluster_spmm", "cluster_spmm_compact"]
 
@@ -59,9 +62,9 @@ def _spmm_kernel_padded(ids_ref, a_ref, b_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     a = a_ref[0]                      # (block_r, block_k)
-    b = b_ref[...]                    # (block_k, bn)
-    o_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32
-                          ).astype(o_ref.dtype)
+    b = b_ref[...].astype(jnp.float32)   # (block_k, bn)
+    o_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32,
+                          precision=_FP32).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -104,7 +107,7 @@ def cluster_spmm(tile_ids: jax.Array, a_values: jax.Array, b: jax.Array,
         _spmm_kernel_padded,
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((nblocks * block_r, n), b.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(tile_ids, a_values, b)
@@ -115,28 +118,33 @@ def cluster_spmm(tile_ids: jax.Array, a_values: jax.Array, b: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _spmm_kernel_compact(block_ids_ref, tile_ids_ref, a_ref, b_ref, o_ref):
+def _spmm_kernel_compact(s_total, block_r, bn, meta_ref, block_ids_ref,
+                         tile_ids_ref, a_ref, b_ref, c_hbm, o_ref, sem):
+    j = pl.program_id(0)
     s = pl.program_id(1)
-    is_first = jnp.where(s == 0, True,
-                         block_ids_ref[s] != block_ids_ref[jnp.maximum(s - 1,
-                                                                       0)])
 
-    @pl.when(is_first)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def window(blk):
+        return c_hbm.at[pl.ds(pl.multiple_of(blk * block_r, block_r),
+                              block_r),
+                        pl.ds(pl.multiple_of(j * bn, bn), bn)]
 
-    a = a_ref[0]
-    b = b_ref[...]
-    o_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32
-                          ).astype(o_ref.dtype)
+    open_window(s, block_ids_ref, meta_ref, o_ref, window, sem)
+
+    @pl.when(meta_ref[1] + s < s_total)       # chunk tail pads: no MXU
+    def _acc():
+        o_ref[...] += jnp.dot(a_ref[0], b_ref[...].astype(jnp.float32),
+                              preferred_element_type=jnp.float32,
+                              precision=_FP32
+                              ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_r", "block_k", "nblocks", "bn", "interpret"))
+    "block_r", "block_k", "nblocks", "bn", "chunk", "interpret"))
 def cluster_spmm_compact(block_ids: jax.Array, tile_ids: jax.Array,
                          a_values: jax.Array, b: jax.Array,
                          *, block_r: int, block_k: int, nblocks: int,
-                         bn: int = 128, interpret: bool = False) -> jax.Array:
+                         bn: int = 128, chunk: int | None = None,
+                         interpret: bool = False) -> jax.Array:
     """Compact-stream variant: only live (block, tile) pairs are visited.
 
     Args:
@@ -146,30 +154,42 @@ def cluster_spmm_compact(block_ids: jax.Array, tile_ids: jax.Array,
       tile_ids: (S,) int32 — B tile id per live tile.
       a_values: (S, block_r, block_k) value slabs.
       b: (K, N) dense.
+      chunk: most stream steps per launch — the two streams are
+        scalar-prefetched into SMEM, so long streams run as several
+        launches (:mod:`repro.kernels.chunked`); ``None`` is one launch.
     """
     s_total, br, bk = a_values.shape
     assert (br, bk) == (block_r, block_k)
     k, n = b.shape
     assert k % block_k == 0 and n % bn == 0
+    last = s_total - 1
 
-    grid = (n // bn, s_total)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_r, block_k),
-                         lambda j, s, blks, ids: (s, 0, 0)),
-            pl.BlockSpec((block_k, bn),
-                         lambda j, s, blks, ids: (ids[s], j)),
-        ],
-        out_specs=pl.BlockSpec((block_r, bn),
-                               lambda j, s, blks, ids: (blks[s], j)),
-    )
-    return pl.pallas_call(
-        _spmm_kernel_compact,
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((nblocks * block_r, n), b.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_ids, tile_ids, a_values, b)
+    def launch(meta, blks, ids, c):
+        spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n // bn, blks.shape[0]),
+            in_specs=[
+                pl.BlockSpec((1, block_r, block_k),
+                             lambda j, s, m, blks, ids:
+                             (jnp.minimum(m[1] + s, last), 0, 0)),
+                pl.BlockSpec((block_k, bn),
+                             lambda j, s, m, blks, ids: (ids[s], j)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((block_r, bn),
+                                   lambda j, s, m, blks, ids: (blks[s], j)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
+        )
+        return pl.pallas_call(
+            functools.partial(_spmm_kernel_compact, s_total, block_r, bn),
+            grid_spec=spec,
+            out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
+            input_output_aliases={5: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(meta, blks, ids, a_values, b, c)
+
+    c0 = jnp.zeros((nblocks * block_r, n), b.dtype)
+    return run_in_chunks(launch, (block_ids, tile_ids), c0, slot_pos=None,
+                         chunk=chunk)

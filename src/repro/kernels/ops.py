@@ -34,7 +34,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_chunk import ssd_chunk_scan
 from repro.resilience import faults as _faults
 
-__all__ = ["on_tpu", "pallas_shard_count", "bcc_spmm",
+__all__ = ["on_tpu", "pallas_shard_count", "stream_chunk", "bcc_spmm",
            "bcc_compact_stream", "bcc_compact_stream_reference",
            "bcc_spmm_compact", "build_live_pairs", "build_shard_pack",
            "build_sparse_c_pairs", "predict_c_window_density",
@@ -44,6 +44,14 @@ __all__ = ["on_tpu", "pallas_shard_count", "bcc_spmm",
 # VMEM budget for pinning TiledCSR's tile store on-chip (leave headroom for
 # the A slab / C tile double buffers out of the 16 MiB core budget)
 _RESIDENT_B_BUDGET = 8 * 2**20
+
+# SMEM shared by the scalar-prefetched streams of one Pallas launch. The
+# chip's compiler places every prefetched array whole in the TensorCore's
+# SMEM, 1 MiB on a TPU v5e (its refusal when the streams overflow it:
+# "Ran out of memory in memory space smem. Used 1.00M of 1.00M smem").
+# Half of it is left to the compiler's own scalars; longer streams are
+# launched in chunks of stream_chunk() steps.
+_SMEM_STREAM_BUDGET = 2**19
 
 # ceiling on the compacted kernels' C row-strip window (block_r × nnb·bn
 # fp32, double-buffered by the pipeline): B matrices wide enough to blow
@@ -85,6 +93,17 @@ def pallas_shard_count() -> int:
     threads — sharding the stream over them only adds dispatch overhead,
     and interpret-mode tests want the serial path's determinism)."""
     return jax.device_count() if on_tpu() else 1
+
+
+def stream_chunk(n_streams: int) -> int:
+    """Steps per launch of a kernel that scalar-prefetches ``n_streams``
+    int32 streams: the most that fit :data:`_SMEM_STREAM_BUDGET`, a
+    multiple of 8.
+
+    >>> stream_chunk(4)
+    32768
+    """
+    return max(8, _SMEM_STREAM_BUDGET // (4 * n_streams) // 8 * 8)
 
 
 def _pad_cols(b: jax.Array, multiple: int) -> jax.Array:
@@ -201,7 +220,8 @@ def bcc_spmm_compact(a: BCC, b: jax.Array, *, bn: int = 128,
     out = cluster_spmm_compact(block_ids, tile_ids, values, b,
                                block_r=a.block_r, block_k=a.block_k,
                                nblocks=nblocks, bn=bn_eff,
-                               interpret=interpret)
+                               chunk=stream_chunk(2), interpret=interpret)
+    _note_kernel_launch("spmm_compact")
     return out[: a.nrows, : n0]
 
 
@@ -401,7 +421,8 @@ def bcc_spgemm_sparse_c(a: BCC, b: TiledCSR, *,
         slabs = kernel(jnp.asarray(c_slots), jnp.asarray(slots),
                        jnp.asarray(a_idx), values, b.tiles,
                        block_r=a.block_r, block_k=a.block_k, bn=b.bn,
-                       nslabs=int(nslabs), interpret=interpret)
+                       nslabs=int(nslabs), chunk=stream_chunk(3),
+                       interpret=interpret)
     out = CompactedC(slabs=slabs, table=jnp.asarray(table),
                      nrows=a.nrows, ncols=b.ncols,
                      block_r=a.block_r, bn=b.bn)
@@ -510,6 +531,7 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
                     double_buffer=(double_buffer
                                    if double_buffer is not None
                                    else on_tpu()),
+                    chunk=stream_chunk(4 if wb is None else 5),
                     interpret=interpret)
             _note_kernel_launch(variant, pairs=pairs, block_r=a.block_r,
                                 block_k=a.block_k, bn=b.bn)
@@ -524,10 +546,19 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
         with get_tracer().span("kernel_variant", variant=variant):
             out = kernel(blocks, js, slots, a_idx, values, b.tiles,
                          block_r=a.block_r, block_k=a.block_k, bn=b.bn,
-                         nblocks=nblocks, nnb=b.nnb, interpret=interpret)
+                         nblocks=nblocks, nnb=b.nnb, chunk=stream_chunk(4),
+                         interpret=interpret)
         _note_kernel_launch(variant, pairs=pairs, block_r=a.block_r,
                             block_k=a.block_k, bn=b.bn)
         return out[: a.nrows, : b.ncols]
+    # the padded grid prefetches its whole stream and B's whole table
+    prefetch_bytes = 4 * (2 * len(stream[0]) + b.nkb * b.nnb)
+    if prefetch_bytes > _SMEM_STREAM_BUDGET:
+        raise ValueError(
+            f"padded Sp×Sp grid needs {prefetch_bytes} B of SMEM for its "
+            f"stream and B's tile table, over the {_SMEM_STREAM_BUDGET} B "
+            f"budget (C row strip of {b.nnb * b.bn} columns is too wide "
+            "for the compacted grid)")
     block_ids, tile_ids, values = (jnp.asarray(s) for s in stream)
     kernel = cluster_spgemm_resident if resident else cluster_spgemm_tiled
     with get_tracer().span("kernel_variant", variant="padded",

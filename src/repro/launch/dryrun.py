@@ -1,13 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
-production mesh (16×16 = 256 chips/pod and 2×16×16 = 512 chips) and extract
-memory / cost / collective statistics for EXPERIMENTS.md.
+production mesh (16×16 = 256 chips/pod and 2×16×16 = 512 chips) of TPU v5e
+chips and extract memory / cost / collective statistics.
 
-The two lines above MUST run before any other import — jax locks the device
-count at first initialization. Do not set this flag globally: smoke tests
-and benchmarks are supposed to see 1 device.
+``main()`` asks XLA for 512 host devices before JAX initializes (it locks
+the device count then); importing this module sets nothing.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-14b \
@@ -16,20 +12,24 @@ Usage:
 Results: experiments/dryrun/<arch>__<shape>__<mesh>.json
 """
 
-import argparse      # noqa: E402
-import gzip          # noqa: E402
-import json          # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
+import argparse
+import gzip
+import json
+import os
+import time
+import traceback
 
-import jax           # noqa: E402
+import jax
 
-from repro.configs.base import ARCH_IDS, get_config        # noqa: E402
-from repro.configs.shapes import SHAPES, shape_applicable  # noqa: E402
-from repro.launch.jaxpr_cost import trace_cost             # noqa: E402
-from repro.launch.mesh import make_production_mesh         # noqa: E402
-from repro.launch.roofline import analyze                  # noqa: E402
-from repro.launch.specs import build_cell                  # noqa: E402
+from repro.configs.base import ARCH_IDS, get_config
+from repro.configs.shapes import SHAPES, shape_applicable
+from repro.launch.jaxpr_cost import trace_cost
+from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import analyze
+from repro.launch.specs import build_cell
+
+# the chip the production mesh is made of (its peaks price the roofline)
+TARGET_KIND = "TPU v5 lite"
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
@@ -83,7 +83,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 "wt") as f:
             f.write(hlo)
         report = analyze(arch, shape, mesh_name, chips, cost,
-                         _mem_dict(mem), hlo, cfg, jx, notes=cell.notes)
+                         _mem_dict(mem), hlo, cfg, jx,
+                         device_kind=TARGET_KIND, notes=cell.notes)
         result.update(status="ok", lower_s=round(t_lower, 1),
                       compile_s=round(t_compile, 1),
                       roofline=report.to_json())
@@ -110,6 +111,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=sorted(SHAPES))

@@ -2,9 +2,12 @@
 
 Per (arch × shape × mesh):
 
-    compute_s    = FLOPs_total      / (chips × 197e12 bf16 FLOP/s)
-    memory_s     = HBM_bytes_total  / (chips × 819e9 B/s)
-    collective_s = wire_bytes_total / (chips × 50e9 B/s ICI link)
+    compute_s    = FLOPs_total      / (chips × peak FLOP/s)
+    memory_s     = HBM_bytes_total  / (chips × HBM B/s)
+    collective_s = wire_bytes_total / (chips × ICI link B/s)
+
+The peaks come from :data:`PEAKS`, keyed by JAX's ``device_kind``; a
+kind missing from the table is an error, never a default.
 
 Two measurement sources are recorded side by side:
 
@@ -31,14 +34,33 @@ import dataclasses
 
 from repro.launch.hlo_graph import collective_stats
 
-__all__ = ["HW", "RooflineReport", "analyze", "model_flops_for_cell"]
+__all__ = ["HW", "PEAKS", "peaks_for", "RooflineReport", "analyze",
+           "model_flops_for_cell"]
 
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12      # bf16 / chip (TPU v5e)
-    hbm_bw: float = 819e9           # B/s / chip
-    link_bw: float = 50e9           # B/s / link ICI
+    peak_flops: float               # bf16 FLOP/s per chip
+    hbm_bw: float                   # HBM B/s per chip
+    link_bw: float                  # B/s per ICI link
+
+
+# published per-chip peaks by device_kind. TPU v5e ("TPU v5 lite"):
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s of
+# HBM, 1,600 Gbit/s of interchip interconnect over 4 links
+PEAKS: dict[str, HW] = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> HW:
+    """The :data:`PEAKS` entry of ``device_kind``; raises for a kind
+    without published peaks in the table."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; add "
+                       "its published peaks to PEAKS") from None
 
 
 @dataclasses.dataclass
@@ -82,8 +104,9 @@ def model_flops_for_cell(cfg, shape) -> float:
 
 
 def analyze(arch: str, shape, mesh_name: str, chips: int, cost: dict,
-            memory_stats: dict, hlo_text: str, cfg, jaxpr_stats: dict,
-            hw: HW = HW(), notes: str = "") -> RooflineReport:
+            memory_stats: dict, hlo_text: str, cfg, jaxpr_stats: dict, *,
+            device_kind: str, notes: str = "") -> RooflineReport:
+    hw = peaks_for(device_kind)
     xla_flops_dev = float(cost.get("flops", 0.0))
     xla_bytes_dev = float(cost.get("bytes accessed", 0.0))
     flops_total = float(jaxpr_stats["flops"])

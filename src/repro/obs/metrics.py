@@ -75,8 +75,8 @@ _HOST_METRICS: dict[str, tuple[str, str]] = {
     "exec_cache_entries": (
         "gauge", "packed operand sets resident in the exec cache (count)"),
     "kernel_launches": (
-        "counter", "Pallas Sp×Sp kernel dispatches, by variant label "
-        "(count)"),
+        "counter", "Pallas Sp×Sp and SpMM kernel dispatches, by variant "
+        "label (count)"),
     "chain_hops": (
         "counter", "chain-workload hops executed (count)"),
     "pipeline_stage_s": (

@@ -3,7 +3,8 @@
 A :class:`Plan` is the full output of preprocessing — the chosen
 (reorder, scheme), the row permutation, the cluster boundaries and the
 timings that justified the choice. The cache keys plans by
-``(pattern fingerprint, reuse bucket, workload, PLAN_CACHE_VERSION)``:
+``(pattern fingerprint, reuse bucket, workload, backend,
+PLAN_CACHE_VERSION)``:
 
 * the *fingerprint* (see :func:`repro.planner.features.fingerprint`) is
   value-independent, so re-serving the same sparsity pattern with new
@@ -15,6 +16,10 @@ timings that justified the choice. The cache keys plans by
   plan measured on one kernel family from serving the other — the SpMM
   menu (``spmm_*``, ``cluster_spmm_compact``) has different economics
   than the A² menu;
+* the *backend* (:func:`backend_tag`: platform, device kind and device
+  count) keeps a plan scored for one device from serving another — the
+  cost model rules the pallas scheme out on the CPU interpreter, so a
+  CPU-made plan must never be served on a TPU from a shared directory;
 * the *version* is bumped whenever plan semantics change, like
   ``benchlib``'s kernel-generation cache key — a stale on-disk plan from
   an older planner can never be served.
@@ -49,6 +54,7 @@ owns the un-prefixed files and likewise never touches namespaced ones.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import io
 import json
@@ -63,10 +69,11 @@ from repro.resilience import faults as _faults
 from repro.resilience.errors import CorruptPlanError
 
 __all__ = ["Plan", "PlanCache", "PLAN_CACHE_VERSION", "reuse_bucket",
-           "DEFAULT_CACHE_DIR", "DEFAULT_MAX_BYTES"]
+           "backend_tag", "DEFAULT_CACHE_DIR", "DEFAULT_MAX_BYTES"]
 
-# v3: checksummed crash-safe entries (v2: workload keys + pallas scheme)
-PLAN_CACHE_VERSION = "plan-v3"
+# v4: backend-keyed entries (v3: checksummed crash-safe entries; v2:
+# workload keys + pallas scheme)
+PLAN_CACHE_VERSION = "plan-v4"
 
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(__file__), "..", "..", "..", "experiments", "plan_cache")
@@ -75,6 +82,17 @@ DEFAULT_CACHE_DIR = os.path.join(
 # + boundaries — even 1M-row plans are ~8 MB, so this holds dozens of hot
 # tenants while bounding the on-disk store)
 DEFAULT_MAX_BYTES = 256 * 2**20
+
+
+@functools.lru_cache(maxsize=1)
+def backend_tag() -> str:
+    """The backend plans are made for: ``platform-device_kind-count`` of
+    JAX's devices, with every character but letters and digits turned to
+    ``-`` (it is part of on-disk file names)."""
+    import jax
+    devs = jax.devices()
+    raw = f"{devs[0].platform}-{devs[0].device_kind}-{len(devs)}"
+    return "".join(c if c.isalnum() else "-" for c in raw)
 
 
 def reuse_bucket(reuse_hint: int) -> int:
@@ -295,7 +313,7 @@ class PlanCache:
     def key(fingerprint: str, reuse_hint: int, workload: str = "a2",
             namespace: str = "") -> str:
         base = (f"{fingerprint}|r{reuse_bucket(reuse_hint)}|{workload}"
-                f"|{PLAN_CACHE_VERSION}")
+                f"|{backend_tag()}|{PLAN_CACHE_VERSION}")
         return f"ns-{namespace}|{base}" if namespace else base
 
     def _key(self, fingerprint: str, reuse_hint: int,

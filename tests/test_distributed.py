@@ -95,7 +95,7 @@ _SCRIPT = textwrap.dedent("""
 @pytest.mark.parametrize("arch", ["qwen3-14b", "mamba2-370m",
                                   "moonshot-v1-16b-a3b", "zamba2-2.7b"])
 def test_sharded_execution_matches_single_device(arch):
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT.replace("ARCH", arch)],
         capture_output=True, text=True, env=env, timeout=600)

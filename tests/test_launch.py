@@ -10,7 +10,7 @@ from repro.configs.shapes import SHAPES, shape_applicable
 from repro.launch.presets import preset_for
 from repro.launch.report import _diagnosis, dryrun_table, roofline_table
 from repro.launch.specs import input_specs
-from repro.launch.roofline import HW, analyze, model_flops_for_cell
+from repro.launch.roofline import analyze, model_flops_for_cell, peaks_for
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -62,11 +62,19 @@ def test_analyze_bottleneck_selection():
     hlo = "ENTRY %main (p: f32[4]) -> f32[4] {\n  ROOT %r = f32[4] copy(%p)\n}"
     rep = analyze("qwen3-14b", ss, "single", 256,
                   {"flops": 1e12, "bytes accessed": 1e9}, {}, hlo, cfg,
-                  {"flops": 1e18, "bytes": 1e12, "bytes_ub": 1e13})
+                  {"flops": 1e18, "bytes": 1e12, "bytes_ub": 1e13},
+                  device_kind="TPU v5 lite")
     assert rep.bottleneck == "compute"
-    assert rep.compute_s == pytest.approx(1e18 / (256 * HW().peak_flops))
+    assert rep.compute_s == pytest.approx(
+        1e18 / (256 * peaks_for("TPU v5 lite").peak_flops))
     assert 0 < rep.useful_ratio < 1
     assert rep.peak_fraction <= 1.0
+
+
+def test_peaks_unknown_device_kind_raises():
+    assert peaks_for("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(KeyError, match="cpu"):
+        peaks_for("cpu")
 
 
 def test_model_flops_decode_scaling():

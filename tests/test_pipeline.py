@@ -20,7 +20,7 @@ _SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.distributed.pipeline import pipeline_apply
 
-    from repro.launch.mesh import _make_mesh   # jax<0.5 lacks AxisType
+    from repro.launch.mesh import _make_mesh
     mesh = _make_mesh((4,), ("pipe",))
     rng = np.random.default_rng(0)
     P_, M, B, D, F = 4, 6, 2, 16, 32
@@ -46,7 +46,7 @@ _SCRIPT = textwrap.dedent("""
 
 
 def test_pipeline_matches_sequential():
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _SCRIPT],
                          capture_output=True, text=True, env=env,
                          timeout=600)

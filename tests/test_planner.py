@@ -192,6 +192,23 @@ def test_cache_budget_bounds_disk_across_restarts(tmp_path):
     assert c3.get("fp-0", 10) is None        # the oldest never survives
 
 
+def test_cache_plan_from_another_backend_misses(tmp_path, monkeypatch):
+    """A plan made under one backend (platform, device kind, device
+    count) is never served under another — in memory or from disk."""
+    from repro.planner import plan_cache
+    path = str(tmp_path / "plans")
+    monkeypatch.setattr(plan_cache, "backend_tag", lambda: "cpu-cpu-1")
+    cache = PlanCache(path=path)
+    cache.put(_plan_of_size(0, 64))
+    assert cache.get("fp-0", 10) is not None
+    monkeypatch.setattr(plan_cache, "backend_tag",
+                        lambda: "tpu-TPU-v5-lite-1")
+    assert cache.get("fp-0", 10) is None
+    assert PlanCache(path=path).get("fp-0", 10) is None
+    monkeypatch.setattr(plan_cache, "backend_tag", lambda: "cpu-cpu-1")
+    assert PlanCache(path=path).get("fp-0", 10) is not None
+
+
 def test_cache_unbudgeted_never_evicts():
     cache = PlanCache()
     for i in range(50):

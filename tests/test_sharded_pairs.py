@@ -342,7 +342,7 @@ def test_shard_map_dispatch_multi_device_subprocess():
         "assert np.array_equal(got, base), 'shard_map mismatch'\n"
         "print('OK')\n"
     )
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),

@@ -133,6 +133,37 @@ def test_clusterwise_binned_matches_oracle():
                                atol=1e-5)
 
 
+@pytest.mark.parametrize("max_updates", [64, 1 << 20])
+def test_clusterwise_bins_split_at_update_bound(monkeypatch, max_updates):
+    """Buckets over the per-pass update bound split into several bins of
+    one width, each within the bound, covering the same slots in order —
+    and the product is unchanged."""
+    rng = np.random.default_rng(12)
+    dense = (rng.random((48, 48)) < 0.2).astype(np.float32)
+    dense[:, 5] = 1.0
+    a = HostCSR.from_dense(dense)
+    cl = fixed_length_clusters(a, 4)
+    cc = csr_cluster_from_host(a, cl.boundaries.tolist(), max_cluster=4)
+    total = int(np.asarray(cc.cluster_ptr)[-1])
+    slot_cols = np.asarray(cc.cols)[:total].astype(np.int64)
+    lens = np.where(slot_cols < a.ncols,
+                    a.row_nnz()[np.clip(slot_cols, 0, a.nrows - 1)], 0)
+    whole = length_bins(lens, pad_sentinel=cc.slot_cap)
+    from repro.core import spgemm
+    monkeypatch.setattr(spgemm, "MAX_PASS_UPDATES", max_updates)
+    bins = length_bins(lens, pad_sentinel=cc.slot_cap)
+    assert all(s.size * w <= max(max_updates, 8 * w) for s, w in bins)
+    if max_updates == 64:
+        assert len(bins) > len(whole)
+    live = lambda bs: [x for s, _ in bs for x in s.tolist()
+                       if x < cc.slot_cap]
+    assert live(bins) == live(whole)
+    got = np.asarray(spgemm_clusterwise_dense_binned(cc, csr_from_host(a),
+                                                     bins))
+    np.testing.assert_allclose(got, spgemm_reference(a, a), rtol=1e-5,
+                               atol=1e-5)
+
+
 def test_flops_and_symbolic():
     a = rand_host(16, 16, 0.3, 9)
     c = spgemm_reference(a, a)
