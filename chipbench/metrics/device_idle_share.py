@@ -1,0 +1,9 @@
+"""Device: the share of the traced window in which no operation ran on
+the device (profiler trace)."""
+UNIT = "%"
+
+
+def read(ctx):
+    if ctx.trace is None or not ctx.trace.ops or ctx.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s() / ctx.trace.window_s)
