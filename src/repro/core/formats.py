@@ -786,9 +786,11 @@ def bcc_from_host(h: HostCSR, block_r: int = 8, block_k: int = 128,
     nnz_flat[order] = flat[slot_sorted]
     values[nnz_flat, rows % block_r, cols % block_k] = h.data
     ntiles = per_block.astype(np.int32)
-    return BCC(tile_ids=jnp.asarray(tile_ids),
-               values=jnp.asarray(values, dtype),
-               ntiles=jnp.asarray(ntiles),
+    # imported here: repro.obs, which transfer uses, imports this module
+    from repro.core.transfer import to_device
+    tile_ids, values, ntiles = to_device(tile_ids, values, ntiles,
+                                         dtypes=(None, dtype, None))
+    return BCC(tile_ids=tile_ids, values=values, ntiles=ntiles,
                nrows=h.nrows, ncols=h.ncols,
                block_r=block_r, block_k=block_k, tiles_per_block=tpb)
 
@@ -868,8 +870,9 @@ def tiled_csr_from_host(h: HostCSR, block_k: int = 128, bn: int = 128,
         slot = np.empty(h.nnz, dtype=np.int64)
         slot[order] = slot_sorted
         tiles[slot, rows % block_k, cols % bn] = h.data
-    return TiledCSR(tiles=jnp.asarray(tiles, dtype),
-                    table=jnp.asarray(table),
+    from repro.core.transfer import to_device
+    tiles, table = to_device(tiles, table, dtypes=(dtype, None))
+    return TiledCSR(tiles=tiles, table=table,
                     nrows=h.nrows, ncols=h.ncols, block_k=block_k, bn=bn)
 
 
@@ -1234,6 +1237,9 @@ def partition_pair_stream(pairs, *, nblocks: int, num_shards: int,
         e0 = int(np.clip(np.searchsorted(cum, target, side="left"),
                          1, nblocks))
         e = e0 - 1 if target - cum[e0 - 1] <= cum[e0] - target else e0
+        # ties take the earliest block: the first boundary with this
+        # cumulative count (blocks without live pairs leave it flat)
+        e = int(np.searchsorted(cum, cum[e], side="left"))
         e = int(np.clip(e, bounds[-1] + 1, nblocks - (s_eff - i)))
         bounds.append(e)
     bounds.append(nblocks)

@@ -29,7 +29,6 @@ the pipeline hardcoding one.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -38,7 +37,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.formats import HostCSR
-from repro.obs import metrics as obs_metrics
 from repro.obs.trace import get_tracer
 from repro.planner.plan_cache import Plan
 from repro.planner.service import Planner, default_planner
@@ -167,17 +165,14 @@ def pipeline_spmm_apply(plans: Sequence[Plan],
     m, bsz, feat = x.shape
     acts = np.asarray(x, dtype=np.float32)
     tracer = get_tracer()
-    stage_hist = obs_metrics.get_registry().histogram("pipeline_stage_s")
     for i, (plan, mat) in enumerate(zip(plans, stage_mats)):
         if mat.nrows != mat.ncols or mat.ncols != feat:
             raise ValueError("stage matrices must be (F, F)")
         with tracer.span("stage", stage=i, phase="execute",
                          scheme=plan.scheme):
-            t0 = time.perf_counter()
             # one (F, M·B) SpMM per stage: microbatches ride the dense
             # width; the planner runner device-syncs before returning
             flat = acts.reshape(m * bsz, feat).T        # (F, M·B)
             out = planner.execute(plan, mat, flat)      # (F, M·B)
             acts = out.T.reshape(m, bsz, feat)
-            stage_hist.observe(time.perf_counter() - t0)
     return acts

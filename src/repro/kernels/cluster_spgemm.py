@@ -123,6 +123,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.transfer import to_device
 from repro.kernels.chunked import open_window, run_in_chunks
 
 # fp32 contractions at full fp32 precision: at the MXU's default precision
@@ -213,6 +214,7 @@ def cluster_spgemm_tiled(block_ids: jax.Array, tile_ids: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="cluster_spgemm_tiled",
     )(block_ids, tile_ids, table, a_values, b_tiles)
 
 
@@ -276,6 +278,7 @@ def cluster_spgemm_resident(block_ids: jax.Array, tile_ids: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="cluster_spgemm_resident",
     )(block_ids, tile_ids, table, a_values, b_tiles)
 
 
@@ -291,15 +294,17 @@ def _rows(c_hbm, rows):
                                       rows)]
 
 
-def _stream_call(kernel, streams, a_values, b_tiles, *, slot_pos, chunk,
-                 in_specs, out_spec, out_shape, scratch=(), interpret):
+def _stream_call(kernel, streams, a_values, b_tiles, *, name, slot_pos,
+                 chunk, in_specs, out_spec, out_shape, scratch=(),
+                 interpret):
     """Run a 1-D pair-stream kernel over ``streams`` in chunk launches
     (:func:`repro.kernels.chunked.run_in_chunks`). Each launch
     scalar-prefetches ``(meta, *chunk streams)``, reads A and B through
     ``in_specs`` and writes the fp32 ``out_shape`` output, which it takes
     in again — unblocked, for :func:`repro.kernels.chunked.open_window` —
     and aliases to its result. The kernel's last scratch operand is the
-    window-carry DMA semaphore."""
+    window-carry DMA semaphore. ``name`` names the Pallas kernel (its
+    launcher's name), so it reads the same in every profile."""
     npre = 1 + len(streams)
 
     def launch(meta, *args):
@@ -319,6 +324,7 @@ def _stream_call(kernel, streams, a_values, b_tiles, *, slot_pos, chunk,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
+            name=name,
         )(meta, *chunks, a_values, b_tiles, c)
 
     return run_in_chunks(launch, streams, jnp.zeros(out_shape, jnp.float32),
@@ -390,7 +396,8 @@ def cluster_spgemm_pairs(blocks: jax.Array, js: jax.Array, slots: jax.Array,
                      lambda t, m, blks, js_, sl, ai: (sl[t], 0, 0)))
     return _stream_call(
         functools.partial(_spgemm_kernel_pairs, bn, block_r),
-        (blocks, js, slots, a_idx), a_values, b_tiles, slot_pos=2,
+        (blocks, js, slots, a_idx), a_values, b_tiles,
+        name="cluster_spgemm_pairs", slot_pos=2,
         chunk=chunk, in_specs=in_specs, out_spec=out_spec,
         out_shape=(nblocks * block_r, nnb * bn), interpret=interpret)
 
@@ -428,7 +435,8 @@ def cluster_spgemm_pairs_resident(blocks: jax.Array, js: jax.Array,
                      lambda t, m, blks, js_, sl, ai: (0, 0, 0)))
     return _stream_call(
         functools.partial(_spgemm_kernel_pairs_resident, bn, block_r),
-        (blocks, js, slots, a_idx), a_values, b_tiles, slot_pos=2,
+        (blocks, js, slots, a_idx), a_values, b_tiles,
+        name="cluster_spgemm_pairs_resident", slot_pos=2,
         chunk=chunk, in_specs=in_specs, out_spec=out_spec,
         out_shape=(nblocks * block_r, nnb * bn), interpret=interpret)
 
@@ -490,7 +498,8 @@ def cluster_spgemm_pairs_db(blocks: jax.Array, js: jax.Array,
                                      pl.BlockSpec(memory_space=pl.ANY))
     return _stream_call(
         functools.partial(_spgemm_kernel_pairs_db, bn, block_r),
-        (blocks, js, slots, a_idx), a_values, b_tiles, slot_pos=2,
+        (blocks, js, slots, a_idx), a_values, b_tiles,
+        name="cluster_spgemm_pairs_db", slot_pos=2,
         chunk=chunk, in_specs=in_specs, out_spec=out_spec,
         out_shape=(nblocks * block_r, nnb * bn),
         scratch=(pltpu.VMEM((2, block_k, bn), b_tiles.dtype),
@@ -561,7 +570,8 @@ def cluster_spgemm_pairs_window(wins: jax.Array, blocks: jax.Array,
     out = _stream_call(
         functools.partial(_spgemm_kernel_pairs_window, bn, block_r,
                           window_blocks),
-        (wins, blocks, js, slots, a_idx), a_values, b_tiles, slot_pos=3,
+        (wins, blocks, js, slots, a_idx), a_values, b_tiles,
+        name="cluster_spgemm_pairs_window", slot_pos=3,
         chunk=chunk, in_specs=in_specs, out_spec=out_spec,
         out_shape=(nwin * window_blocks * block_r, nnb * bn),
         interpret=interpret)
@@ -672,7 +682,7 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
         # serial fallback: the same partition, one launch per shard
         outs = []
         for (start, end), pairs in zip(ranges, shard_pairs):
-            sb, sj, ss, sa = (jnp.asarray(p) for p in pairs)
+            sb, sj, ss, sa = to_device(*pairs)
             out = _shard_local_call(sb, sj, ss, sa, a_values, b_tiles,
                                     start=int(start), **kw)
             outs.append(out[: (int(end) - int(start)) * block_r])
@@ -681,9 +691,8 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
     from repro.distributed.sharding import core_mesh
     from jax.sharding import PartitionSpec as P
     mesh = core_mesh(n_shards)
-    blk, js_, sl, ai = (jnp.asarray(c)
-                        for c in _stack_shard_streams(shard_pairs))
-    starts = jnp.asarray(ranges[:, 0].astype(np.int32)).reshape(-1, 1)
+    blk, js_, sl, ai, starts = to_device(
+        *_stack_shard_streams(shard_pairs), ranges[:, :1].astype(np.int32))
 
     def body(blk, js_, sl, ai, starts, a_values, b_tiles):
         out = _shard_local_call(blk[0], js_[0], sl[0], ai[0],
@@ -773,8 +782,9 @@ def cluster_spgemm_pairs_sparse(c_slots: jax.Array, slots: jax.Array,
                      lambda t, m, cw, sl, ai: (sl[t], 0, 0)))
     return _stream_call(
         _spgemm_kernel_pairs_sparse, (c_slots, slots, a_idx), a_values,
-        b_tiles, slot_pos=1, chunk=chunk, in_specs=in_specs,
-        out_spec=out_spec, out_shape=(nslabs, block_r, bn),
+        b_tiles, name="cluster_spgemm_pairs_sparse", slot_pos=1,
+        chunk=chunk, in_specs=in_specs, out_spec=out_spec,
+        out_shape=(nslabs, block_r, bn),
         interpret=interpret)
 
 
@@ -812,8 +822,9 @@ def cluster_spgemm_pairs_sparse_db(c_slots: jax.Array, slots: jax.Array,
                                        pl.BlockSpec(memory_space=pl.ANY))
     return _stream_call(
         _spgemm_kernel_pairs_sparse_db, (c_slots, slots, a_idx), a_values,
-        b_tiles, slot_pos=1, chunk=chunk, in_specs=in_specs,
-        out_spec=out_spec, out_shape=(nslabs, block_r, bn),
+        b_tiles, name="cluster_spgemm_pairs_sparse_db", slot_pos=1,
+        chunk=chunk, in_specs=in_specs, out_spec=out_spec,
+        out_shape=(nslabs, block_r, bn),
         scratch=(pltpu.VMEM((2, block_k, bn), b_tiles.dtype),
                  pltpu.SemaphoreType.DMA((2,))),
         interpret=interpret)

@@ -110,6 +110,7 @@ def cluster_spmm(tile_ids: jax.Array, a_values: jax.Array, b: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="cluster_spmm",
     )(tile_ids, a_values, b)
 
 
@@ -188,6 +189,7 @@ def cluster_spmm_compact(block_ids: jax.Array, tile_ids: jax.Array,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
+            name="cluster_spmm_compact",
         )(meta, blks, ids, a_values, b, c)
 
     c0 = jnp.zeros((nblocks * block_r, n), b.dtype)

@@ -19,6 +19,7 @@ from repro.core.formats import (BCC, CompactedC, TiledCSR,
                                 live_pair_stream, partition_pair_stream,
                                 revisit_pair_stream, revisit_window_blocks)
 from repro.core.segment import rank_in_segment
+from repro.core.transfer import to_device, to_host
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import get_tracer
 from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs,
@@ -147,10 +148,8 @@ def bcc_compact_stream(a: BCC, *, cover_all_blocks: bool = False
     ``ntiles``; the squeeze is one ``flatnonzero`` + fancy gather.
     Identical stream to :func:`bcc_compact_stream_reference`.
     """
-    ntiles = np.asarray(a.ntiles)
+    ntiles, tile_ids, values = to_host(a.ntiles, a.tile_ids, a.values)
     tpb = a.tiles_per_block
-    tile_ids = np.asarray(a.tile_ids)
-    values = np.asarray(a.values)
     eff = np.maximum(ntiles, 1) if cover_all_blocks else ntiles
     live_mask = np.arange(tpb, dtype=np.int64)[None, :] < eff[:, None]
     keep = np.flatnonzero(live_mask.ravel())
@@ -209,7 +208,7 @@ def bcc_spmm_compact(a: BCC, b: jax.Array, *, bn: int = 128,
         # cover_all_blocks: a block with no live tiles must still appear
         # once so the compact-grid kernel zero-initializes its C strip
         stream = bcc_compact_stream(a, cover_all_blocks=True)
-    block_ids, tile_ids, values = (jnp.asarray(s) for s in stream)
+    block_ids, tile_ids, values = to_device(*stream)
     k_needed = ((a.ncols + a.block_k - 1) // a.block_k) * a.block_k
     if b.shape[0] < k_needed:
         b = jnp.pad(b, ((0, k_needed - b.shape[0]), (0, 0)))
@@ -217,10 +216,12 @@ def bcc_spmm_compact(a: BCC, b: jax.Array, *, bn: int = 128,
     bn_eff = min(bn, max(8, n0))
     b = _pad_cols(b, bn_eff)
     nblocks = (a.nrows + a.block_r - 1) // a.block_r
-    out = cluster_spmm_compact(block_ids, tile_ids, values, b,
-                               block_r=a.block_r, block_k=a.block_k,
-                               nblocks=nblocks, bn=bn_eff,
-                               chunk=stream_chunk(2), interpret=interpret)
+    with get_tracer().span("kernel_variant", variant="spmm_compact"):
+        out = cluster_spmm_compact(block_ids, tile_ids, values, b,
+                                   block_r=a.block_r, block_k=a.block_k,
+                                   nblocks=nblocks, bn=bn_eff,
+                                   chunk=stream_chunk(2),
+                                   interpret=interpret)
     _note_kernel_launch("spmm_compact")
     return out[: a.nrows, : n0]
 
@@ -259,11 +260,11 @@ def build_live_pairs(a: BCC, b: TiledCSR, stream: tuple | None = None
     if stream is None:
         stream = bcc_compact_stream(a, cover_all_blocks=True)
     block_ids, tile_ids = np.asarray(stream[0]), np.asarray(stream[1])
-    ntiles = np.asarray(a.ntiles)
+    ntiles, table = to_host(a.ntiles, b.table)
     step_live = rank_in_segment(block_ids.astype(np.int64)) \
         < ntiles[block_ids]
     return live_pair_stream(
-        block_ids, tile_ids, np.asarray(b.table), nnb=b.nnb,
+        block_ids, tile_ids, table, nnb=b.nnb,
         nblocks=(a.nrows + a.block_r - 1) // a.block_r,
         step_live=step_live)
 
@@ -412,18 +413,18 @@ def bcc_spgemm_sparse_c(a: BCC, b: TiledCSR, *,
                                       bn=b.bn)
     if epilogue != "kernel":
         raise ValueError(f"unknown epilogue '{epilogue}'")
-    values = jnp.asarray(stream[2])
+    values, c_slots, slots, a_idx, table = to_device(
+        stream[2], c_slots, slots, a_idx, table)
     db = double_buffer if double_buffer is not None else on_tpu()
     kernel = (cluster_spgemm_pairs_sparse_db if db
               else cluster_spgemm_pairs_sparse)
     with get_tracer().span("kernel_variant", variant="sparse_c",
                            epilogue="kernel"):
-        slabs = kernel(jnp.asarray(c_slots), jnp.asarray(slots),
-                       jnp.asarray(a_idx), values, b.tiles,
+        slabs = kernel(c_slots, slots, a_idx, values, b.tiles,
                        block_r=a.block_r, block_k=a.block_k, bn=b.bn,
                        nslabs=int(nslabs), chunk=stream_chunk(3),
                        interpret=interpret)
-    out = CompactedC(slabs=slabs, table=jnp.asarray(table),
+    out = CompactedC(slabs=slabs, table=table,
                      nrows=a.nrows, ncols=b.ncols,
                      block_r=a.block_r, bn=b.bn)
     _note_kernel_launch("sparse_c", cc=out)
@@ -504,7 +505,6 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
     if compact:
         if pairs is None:
             pairs = build_live_pairs(a, b, stream)
-        values = jnp.asarray(stream[2])
         if shard_pack is None:
             shard_pack = build_shard_pack(a, b, pairs, shards=shards,
                                           revisit=revisit)
@@ -521,6 +521,7 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
         if shard_pack is not None:
             ranges, shard_pairs, wb = shard_pack
             variant = "sharded_revisit" if wb is not None else "sharded"
+            values, = to_device(stream[2])
             with get_tracer().span("kernel_variant", variant=variant,
                                    shards=len(shard_pairs)):
                 out = cluster_spgemm_pairs_sharded(
@@ -536,7 +537,7 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
             _note_kernel_launch(variant, pairs=pairs, block_r=a.block_r,
                                 block_k=a.block_k, bn=b.bn)
             return out[: a.nrows, : b.ncols]
-        blocks, js, slots, a_idx = (jnp.asarray(p) for p in pairs)
+        values, blocks, js, slots, a_idx = to_device(stream[2], *pairs)
         if resident:
             kernel, variant = cluster_spgemm_pairs_resident, "resident"
         elif double_buffer if double_buffer is not None else on_tpu():
@@ -559,7 +560,7 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
             f"stream and B's tile table, over the {_SMEM_STREAM_BUDGET} B "
             f"budget (C row strip of {b.nnb * b.bn} columns is too wide "
             "for the compacted grid)")
-    block_ids, tile_ids, values = (jnp.asarray(s) for s in stream)
+    block_ids, tile_ids, values = to_device(*stream)
     kernel = cluster_spgemm_resident if resident else cluster_spgemm_tiled
     with get_tracer().span("kernel_variant", variant="padded",
                            resident=bool(resident)):

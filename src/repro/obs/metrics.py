@@ -50,13 +50,6 @@ __all__ = ["METRIC_CATALOG", "Counter", "Gauge", "Histogram",
 _HOST_METRICS: dict[str, tuple[str, str]] = {
     "serve_requests": (
         "counter", "requests received by SpGEMMServer (count)"),
-    "serve_request_s": (
-        "histogram", "end-to-end request wall time (seconds)"),
-    "serve_plan_s": (
-        "histogram", "per-request planning wall time (seconds)"),
-    "serve_execute_s": (
-        "histogram", "per-request execute wall time, device-synced "
-        "(seconds)"),
     "plan_total": (
         "counter", "Planner.plan calls, hits and misses (count)"),
     "plan_cache_hits": (
@@ -79,8 +72,6 @@ _HOST_METRICS: dict[str, tuple[str, str]] = {
         "label (count)"),
     "chain_hops": (
         "counter", "chain-workload hops executed (count)"),
-    "pipeline_stage_s": (
-        "histogram", "planned sparse pipeline stage wall time (seconds)"),
     "audit_records": (
         "counter", "drift-audit samples recorded (count)"),
     "audit_flagged": (

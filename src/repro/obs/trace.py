@@ -23,6 +23,20 @@ https://ui.perfetto.dev to see the nested timeline).
 
 Timing is ``time.perf_counter()`` (monotonic); timestamps in exports are
 seconds (JSONL) / microseconds (Chrome) relative to the tracer's epoch.
+
+4. **One request, one trace across threads.** :meth:`Tracer.join` lets
+   a worker thread open spans inside a trace another thread started
+   (the front end's ``admit`` on the client thread); :meth:`Tracer.record`
+   adds an interval measured elsewhere (the time in the queue, a
+   compile) as a finished span.
+5. **One clock with the device trace.** While enabled, every live span
+   also opens a ``jax.profiler.TraceAnnotation`` of its name, so the
+   spans land in a profiler capture's host plane beside the device
+   operations. JAX is imported lazily: this module needs none.
+
+While any tracer is enabled, a ``jax.monitoring`` listener (registered
+once, on the first :meth:`Tracer.enable`) records each XLA backend
+compile as a finished ``compile`` span in a trace of its own.
 """
 from __future__ import annotations
 
@@ -32,10 +46,15 @@ import json
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Optional
 
-__all__ = ["Span", "Tracer", "get_tracer", "span", "NOOP_SPAN"]
+__all__ = ["Span", "Tracer", "get_tracer", "span", "NOOP_SPAN",
+           "COMPILE_EVENT"]
+
+# the jax.monitoring duration event of one XLA backend compile
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclasses.dataclass(slots=True)
@@ -91,7 +110,7 @@ class _LiveSpan:
     """
 
     __slots__ = ("_tracer", "name", "attrs", "trace_id", "span_id",
-                 "parent_id", "_t0", "_stack_ref")
+                 "parent_id", "_t0", "_stack_ref", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -119,11 +138,16 @@ class _LiveSpan:
             self.trace_id = tr._new_trace_id()
         self.span_id = next(tr._ids)
         stack.append(self)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = self._stack_ref
         # tolerate exceptions unwinding multiple frames at once
         while stack and stack[-1] is not self:
@@ -137,6 +161,81 @@ class _LiveSpan:
         return False
 
 
+class _Joined:
+    """A trace opened on another thread, entered on this one: spans
+    opened inside inherit its ``trace_id`` and take ``span_id`` (the
+    span that opened the trace there) as their parent. Records nothing
+    itself."""
+
+    __slots__ = ("_tracer", "trace_id", "span_id", "_stack_ref")
+
+    def __init__(self, tracer: "Tracer", trace_id: str, span_id: int):
+        self._tracer = tracer
+        self.trace_id = trace_id
+        self.span_id = span_id
+
+    def __enter__(self) -> "_Joined":
+        self._stack_ref = self._tracer._stack()
+        self._stack_ref.append(self)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        stack = self._stack_ref
+        while stack and stack[-1] is not self:
+            stack.pop()
+        if stack:
+            stack.pop()
+        return False
+
+    def set(self, **attrs) -> "_Joined":
+        return self
+
+
+# jax.profiler.TraceAnnotation once resolved; False without JAX
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """A profiler annotation named ``name`` (None without JAX)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name) if _ANNOTATION else None
+
+
+# tracers that record compile spans, and whether the listener is in
+_COMPILE_TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_LISTENING = False
+
+
+def _on_duration_event(event: str, duration: float, **kwargs) -> None:
+    """``jax.monitoring`` duration listener: each backend compile becomes
+    a ``compile`` span, ending now, in every enabled tracer."""
+    if event != COMPILE_EVENT:
+        return
+    for tr in list(_COMPILE_TRACERS):
+        if tr.enabled:
+            tr.record("compile", time.perf_counter() - duration, duration,
+                      root=True, fun_name=str(kwargs.get("fun_name", "")))
+
+
+def _listen_for_compiles(tracer: "Tracer") -> None:
+    global _LISTENING
+    _COMPILE_TRACERS.add(tracer)
+    if _LISTENING:
+        return
+    try:
+        import jax.monitoring
+    except ImportError:
+        return
+    jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
+    _LISTENING = True
+
+
 class Tracer:
     """Process-global span collector with a bounded ring buffer.
 
@@ -144,9 +243,9 @@ class Tracer:
     nothing until :meth:`enable` is called.
     """
 
-    def __init__(self, capacity: int = 8192, enabled: bool = False):
+    def __init__(self, capacity: int = 32768, enabled: bool = False):
         self.capacity = int(capacity)
-        self.enabled = bool(enabled)
+        self.enabled = False
         self.dropped = 0
         # ring of raw span tuples (Span field order) — see spans()
         self._buf: deque[tuple] = deque(maxlen=self.capacity)
@@ -154,6 +253,8 @@ class Tracer:
         self._ids = itertools.count(1)
         self._traces = itertools.count(1)
         self._epoch = time.perf_counter()
+        if enabled:
+            self.enable()
 
     # -- span creation -------------------------------------------------------
 
@@ -172,6 +273,33 @@ class Tracer:
         stack = self._stack()
         return stack[-1] if stack else None
 
+    def join(self, trace_id: str, span_id: int = 0):
+        """Continue, on this thread, the trace ``trace_id`` that span
+        ``span_id`` opened on another: ``with tracer.join(tid, sid):``.
+        Spans opened inside inherit the trace and take ``span_id`` as
+        their parent. The no-op singleton when disabled or when
+        ``trace_id`` is empty (the trace was opened while disabled)."""
+        if not self.enabled or not trace_id:
+            return NOOP_SPAN
+        return _Joined(self, trace_id, span_id)
+
+    def record(self, name: str, t0: float, duration: float, *,
+               root: bool = False, **attrs) -> None:
+        """Record a finished span that started at the ``perf_counter``
+        reading ``t0`` and lasted ``duration`` seconds, as a child of the
+        innermost open span of this thread (or with ``root``, or outside
+        any span, in a trace of its own). A no-op when disabled."""
+        if not self.enabled:
+            return
+        stack = self._stack()
+        if stack and not root:
+            trace_id, parent_id = stack[-1].trace_id, stack[-1].span_id
+        else:
+            trace_id, parent_id = self._new_trace_id(), 0
+        self._record((name, trace_id, next(self._ids), parent_id,
+                      t0 - self._epoch, duration, attrs,
+                      threading.get_ident() & 0x7FFFFFFF))
+
     # -- state ---------------------------------------------------------------
 
     def enable(self, capacity: Optional[int] = None) -> "Tracer":
@@ -179,6 +307,7 @@ class Tracer:
             self.capacity = int(capacity)
             self._buf = deque(self._buf, maxlen=self.capacity)
         self.enabled = True
+        _listen_for_compiles(self)
         return self
 
     def disable(self) -> "Tracer":

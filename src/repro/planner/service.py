@@ -52,6 +52,7 @@ from repro.core.spgemm import (length_bins, slot_rows_host,
                                spgemm_clusterwise_dense_binned,
                                spgemm_rowwise_dense_binned, spmm_clusterwise,
                                spmm_rowwise)
+from repro.core.transfer import device_nbytes, to_device
 from repro.kernels import ops as kernel_ops
 from repro.obs import audit as obs_audit
 from repro.obs import metrics as obs_metrics
@@ -565,7 +566,7 @@ class Planner:
             return self._execute_impl(plan, a, b)
         key = policy.triple(plan.fingerprint, plan.scheme, plan.reorder)
         try:
-            out = self._guarded_execute(plan, a, b)
+            out = self._execute_impl(plan, a, b)
         except Exception as e:           # noqa: BLE001 — ladder catches all
             primary = e                  # outlives the except block
             policy.breaker.record_failure(key)
@@ -595,7 +596,7 @@ class Planner:
             return self._execute_impl(plan, a, b)
         key = policy.triple(plan.fingerprint, plan.scheme, plan.reorder)
         try:
-            out = self._guarded_execute(plan, a, b)
+            out = self._execute_impl(plan, a, b)
         except Exception as e:           # noqa: BLE001 — batcher disbands
             policy.breaker.record_failure(key)
             policy.record_incident(
@@ -630,9 +631,9 @@ class Planner:
                         # in production nothing is armed; under the chaos
                         # harness it runs fault-suppressed
                         with _faults.suppressed():
-                            out = self._guarded_execute(fb, a, b)
+                            out = self._execute_impl(fb, a, b)
                     else:
-                        out = self._guarded_execute(fb, a, b)
+                        out = self._execute_impl(fb, a, b)
                 except Exception as e:   # noqa: BLE001 — ladder walks on
                     causes.append((rung, e))
                     sp.set(recovered=False)
@@ -651,19 +652,20 @@ class Planner:
             error=primary, fallback="")
         raise LadderExhaustedError(plan.scheme, causes) from primary
 
-    def _guarded_execute(self, plan: Plan, a: HostCSR,
-                         b: HostCSR | np.ndarray | None) -> np.ndarray:
-        """One execution under the output guard: the chaos harness's
-        ``output`` site corrupts here, and non-finite results raise (a
-        single ``np.sum`` reduction propagates any NaN/Inf)."""
-        out = self._execute_impl(plan, a, b)
+    @staticmethod
+    def _guard(plan: Plan, out) -> np.ndarray:
+        """The output guard, timed as the ``guard`` span: the chaos
+        harness's ``output`` site corrupts here, and non-finite results
+        raise (a single ``np.sum`` reduction propagates any NaN/Inf)."""
         out = _faults.corrupt_output("output", out)
-        # np.asarray first: on a device array, np.sum would dispatch a
-        # traced jax reduction that silently truncates the requested
-        # float64 accumulator to f32 — the host-side f64 sum is both the
-        # intended overflow-safe accumulation and cheaper
-        if not np.isfinite(np.sum(np.asarray(out), dtype=np.float64)):
-            raise NonFiniteOutputError(plan.scheme)
+        with get_tracer().span("guard"):
+            # np.asarray first: on a device array, np.sum would dispatch
+            # a traced jax reduction that silently truncates the
+            # requested float64 accumulator to f32 — the host-side f64
+            # sum is both the intended overflow-safe accumulation and
+            # cheaper
+            if not np.isfinite(np.sum(np.asarray(out), dtype=np.float64)):
+                raise NonFiniteOutputError(plan.scheme)
         return out
 
     @staticmethod
@@ -693,8 +695,10 @@ class Planner:
 
     def _execute_impl(self, plan: Plan, a: HostCSR,
                       b: HostCSR | np.ndarray | None = None) -> np.ndarray:
-        """:meth:`execute` minus the resilience ladder (the raw path the
-        overhead benchmark baselines against)."""
+        """One execution, without the ladder's rungs. With the ladder
+        off it is the raw path the overhead benchmark baselines against;
+        with it armed, the output guard runs last, inside the
+        ``execute`` span."""
         tracer = get_tracer()
         with tracer.span("execute", fingerprint=plan.fingerprint,
                          scheme=plan.scheme, reorder=plan.reorder,
@@ -711,7 +715,8 @@ class Planner:
                     sp.set(predicted_rel=rec.predicted_rel,
                            measured_rel=rec.measured_rel,
                            residual=rec.residual)
-            return out
+            return (self._guard(plan, out) if self.resilience.ladder
+                    else out)
 
     # -- chained products (workload="chain") ---------------------------------
 
@@ -876,7 +881,7 @@ class Planner:
         perm = plan.perm
 
         if dense_b:
-            bd = jnp.asarray(np.asarray(b, dtype=np.float32))
+            bd, = to_device(np.asarray(b, dtype=np.float32))
             if cached is None:
                 with get_tracer().span("pack", fingerprint=plan.fingerprint,
                                        scheme=plan.scheme, kind="dense_b"):
@@ -1021,19 +1026,28 @@ class Planner:
         # block_until_ready before np.asarray: the conversion would sync
         # anyway, but syncing explicitly makes every timed region around a
         # runner measure device completion, not dispatch (and it is a
-        # no-op passthrough for host-side numpy results)
-        if perm is None:
-            return lambda: np.asarray(jax.block_until_ready(run()))
-        p = np.asarray(perm, dtype=np.int64)
+        # no-op passthrough for host-side numpy results). The wait is the
+        # ``sync`` span; the copy to the host and the unpermute scatter
+        # the ``fetch`` span.
+        p = None if perm is None else np.asarray(perm, dtype=np.int64)
 
         def wrapped():
-            cp = np.asarray(jax.block_until_ready(run()))
-            out = np.empty_like(cp)
-            if rows_only:
-                out[p] = cp
-            else:
-                out[np.ix_(p, p)] = cp
-            return out
+            tracer = get_tracer()
+            res = run()
+            with tracer.span("sync"):
+                res = jax.block_until_ready(res)
+            with tracer.span("fetch") as sp:
+                cp = np.asarray(res)
+                if tracer.enabled:
+                    sp.set(bytes=device_nbytes(res))
+                if p is None:
+                    return cp
+                out = np.empty_like(cp)
+                if rows_only:
+                    out[p] = cp
+                else:
+                    out[np.ix_(p, p)] = cp
+                return out
         return wrapped
 
     @property

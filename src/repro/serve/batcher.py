@@ -190,9 +190,8 @@ class Batcher:
         plan_s, exec_s = (t1 - t0) / len(valid), (t2 - t1) / len(valid)
         served: list[tuple[QueuedRequest, object]] = list(rejected)
         for req, block in zip(valid, parts):
-            # per-request serve_* histograms mirror the unbatched
-            # boundary; plan/execute wall time is apportioned evenly —
-            # the launch is shared, so is its cost
+            # plan/execute wall time is apportioned evenly — the launch
+            # is shared, so is its cost
             resp = SpGEMMResponse(
                 result=block, fingerprint=req.fingerprint,
                 reorder=plan.reorder, scheme=plan.scheme,
@@ -202,11 +201,5 @@ class Batcher:
                 plan_s=plan_s, execute_s=exec_s,
                 batched=True, batch_size=len(valid))
             reg.counter("serve_requests", tenant=req.tenant).inc()
-            reg.histogram("serve_request_s", tenant=req.tenant,
-                          scheme=plan.scheme).observe(plan_s + exec_s)
-            reg.histogram("serve_plan_s",
-                          tenant=req.tenant).observe(plan_s)
-            reg.histogram("serve_execute_s",
-                          tenant=req.tenant).observe(exec_s)
             served.append((req, resp))
         return served

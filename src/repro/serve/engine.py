@@ -123,9 +123,11 @@ class SpGEMMServer:
         ``plan_cache_hit`` true only when *every* hop hit the cache (the
         steady serving state for a recurring chain).
 
-        Each request runs under a root ``request`` span (its trace id is
-        returned as ``SpGEMMResponse.trace_id`` when tracing is on) and
-        feeds the per-tenant ``serve_*`` metrics.
+        Each request runs under a ``request`` span, after a ``validate``
+        span for the operand checks (its trace id is returned as
+        ``SpGEMMResponse.trace_id`` when tracing is on; under the async
+        front end both join the trace its ``admit`` span opened), and
+        counts in the per-tenant ``serve_requests`` metric.
 
         With the resilience policy's validation armed (the default),
         malformed operands — a non-monotone ``indptr``, out-of-range or
@@ -156,30 +158,28 @@ class SpGEMMServer:
         reg = obs_metrics.get_registry()
         reg.counter("serve_requests", tenant=self.tenant).inc()
         policy = self.planner.resilience
+        tracer = get_tracer()
         if policy.validate:
-            try:
-                validate_request_pair(a, b, skip=policy.is_validated)
-            except InvalidOperandError as e:
-                policy.rejects += 1
-                reg.counter("serve_rejects", tenant=self.tenant,
-                            field=e.field).inc()
-                raise
-            policy.mark_validated(a)
-            if b is not None and hasattr(b, "indptr"):
-                policy.mark_validated(b)
-        with get_tracer().span("request", tenant=self.tenant,
-                               workload=workload) as root:
+            with tracer.span("validate") as sp:
+                if tracer.enabled:
+                    sp.set(skipped_a=policy.is_validated(a))
+                try:
+                    validate_request_pair(a, b, skip=policy.is_validated)
+                except InvalidOperandError as e:
+                    policy.rejects += 1
+                    reg.counter("serve_rejects", tenant=self.tenant,
+                                field=e.field).inc()
+                    raise
+                policy.mark_validated(a)
+                if b is not None and hasattr(b, "indptr"):
+                    policy.mark_validated(b)
+        with tracer.span("request", tenant=self.tenant,
+                         workload=workload) as root:
             resp = self._submit_impl(a, b, hint=hint, hops=hops,
                                      workload=workload)
             resp.trace_id = root.trace_id
             root.set(fingerprint=resp.fingerprint, scheme=resp.scheme,
                      cache_hit=resp.plan_cache_hit)
-        reg.histogram("serve_request_s", tenant=self.tenant,
-                      scheme=resp.scheme).observe(resp.plan_s
-                                                  + resp.execute_s)
-        reg.histogram("serve_plan_s", tenant=self.tenant).observe(resp.plan_s)
-        reg.histogram("serve_execute_s",
-                      tenant=self.tenant).observe(resp.execute_s)
         return resp
 
     def _submit_impl(self, a: HostCSR, b, *, hint: Optional[int],
@@ -201,8 +201,6 @@ class SpGEMMServer:
             degraded = policy.fallbacks > inc0
             # truthful chain planning time: the sum of the per-hop
             # planning wall times execute_chain annotates on each plan
-            # (previously hardcoded 0.0, which made the serve_plan_s
-            # histogram lie for chain traffic)
             plan_s = sum(getattr(p, "plan_wall_s", 0.0) for p in plans)
             return SpGEMMResponse(
                 result=out, fingerprint=lead.fingerprint,

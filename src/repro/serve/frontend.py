@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.core.formats import HostCSR
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import get_tracer
 from repro.planner.features import fingerprint
 from repro.planner.service import _value_digest
 from repro.resilience.errors import DeadlineExceededError, OverloadError
@@ -158,34 +159,51 @@ class AsyncSpGEMMServer:
         """
         if self._closed:
             raise OverloadError("shutdown", tenant=tenant)
-        fp = self._fingerprint(a)
-        self.estimator.observe(fp)     # arrivals count even when shed
-        now = self.clock()
-        req = QueuedRequest(a=a, b=b, hops=hops, tenant=tenant,
-                            fingerprint=fp, reuse_hint=reuse_hint,
-                            deadline_s=deadline_s or 0.0,
-                            enqueued_at=now,
-                            coalesce_key=self._coalesce_key(fp, a, b, hops))
-        if deadline_s is not None:
-            req.deadline_at = now + float(deadline_s)
-            self._admission_deadline(req, fp, float(deadline_s), tenant)
-        reg = obs_metrics.get_registry()
-        with self._mu:
-            waiters = self._inflight.get(req.coalesce_key)
-            if waiters is not None:
-                # identical request already in flight: ride its latch
-                waiters.append(req.ticket)
-                reg.counter("serve_coalesced", tenant=tenant).inc()
-                return req.ticket
-            try:
-                depth = self.queue.offer(req)
-            except OverloadError as e:
-                self._note_shed(e.reason, tenant)
-                raise
-            if req.coalesce_key:
-                self._inflight[req.coalesce_key] = []
-            self._update_pressure(depth)
-        reg.gauge("serve_queue_depth").set(depth)
+        tracer = get_tracer()
+        # the request's trace opens here, on the client's thread: the
+        # fingerprint, the coalescing digests and the queue offer
+        with tracer.span("admit", tenant=tenant, coalesced=False,
+                         shed="") as sp:
+            fp = self._fingerprint(a)
+            self.estimator.observe(fp)     # arrivals count even when shed
+            now = self.clock()
+            req = QueuedRequest(a=a, b=b, hops=hops, tenant=tenant,
+                                fingerprint=fp, reuse_hint=reuse_hint,
+                                deadline_s=deadline_s or 0.0,
+                                enqueued_at=now,
+                                coalesce_key=self._coalesce_key(fp, a, b,
+                                                                hops),
+                                trace_id=sp.trace_id,
+                                admit_span_id=sp.span_id)
+            if deadline_s is not None:
+                req.deadline_at = now + float(deadline_s)
+                try:
+                    self._admission_deadline(req, fp, float(deadline_s),
+                                             tenant)
+                except DeadlineExceededError:
+                    sp.set(shed="deadline")
+                    raise
+            reg = obs_metrics.get_registry()
+            with self._mu:
+                waiters = self._inflight.get(req.coalesce_key)
+                if waiters is not None:
+                    # identical request already in flight: ride its latch
+                    waiters.append(req.ticket)
+                    reg.counter("serve_coalesced", tenant=tenant).inc()
+                    sp.set(coalesced=True)
+                    return req.ticket
+                if tracer.enabled:
+                    req.queued_perf = time.perf_counter()
+                try:
+                    depth = self.queue.offer(req)
+                except OverloadError as e:
+                    self._note_shed(e.reason, tenant)
+                    sp.set(shed=e.reason)
+                    raise
+                if req.coalesce_key:
+                    self._inflight[req.coalesce_key] = []
+                self._update_pressure(depth)
+            reg.gauge("serve_queue_depth").set(depth)
         return req.ticket
 
     def submit_wait(self, a: HostCSR, b=None, *,
@@ -288,7 +306,9 @@ class AsyncSpGEMMServer:
         exactly as the in-process queue-deadline check."""
         reg = obs_metrics.get_registry()
         now = self.clock()
-        reg.gauge("serve_queue_depth").set(self.queue.depth())
+        depth = self.queue.depth()
+        self._note_dequeue(req, depth)
+        reg.gauge("serve_queue_depth").set(depth)
         reg.histogram("serve_queue_wait_s",
                       tenant=req.tenant).observe(now - req.enqueued_at)
         reg.counter("serve_deadline_miss", stage="queue",
@@ -296,6 +316,17 @@ class AsyncSpGEMMServer:
         self._resolve_error(req, DeadlineExceededError(
             "queue", deadline_s=req.deadline_s,
             waited_s=now - req.enqueued_at))
+
+    @staticmethod
+    def _note_dequeue(req: QueuedRequest, depth: int) -> None:
+        """Record ``req``'s ``queue`` span in its trace: from its offer to
+        the queue until now, when a worker took it up."""
+        tracer = get_tracer()
+        if tracer.enabled and req.trace_id:
+            with tracer.join(req.trace_id, req.admit_span_id):
+                tracer.record("queue", req.queued_perf,
+                              time.perf_counter() - req.queued_perf,
+                              depth=depth)
 
     def _process(self, req: QueuedRequest, *, dequeued: bool = False) -> None:
         """Execute one dequeued request; every outcome — response,
@@ -310,7 +341,9 @@ class AsyncSpGEMMServer:
         reg = obs_metrics.get_registry()
         if not dequeued:
             now = self.clock()
-            reg.gauge("serve_queue_depth").set(self.queue.depth())
+            depth = self.queue.depth()
+            self._note_dequeue(req, depth)
+            reg.gauge("serve_queue_depth").set(depth)
             reg.histogram("serve_queue_wait_s",
                           tenant=req.tenant).observe(now - req.enqueued_at)
             if req.deadline_at is not None and now >= req.deadline_at:
@@ -327,8 +360,11 @@ class AsyncSpGEMMServer:
             reg.counter("serve_downgrades", tenant=req.tenant).inc()
             self.server.planner.resilience.downgrades += 1
         try:
-            resp = self.server.submit(req.a, req.b, reuse_hint=hint,
-                                      hops=req.hops)
+            # validation, the request span and all below it join the
+            # trace that admit opened on the client's thread
+            with get_tracer().join(req.trace_id, req.admit_span_id):
+                resp = self.server.submit(req.a, req.b, reuse_hint=hint,
+                                          hops=req.hops)
         except Exception as e:        # noqa: BLE001 — ticket carries it
             self._resolve_error(req, e)
             return
@@ -342,8 +378,10 @@ class AsyncSpGEMMServer:
         individually ladder-guarded singles path."""
         reg = obs_metrics.get_registry()
         now = self.clock()
-        reg.gauge("serve_queue_depth").set(self.queue.depth())
+        depth = self.queue.depth()
+        reg.gauge("serve_queue_depth").set(depth)
         for req in group:
+            self._note_dequeue(req, depth)
             reg.histogram("serve_queue_wait_s",
                           tenant=req.tenant).observe(now - req.enqueued_at)
         outcomes = self.batcher.execute(group)

@@ -83,6 +83,12 @@ class QueuedRequest:
     deadline_s: float = 0.0            # the original relative budget
     enqueued_at: float = 0.0
     downgrade: bool = False            # admission chose the identity rung
+    # the request's trace, opened by the front end's ``admit`` span on
+    # the client thread and joined by the worker ("" when tracing is
+    # off), and the perf_counter reading at which it entered the queue
+    trace_id: str = ""
+    admit_span_id: int = 0
+    queued_perf: float = 0.0
 
 
 class BoundedRequestQueue:

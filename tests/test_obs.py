@@ -187,7 +187,7 @@ def test_labels_key_instruments_and_empty_labels_drop():
 
 def test_histogram_snapshot_percentiles():
     reg = MetricsRegistry()
-    h = reg.histogram("serve_request_s")
+    h = reg.histogram("batch_occupancy")
     for v in range(1, 101):
         h.observe(v / 100.0)
     snap = h.snapshot()

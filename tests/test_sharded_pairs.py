@@ -141,6 +141,18 @@ def test_pairless_block_sentinel_lands_in_exactly_one_shard():
         assert np.any((blocks_np == blk) & (slots_np == 0))
 
 
+def test_partition_ties_take_the_earliest_block():
+    """Blocks 0 and 1 hold no live pair, so every boundary up to block 2
+    is equally far from the half-way count: the first one wins, as in
+    the loop reference (hypothesis found n=22, m=16, seed=1)."""
+    pairs = ([0, 1, 2, 2, 2, 2, 2, 2], [0] * 8, [0, 0, 1, 0, 0, 0, 0, 0],
+             [0, 1, 2, 2, 2, 2, 2, 2])
+    ranges, _ = partition_pair_stream(pairs, nblocks=3, num_shards=2)
+    ref, _ = partition_pair_stream_reference(pairs, nblocks=3,
+                                             num_shards=2)
+    assert ranges.tolist() == ref.tolist() == [[0, 1], [1, 3]]
+
+
 def test_num_shards_clipped_to_nblocks():
     a = rand_host(16, 16, 0.3, 4)          # 2 row blocks
     _, _, _, pairs = _pack(a, a)
