@@ -18,7 +18,7 @@ ragged footprint the paper reports (Fig. 11) is computed analytically by
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +37,7 @@ __all__ = [
     "CSR",
     "CSRCluster",
     "BCC",
+    "BCCShape",
     "TiledCSR",
     "CompactedC",
     "csr_from_host",
@@ -44,8 +45,11 @@ __all__ = [
     "csr_cluster_from_host_reference",
     "bcc_from_host",
     "bcc_from_host_reference",
+    "bcc_layout",
     "tiled_csr_from_host",
     "tiled_csr_from_host_reference",
+    "tiled_layout",
+    "scatter_map",
     "tiled_live_tiles",
     "select_block_k",
     "live_pair_stream",
@@ -195,29 +199,34 @@ class HostCSR:
 
     def permute_rows(self, perm: np.ndarray) -> "HostCSR":
         """Return A[perm, :] — ``perm[new_row] = old_row``."""
+        return self.permuted(perm, symmetric=False)[0]
+
+    def permute_symmetric(self, perm: np.ndarray) -> "HostCSR":
+        """Return PAPᵀ — rows and columns permuted together (square only)."""
+        return self.permuted(perm, symmetric=True)[0]
+
+    def permuted(self, perm: np.ndarray, *, symmetric: bool
+                 ) -> tuple["HostCSR", np.ndarray]:
+        """``(PAPᵀ if symmetric else A[perm, :], src)``: ``src[i]`` is the
+        nonzero of ``self`` that the permuted matrix's nonzero ``i`` came
+        from, so its ``data`` is ``self.data[src]``."""
+        if symmetric and self.nrows != self.ncols:
+            raise ValueError("symmetric permutation needs a square matrix")
         perm = np.asarray(perm, dtype=np.int64)
         counts = self.row_nnz()[perm]
         indptr = np.zeros(self.nrows + 1, dtype=np.int64)
         np.cumsum(counts, out=indptr[1:])
-        gather = ragged_gather_indices(self.indptr[perm], counts)
-        return HostCSR(indptr, self.indices[gather], self.data[gather],
-                       self.shape)
-
-    def permute_symmetric(self, perm: np.ndarray) -> "HostCSR":
-        """Return PAPᵀ — rows and columns permuted together (square only)."""
-        if self.nrows != self.ncols:
-            raise ValueError("symmetric permutation needs a square matrix")
-        perm = np.asarray(perm, dtype=np.int64)
-        inv = np.empty_like(perm)
-        inv[perm] = np.arange(perm.shape[0])
-        rowperm = self.permute_rows(perm)
-        # remap then segmented-sort column ids within each row: one lexsort
-        # keyed (row, newcol) re-sorts every row at once
-        newcols = inv[rowperm.indices.astype(np.int64)].astype(np.int32)
-        rows = expand_indptr(rowperm.indptr)
-        order = np.lexsort((newcols, rows))
-        return HostCSR(rowperm.indptr, newcols[order], rowperm.data[order],
-                       self.shape)
+        src = ragged_gather_indices(self.indptr[perm], counts)
+        indices = self.indices[src]
+        if symmetric:
+            inv = np.empty_like(perm)
+            inv[perm] = np.arange(perm.shape[0])
+            # remap then segmented-sort column ids within each row: one
+            # lexsort keyed (row, newcol) re-sorts every row at once
+            newcols = inv[indices.astype(np.int64)].astype(np.int32)
+            order = np.lexsort((newcols, expand_indptr(indptr)))
+            src, indices = src[order], newcols[order]
+        return HostCSR(indptr, indices, self.data[src], self.shape), src
 
     def jaccard(self, i: int, j: int) -> float:
         """Jaccard similarity of the column-id sets of rows i and j."""
@@ -490,6 +499,16 @@ class BCC:
         return out[: self.nrows, : self.ncols]
 
 
+class BCCShape(NamedTuple):
+    """What the Sp×Sp launchers read of a :class:`BCC` once its compact
+    stream is packed: its shape and blocking, without its value lattice."""
+
+    nrows: int
+    ncols: int
+    block_r: int
+    block_k: int
+
+
 @_register
 @dataclasses.dataclass(frozen=True)
 class TiledCSR:
@@ -754,10 +773,31 @@ def bcc_from_host(h: HostCSR, block_r: int = 8, block_k: int = 128,
     """Pack a (reordered) HostCSR into BCC tiles.
 
     Vectorized: per-block tile discovery is one argsort over the
-    ``block_id * nk + col // block_k`` key; slab fill is one fancy-indexed
-    assignment at (tile_slot, row % block_r, col % block_k). Identical
-    layout to :func:`bcc_from_host_reference`.
+    ``block_id * nk + col // block_k`` key (:func:`bcc_layout`); slab fill
+    is one fancy-indexed assignment at each nonzero's lattice position.
+    Identical layout to :func:`bcc_from_host_reference`.
     """
+    tile_ids, ntiles, tpb, pos = bcc_layout(h, block_r, block_k,
+                                            tiles_per_block)
+    values = np.zeros((ntiles.shape[0] * tpb, block_r, block_k),
+                      dtype=np.float32)
+    values.reshape(-1)[pos] = h.data
+    # imported here: repro.obs, which transfer uses, imports this module
+    from repro.core.transfer import to_device
+    tile_ids, values, ntiles = to_device(tile_ids, values, ntiles,
+                                         dtypes=(None, dtype, None))
+    return BCC(tile_ids=tile_ids, values=values, ntiles=ntiles,
+               nrows=h.nrows, ncols=h.ncols,
+               block_r=block_r, block_k=block_k, tiles_per_block=tpb)
+
+
+def bcc_layout(h: HostCSR, block_r: int = 8, block_k: int = 128,
+               tiles_per_block: int | None = None
+               ) -> tuple[np.ndarray, np.ndarray, int, np.ndarray]:
+    """The value-free part of :func:`bcc_from_host`, on the host:
+    ``(tile_ids, ntiles, tiles_per_block, pos)``, where ``pos[i]`` is the
+    flat index of nonzero ``i`` in the ``(nblocks * tiles_per_block,
+    block_r, block_k)`` value lattice."""
     nb = (h.nrows + block_r - 1) // block_r
     nk = (h.ncols + block_k - 1) // block_k
     rows = expand_indptr(h.indptr)
@@ -781,18 +821,10 @@ def bcc_from_host(h: HostCSR, block_r: int = 8, block_k: int = 128,
     flat = ublk * tpb + rank
     tile_ids = np.zeros(nb * tpb, dtype=np.int32)
     tile_ids[flat] = (ukey % nk).astype(np.int32)
-    values = np.zeros((nb * tpb, block_r, block_k), dtype=np.float32)
     nnz_flat = np.empty(h.nnz, dtype=np.int64)
     nnz_flat[order] = flat[slot_sorted]
-    values[nnz_flat, rows % block_r, cols % block_k] = h.data
-    ntiles = per_block.astype(np.int32)
-    # imported here: repro.obs, which transfer uses, imports this module
-    from repro.core.transfer import to_device
-    tile_ids, values, ntiles = to_device(tile_ids, values, ntiles,
-                                         dtypes=(None, dtype, None))
-    return BCC(tile_ids=tile_ids, values=values, ntiles=ntiles,
-               nrows=h.nrows, ncols=h.ncols,
-               block_r=block_r, block_k=block_k, tiles_per_block=tpb)
+    pos = (nnz_flat * block_r + rows % block_r) * block_k + cols % block_k
+    return tile_ids, per_block.astype(np.int32), tpb, pos
 
 
 def bcc_from_host_reference(h: HostCSR, block_r: int = 8, block_k: int = 128,
@@ -845,10 +877,25 @@ def tiled_csr_from_host(h: HostCSR, block_k: int = 128, bn: int = 128,
     Vectorized: live-tile discovery is one argsort over the
     ``(row // block_k) * nnb + col // bn`` key; the table is one
     :func:`repro.core.segment.key_table` scatter (``base=1`` — slot 0 is
-    the reserved zero tile); the slab fill is one fancy-indexed assignment
-    at (slot, row % block_k, col % bn). Identical layout to
-    :func:`tiled_csr_from_host_reference`.
+    the reserved zero tile) (:func:`tiled_layout`); the slab fill is one
+    fancy-indexed assignment at each nonzero's tile-store position.
+    Identical layout to :func:`tiled_csr_from_host_reference`.
     """
+    table, cap, pos = tiled_layout(h, block_k, bn, tile_cap)
+    tiles = np.zeros((cap, block_k, bn), dtype=np.float32)
+    tiles.reshape(-1)[pos] = h.data
+    from repro.core.transfer import to_device
+    tiles, table = to_device(tiles, table, dtypes=(dtype, None))
+    return TiledCSR(tiles=tiles, table=table,
+                    nrows=h.nrows, ncols=h.ncols, block_k=block_k, bn=bn)
+
+
+def tiled_layout(h: HostCSR, block_k: int = 128, bn: int = 128,
+                 tile_cap: int | None = None
+                 ) -> tuple[np.ndarray, int, np.ndarray]:
+    """The value-free part of :func:`tiled_csr_from_host`, on the host:
+    ``(table, tile_cap, pos)``, where ``pos[i]`` is the flat index of
+    nonzero ``i`` in the ``(tile_cap, block_k, bn)`` tile store."""
     nkb = (h.nrows + block_k - 1) // block_k
     nnb = (h.ncols + bn - 1) // bn
     rows = expand_indptr(h.indptr)
@@ -865,15 +912,26 @@ def tiled_csr_from_host(h: HostCSR, block_k: int = 128, bn: int = 128,
         raise ValueError(f"tile_cap {cap} < live tiles + zero tile "
                          f"{nlive + 1}")
     table = key_table(ukey, nkb * nnb, base=1)
-    tiles = np.zeros((cap, block_k, bn), dtype=np.float32)
-    if h.nnz:
-        slot = np.empty(h.nnz, dtype=np.int64)
-        slot[order] = slot_sorted
-        tiles[slot, rows % block_k, cols % bn] = h.data
-    from repro.core.transfer import to_device
-    tiles, table = to_device(tiles, table, dtypes=(dtype, None))
-    return TiledCSR(tiles=tiles, table=table,
-                    nrows=h.nrows, ncols=h.ncols, block_k=block_k, bn=bn)
+    slot = np.empty(h.nnz, dtype=np.int64)
+    slot[order] = slot_sorted
+    return table, cap, (slot * block_k + rows % block_k) * bn + cols % bn
+
+
+def scatter_map(pos: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(src, dst)`` of a value fill ``out.flat[pos] = data``, one pair per
+    distinct position: ``dst`` ascending, and ``src`` the nonzero whose
+    value stays there, the last one written as numpy's assignment keeps
+    it. ``out.flat[dst] = data[src]`` then writes each position once.
+
+    >>> src, dst = scatter_map(np.array([5, 2, 5]))
+    >>> src.tolist(), dst.tolist()
+    ([1, 2], [2, 5])
+    """
+    order = np.argsort(pos, kind="stable")
+    spos = pos[order]
+    last = np.ones(spos.shape[0], dtype=bool)
+    last[:-1] = spos[1:] != spos[:-1]
+    return order[last], spos[last]
 
 
 def tiled_csr_from_host_reference(h: HostCSR, block_k: int = 128,

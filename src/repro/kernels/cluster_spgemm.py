@@ -586,16 +586,18 @@ def cluster_spgemm_pairs_window(wins: jax.Array, blocks: jax.Array,
 def _stack_shard_streams(shard_pairs) -> tuple:
     """Pad every shard's sub-stream to the longest one (zero-slot repeats
     of its last pair — the live_pair_stream tail convention) and stack
-    into (S, T_max) arrays so shard_map sees a rectangular layout."""
+    into (S, T_max) arrays so shard_map sees a rectangular layout. Host
+    sub-streams stack on the host, device ones on the device."""
+    xp = jnp if isinstance(shard_pairs[0][0], jax.Array) else np
     t_max = max(p[0].shape[0] for p in shard_pairs)
     cols = [[], [], [], []]
     for sb, sj, ss, sa in shard_pairs:
         pad = t_max - sb.shape[0]
-        cols[0].append(np.concatenate([sb, np.repeat(sb[-1], pad)]))
-        cols[1].append(np.concatenate([sj, np.repeat(sj[-1], pad)]))
-        cols[2].append(np.concatenate([ss, np.zeros(pad, ss.dtype)]))
-        cols[3].append(np.concatenate([sa, np.repeat(sa[-1], pad)]))
-    return tuple(np.stack(c).astype(np.int32) for c in cols)
+        cols[0].append(xp.concatenate([sb, xp.repeat(sb[-1:], pad)]))
+        cols[1].append(xp.concatenate([sj, xp.repeat(sj[-1:], pad)]))
+        cols[2].append(xp.concatenate([ss, xp.zeros(pad, ss.dtype)]))
+        cols[3].append(xp.concatenate([sa, xp.repeat(sa[-1:], pad)]))
+    return tuple(xp.stack(c).astype(xp.int32) for c in cols)
 
 
 def _shard_local_call(blocks, js, slots, a_idx, a_values, b_tiles, *,

@@ -7,17 +7,21 @@ CI (CPU, interpret=True) and production (TPU, compiled).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.formats import (BCC, CompactedC, TiledCSR,
-                                compacted_c_counters, compacted_c_from_dense,
-                                compacted_c_table, live_pair_counters,
-                                live_pair_stream, partition_pair_stream,
-                                revisit_pair_stream, revisit_window_blocks)
+from repro.core.formats import (BCC, BCCShape, CompactedC, HostCSR,
+                                TiledCSR, bcc_layout, compacted_c_counters,
+                                compacted_c_from_dense, compacted_c_table,
+                                live_pair_counters, live_pair_stream,
+                                partition_pair_stream, revisit_pair_stream,
+                                revisit_window_blocks, scatter_map,
+                                tiled_layout)
 from repro.core.segment import rank_in_segment
 from repro.core.transfer import to_device, to_host
 from repro.obs import metrics as obs_metrics
@@ -40,7 +44,8 @@ __all__ = ["on_tpu", "pallas_shard_count", "stream_chunk", "bcc_spmm",
            "bcc_spmm_compact", "build_live_pairs", "build_shard_pack",
            "build_sparse_c_pairs", "predict_c_window_density",
            "compact_grid_ok", "compact_grid_ok_ncols", "bcc_spgemm_tiled",
-           "bcc_spgemm_sparse_c", "flash_mha", "fused_ssd"]
+           "bcc_spgemm_sparse_c", "SpGEMMPattern", "pack_spgemm_pattern",
+           "flash_mha", "fused_ssd"]
 
 # VMEM budget for pinning TiledCSR's tile store on-chip (leave headroom for
 # the A slab / C tile double buffers out of the 16 MiB core budget)
@@ -149,24 +154,28 @@ def bcc_compact_stream(a: BCC, *, cover_all_blocks: bool = False
     Identical stream to :func:`bcc_compact_stream_reference`.
     """
     ntiles, tile_ids, values = to_host(a.ntiles, a.tile_ids, a.values)
-    tpb = a.tiles_per_block
+    keep, live = _compact_keep(ntiles, a.tiles_per_block, cover_all_blocks)
+    vals = values[keep]
+    vals[live:] = 0.0
+    # slabs of empty blocks (cover_all_blocks) are all-zero by construction
+    # in the padded lattice, so their steps contribute nothing
+    return ((keep // a.tiles_per_block).astype(np.int32),
+            tile_ids[keep].astype(np.int32), vals)
+
+
+def _compact_keep(ntiles: np.ndarray, tpb: int, cover_all_blocks: bool
+                  ) -> tuple[np.ndarray, int]:
+    """``(keep, live)``: the value-lattice slot of each step of the compact
+    stream, block-sorted, and the count of its steps that are not tail
+    padding (the padding repeats the last slot, to a multiple of 8)."""
     eff = np.maximum(ntiles, 1) if cover_all_blocks else ntiles
     live_mask = np.arange(tpb, dtype=np.int64)[None, :] < eff[:, None]
     keep = np.flatnonzero(live_mask.ravel())
     if keep.size == 0:   # fully empty matrix: single zero step
         keep = np.zeros(1, dtype=np.int64)
-    blocks = keep // tpb
     live = keep.shape[0]
     pad = (-live) % 8
-    keep = np.concatenate([keep, np.full(pad, keep[-1], dtype=np.int64)])
-    block_ids = np.concatenate(
-        [blocks, np.full(pad, blocks[-1], dtype=np.int64)]).astype(np.int32)
-    vals = values[keep]
-    if pad:
-        vals[live:] = 0.0
-    # slabs of empty blocks (cover_all_blocks) are all-zero by construction
-    # in the padded lattice, so their steps contribute nothing
-    return block_ids, tile_ids[keep].astype(np.int32), vals
+    return np.concatenate([keep, np.full(pad, keep[-1], np.int64)]), live
 
 
 def bcc_compact_stream_reference(a: BCC, *, cover_all_blocks: bool = False
@@ -259,14 +268,20 @@ def build_live_pairs(a: BCC, b: TiledCSR, stream: tuple | None = None
     """
     if stream is None:
         stream = bcc_compact_stream(a, cover_all_blocks=True)
-    block_ids, tile_ids = np.asarray(stream[0]), np.asarray(stream[1])
     ntiles, table = to_host(a.ntiles, b.table)
+    return _live_pairs(stream, ntiles, table, nnb=b.nnb,
+                       nblocks=(a.nrows + a.block_r - 1) // a.block_r)
+
+
+def _live_pairs(stream, ntiles: np.ndarray, table: np.ndarray, *, nnb: int,
+                nblocks: int) -> tuple:
+    """:func:`build_live_pairs` on host arrays: A's ``ntiles`` and B's
+    tile ``table``."""
+    block_ids, tile_ids = np.asarray(stream[0]), np.asarray(stream[1])
     step_live = rank_in_segment(block_ids.astype(np.int64)) \
         < ntiles[block_ids]
-    return live_pair_stream(
-        block_ids, tile_ids, table, nnb=b.nnb,
-        nblocks=(a.nrows + a.block_r - 1) // a.block_r,
-        step_live=step_live)
+    return live_pair_stream(block_ids, tile_ids, table, nnb=nnb,
+                            nblocks=nblocks, step_live=step_live)
 
 
 def build_shard_pack(a: BCC, b: TiledCSR, pairs: tuple, *,
@@ -342,8 +357,14 @@ def build_sparse_c_pairs(a: BCC, b: TiledCSR, pairs: tuple | None = None,
         stream = bcc_compact_stream(a, cover_all_blocks=True)
     if pairs is None:
         pairs = build_live_pairs(a, b, stream)
-    nblocks = (a.nrows + a.block_r - 1) // a.block_r
-    table, nlive = compacted_c_table(pairs, nblocks=nblocks, nnb=b.nnb)
+    return _sparse_c_pairs(pairs, nblocks=(a.nrows + a.block_r - 1)
+                           // a.block_r, nnb=b.nnb, pad_to=pad_to)
+
+
+def _sparse_c_pairs(pairs, *, nblocks: int, nnb: int, pad_to: int = 8
+                    ) -> tuple:
+    """:func:`build_sparse_c_pairs` of a packed live-pair stream."""
+    table, nlive = compacted_c_table(pairs, nblocks=nblocks, nnb=nnb)
     blocks, js, slots, a_idx = (np.asarray(p) for p in pairs)
     live = slots > 0
     bl = blocks[live].astype(np.int64)
@@ -352,7 +373,7 @@ def build_sparse_c_pairs(a: BCC, b: TiledCSR, pairs: tuple | None = None,
     al = a_idx[live]
     order = np.lexsort((al, jl, bl))
     bl, jl, sl, al = bl[order], jl[order], sl[order], al[order]
-    c_slots = table[bl * b.nnb + jl].astype(np.int64)
+    c_slots = table[bl * nnb + jl].astype(np.int64)
     anchor = int(al[0]) if al.size else 0
     c_slots = np.concatenate([[0], c_slots])
     sl = np.concatenate([[0], sl.astype(np.int64)])
@@ -441,7 +462,8 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
                      shards: int | None = None,
                      revisit: bool = False,
                      shard_pack: tuple | None = None,
-                     sparse_c: bool | None = None) -> jax.Array:
+                     sparse_c: bool | None = None,
+                     sparse_pairs: tuple | None = None) -> jax.Array:
     """C = A_bcc @ B_tiled via the Pallas Sp×Sp kernel tier. Returns the
     dense ``(a.nrows, b.ncols)`` product (fp32 — bf16 B tiles are upcast
     at the MXU input, accumulation stays fp32).
@@ -483,6 +505,12 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
         ``_SPARSE_C_DENSITY`` and the product is not sharded; callers
         that want the compacted format itself call
         :func:`bcc_spgemm_sparse_c` directly.
+      * ``sparse_pairs`` overrides the sparse-C route's packed
+        window-major stream (:func:`build_sparse_c_pairs`).
+
+    ``a`` may be the :class:`repro.core.formats.BCCShape` of the BCC
+    when ``stream`` holds its compact stream and ``pairs`` (or the
+    padded grid) is decided: nothing else of A is read then.
     """
     _faults.maybe_fault("kernel_launch")
     if interpret is None:
@@ -516,7 +544,8 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
         if sparse_c and shard_pack is None:
             cc = bcc_spgemm_sparse_c(
                 a, b, interpret=interpret, stream=stream, pairs=pairs,
-                double_buffer=double_buffer, epilogue="kernel")
+                sparse_pairs=sparse_pairs, double_buffer=double_buffer,
+                epilogue="kernel")
             return cc.to_dense()
         if shard_pack is not None:
             ranges, shard_pairs, wb = shard_pack
@@ -569,6 +598,147 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
                      nblocks=nblocks, nnb=b.nnb, interpret=interpret)
     _note_kernel_launch("padded")
     return out[: a.nrows, : b.ncols]
+
+
+@dataclasses.dataclass(frozen=True)
+class SpGEMMPattern:
+    """The Sp×Sp operands packed from their patterns alone, on the device.
+
+    Everything :func:`bcc_spgemm_tiled` reads except two value arrays is
+    a function of the patterns: A's blocking and compact stream ids, the
+    live pairs, B's tile table, the shard partition, the sparse-C
+    decision and its window-major stream. The two value arrays, A's
+    stream slabs and B's tile store, are zeros with the operands' values
+    at fixed positions; ``a_map``/``b_map`` (``(src, dst)`` of
+    :func:`repro.core.formats.scatter_map`) hold those positions, with
+    ``src`` indexing the ``data`` of the operands as they were sent
+    (before the plan's permutation). :meth:`fill` builds both arrays from
+    a value set on the device, and :meth:`run` launches the kernel on
+    them: the same slabs and tiles, in the same stream order, as a full
+    :func:`bcc_from_host`/:func:`tiled_csr_from_host` pack.
+    """
+
+    a: BCCShape
+    b_shape: tuple                 # (nrows, ncols, block_k, bn) of B
+    table: jax.Array               # B's tile table
+    stream_ids: tuple              # (block_ids, tile_ids)
+    pairs: tuple | None            # None: the padded grid runs
+    shard_pack: tuple | None
+    sparse_c: bool | None
+    sparse_pairs: tuple | None
+    a_map: tuple
+    b_map: tuple
+    values_shape: tuple
+    tiles_shape: tuple
+    tiles_dtype: object
+
+    def fill(self, a_data, b_data=None) -> tuple[jax.Array, TiledCSR]:
+        """A's stream values and B's tiles for one value set, from the
+        operands' ``data``; ``b_data=None`` takes B's values from
+        ``a_data`` (the squared product, B = A)."""
+        up = to_device(a_data) if b_data is None else to_device(a_data,
+                                                               b_data)
+        values, tiles = _fill_values(
+            up[0], up[-1], *self.a_map, *self.b_map,
+            values_shape=self.values_shape, tiles_shape=self.tiles_shape,
+            tiles_dtype=self.tiles_dtype)
+        if get_tracer().enabled:
+            # like an upload, the fill's span closes on the device's work
+            jax.block_until_ready((values, tiles))
+        nrows, ncols, block_k, bn = self.b_shape
+        return values, TiledCSR(tiles=tiles, table=self.table, nrows=nrows,
+                                ncols=ncols, block_k=block_k, bn=bn)
+
+    def run(self, values: jax.Array, tiled: TiledCSR) -> jax.Array:
+        """C = A @ B on one value set that :meth:`fill` built."""
+        return bcc_spgemm_tiled(
+            self.a, tiled, stream=(*self.stream_ids, values),
+            pairs=self.pairs, shard_pack=self.shard_pack,
+            sparse_c=self.sparse_c, sparse_pairs=self.sparse_pairs)
+
+
+@functools.partial(jax.jit, static_argnames=("values_shape", "tiles_shape",
+                                             "tiles_dtype"))
+def _fill_values(a_data, b_data, a_src, a_dst, b_src, b_dst, *,
+                 values_shape, tiles_shape, tiles_dtype):
+    def scatter(data, src, dst, shape, dtype):
+        flat = jnp.zeros(math.prod(shape), dtype).at[dst].set(
+            data[src].astype(dtype), indices_are_sorted=True,
+            unique_indices=True)
+        return flat.reshape(shape)
+    return (scatter(a_data, a_src, a_dst, values_shape, jnp.float32),
+            scatter(b_data, b_src, b_dst, tiles_shape, tiles_dtype))
+
+
+def _value_map(pos: np.ndarray, src: np.ndarray | None, size: int) -> tuple:
+    """:func:`scatter_map` of ``pos``, its sources composed with ``src``
+    (the operand's permutation, ``None`` for none), both int32."""
+    if size >= 2**31:
+        raise ValueError(f"value array of {size} entries is too large for "
+                         "int32 positions")
+    take, dst = scatter_map(pos)
+    if src is not None:
+        take = np.asarray(src)[take]
+    return take.astype(np.int32), dst.astype(np.int32)
+
+
+def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
+                        a_src: np.ndarray | None = None,
+                        b_src: np.ndarray | None = None,
+                        b_dtype=jnp.float32) -> SpGEMMPattern:
+    """Pack ``ap @ bh`` for the Sp×Sp kernel from the patterns alone.
+
+    ``a_src``/``b_src`` map each nonzero of ``ap``/``bh`` to the nonzero
+    of the operand as sent (``HostCSR.permuted``'s ``src``; ``None``: the
+    same order), so :meth:`SpGEMMPattern.fill` takes the sent ``data``.
+    Nothing is read back from the device: the stream ids, the live pairs
+    and the shard partition come from the host layouts
+    (:func:`bcc_layout`, :func:`tiled_layout`), at the serving path's
+    blocking: ``block_r`` 8 and ``bn`` 128, the packers' defaults.
+    """
+    block_r, bn = 8, 128
+    tile_ids, ntiles, tpb, a_pos = bcc_layout(ap, block_r, block_k)
+    table, tile_cap, b_pos = tiled_layout(bh, block_k, bn)
+    keep, live = _compact_keep(ntiles, tpb, cover_all_blocks=True)
+    stream_ids = ((keep // tpb).astype(np.int32),
+                  tile_ids[keep].astype(np.int32))
+    # A's lattice position → its step of the compact stream
+    slab = block_r * block_k
+    step_of = np.zeros(ntiles.shape[0] * tpb, dtype=np.int64)
+    step_of[keep[:live]] = np.arange(live)
+    a_pos = step_of[a_pos // slab] * slab + a_pos % slab
+    values_shape = (keep.shape[0], block_r, block_k)
+    tiles_shape = (tile_cap, block_k, bn)
+    a_map = _value_map(a_pos, a_src, math.prod(values_shape))
+    b_map = _value_map(b_pos, b_src, math.prod(tiles_shape))
+    a = BCCShape(ap.nrows, ap.ncols, block_r, block_k)
+    nblocks = ntiles.shape[0]
+    nnb = (bh.ncols + bn - 1) // bn
+    pairs = shard_pack = sparse_c = sparse_pairs = None
+    if compact_grid_ok_ncols(nnb * bn, block_r=block_r, bn=bn):
+        pairs = _live_pairs(stream_ids, ntiles, table, nnb=nnb,
+                            nblocks=nblocks)
+        shards = pallas_shard_count()
+        if shards > 1:
+            ranges, shard_pairs = partition_pair_stream(
+                pairs, nblocks=nblocks, num_shards=shards)
+            shard_pack = (ranges, [to_device(*p) for p in shard_pairs],
+                          None)
+        sparse_c = (shard_pack is None and predict_c_window_density(
+            pairs, nblocks=nblocks, nnb=nnb) <= _SPARSE_C_DENSITY)
+        if sparse_c:
+            *streams, nslabs = _sparse_c_pairs(pairs, nblocks=nblocks,
+                                               nnb=nnb)
+            sparse_pairs = (*to_device(*streams), nslabs)
+        pairs = to_device(*pairs)
+    table, *ids = to_device(table, *stream_ids)
+    return SpGEMMPattern(
+        a=a, b_shape=(bh.nrows, bh.ncols, block_k, bn), table=table,
+        stream_ids=tuple(ids), pairs=pairs, shard_pack=shard_pack,
+        sparse_c=sparse_c, sparse_pairs=sparse_pairs,
+        a_map=to_device(*a_map), b_map=to_device(*b_map),
+        values_shape=values_shape, tiles_shape=tiles_shape,
+        tiles_dtype=jnp.dtype(b_dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

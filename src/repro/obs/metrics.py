@@ -65,6 +65,9 @@ _HOST_METRICS: dict[str, tuple[str, str]] = {
         "gauge", "PlanCache budget usage, memory + disk (bytes)"),
     "exec_cache_packs": (
         "counter", "operand packings on exec-cache misses (count)"),
+    "exec_cache_refills": (
+        "counter", "Sp×Sp value refills on the device: a pattern hit "
+        "with new operand values (count)"),
     "exec_cache_entries": (
         "gauge", "packed operand sets resident in the exec cache (count)"),
     "kernel_launches": (

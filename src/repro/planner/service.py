@@ -864,6 +864,8 @@ class Planner:
         squared = b is None
         if squared and a.nrows != a.ncols:
             raise ValueError("A² workload needs a square matrix")
+        if plan.scheme == "pallas" and not dense_b:
+            return self._pallas_runner(plan, a, b)
         # the plan fingerprint is value-independent by design; the packed
         # device operands are not — key them by the operand values (and
         # for a second sparse operand, its pattern too) AND by the plan's
@@ -926,70 +928,34 @@ class Planner:
                 else:
                     ap = _apply_plan_perm(a, plan, symmetric=False)
                     bh = b
-                if plan.scheme == "pallas":
-                    # the Pallas Sp×Sp tier: BCC(A) × TiledCSR(B) on the
-                    # MXU. Everything the kernel streams is packed exactly
-                    # once per cached operand pair: the adaptive k-tile
-                    # height, the compact A stream, the live-pair compacted
-                    # grid AND (on multi-core backends) its per-core shard
-                    # partition — a cache hit goes straight to the kernel
-                    # with zero host work
-                    bk = select_block_k(bh)
-                    bcc = bcc_from_host(ap, block_k=bk)
-                    tiled = tiled_csr_from_host(bh, block_k=bk,
-                                                dtype=self.pallas_b_dtype)
-                    stream = kernel_ops.bcc_compact_stream(
-                        bcc, cover_all_blocks=True)
-                    # the intersection is only worth packing when the
-                    # compacted grid will actually run (wide B falls back
-                    # to the padded per-tile grid, which ignores it)
-                    pairs = (kernel_ops.build_live_pairs(bcc, tiled, stream)
-                             if kernel_ops.compact_grid_ok(bcc, tiled)
-                             else None)
-                    shard_pack = (
-                        kernel_ops.build_shard_pack(bcc, tiled, pairs)
-                        if pairs is not None
-                        and kernel_ops.pallas_shard_count() > 1
-                        else None)
-                    cached = ("pallas", bcc, tiled, stream, pairs,
-                              shard_pack)
+                dev_b = csr_from_host(bh)
+                b_lens = bh.row_nnz()
+                if plan.scheme == "rowwise":
+                    dev_a = csr_from_host(ap)
+                    fetch = np.zeros(dev_a.nnz_cap, dtype=np.int64)
+                    fetch[: ap.nnz] = b_lens[ap.indices.astype(np.int64)]
+                    bins = length_bins(fetch, pad_sentinel=dev_a.nnz_cap)
+                    srows = slot_rows_host(np.asarray(dev_a.indptr),
+                                           dev_a.nnz_cap)
+                    cached = ("row", dev_a, dev_b, bins, srows)
                 else:
-                    dev_b = csr_from_host(bh)
-                    b_lens = bh.row_nnz()
-                    if plan.scheme == "rowwise":
-                        dev_a = csr_from_host(ap)
-                        fetch = np.zeros(dev_a.nnz_cap, dtype=np.int64)
-                        fetch[: ap.nnz] = b_lens[
-                            ap.indices.astype(np.int64)]
-                        bins = length_bins(fetch,
-                                           pad_sentinel=dev_a.nnz_cap)
-                        srows = slot_rows_host(np.asarray(dev_a.indptr),
-                                               dev_a.nnz_cap)
-                        cached = ("row", dev_a, dev_b, bins, srows)
-                    else:
-                        cc = csr_cluster_from_host(
-                            ap, self._bounds(plan, ap),
-                            max_cluster=plan.max_cluster)
-                        total = int(np.asarray(cc.cluster_ptr)[-1])
-                        slot_cols = np.asarray(
-                            cc.cols)[:total].astype(np.int64)
-                        fetch = np.zeros(cc.slot_cap, dtype=np.int64)
-                        fetch[:total] = np.where(
-                            slot_cols < bh.nrows, b_lens[
-                                np.clip(slot_cols, 0, bh.nrows - 1)], 0)
-                        bins = length_bins(fetch, pad_sentinel=cc.slot_cap)
-                        sclust = slot_rows_host(np.asarray(cc.cluster_ptr),
-                                                cc.slot_cap)
-                        cached = ("cluster", cc, dev_b, bins, sclust)
+                    cc = csr_cluster_from_host(
+                        ap, self._bounds(plan, ap),
+                        max_cluster=plan.max_cluster)
+                    total = int(np.asarray(cc.cluster_ptr)[-1])
+                    slot_cols = np.asarray(cc.cols)[:total].astype(np.int64)
+                    fetch = np.zeros(cc.slot_cap, dtype=np.int64)
+                    fetch[:total] = np.where(
+                        slot_cols < bh.nrows, b_lens[
+                            np.clip(slot_cols, 0, bh.nrows - 1)], 0)
+                    bins = length_bins(fetch, pad_sentinel=cc.slot_cap)
+                    sclust = slot_rows_host(np.asarray(cc.cluster_ptr),
+                                            cc.slot_cap)
+                    cached = ("cluster", cc, dev_b, bins, sclust)
                 self._exec_put(ck, cached)
             self._note_pack()
         kind = cached[0]
-        if kind == "pallas":
-            _, bcc, tiled, stream, pairs, shard_pack = cached
-            out = lambda: kernel_ops.bcc_spgemm_tiled(  # noqa: E731
-                bcc, tiled, stream=stream, pairs=pairs,
-                shard_pack=shard_pack)
-        elif kind == "row":
+        if kind == "row":
             _, op_a, op_b, bins, srows = cached
             out = lambda: spgemm_rowwise_dense_binned(  # noqa: E731
                 op_a, op_b, bins, srows)
@@ -998,6 +964,58 @@ class Planner:
             out = lambda: spgemm_clusterwise_dense_binned(  # noqa: E731
                 op_a, op_b, bins, sclust)
         return self._unpermuted(out, perm, rows_only=not squared)
+
+    def _pallas_runner(self, plan: Plan, a: HostCSR, b: HostCSR | None):
+        """The Pallas Sp×Sp tier, BCC(A) × TiledCSR(B) on the MXU.
+
+        Its exec-cache entry is keyed by the operands' patterns: the plan
+        and B's fingerprint. It holds the :class:`SpGEMMPattern`, packed
+        once (the adaptive k-tile height, the compact A stream's ids, the
+        live pairs, the shard partition, all on the device), and one
+        value slot ``(value digest, A's stream values, B's TiledCSR)``. A
+        request whose values differ from the slot's refills both arrays on
+        the device from the sent ``data`` (a ``pack`` span of
+        ``kind="refill"``); the same values again go straight to the
+        kernel. A request builds its own slot and launches on it, so
+        concurrent value sets never mix."""
+        squared = b is None
+        ck = (f"{plan.fingerprint}|{_plan_digest(plan)}|"
+              + ("sq" if squared else f"ab|{fingerprint(b)}"))
+        vk = (_value_digest(a) if squared
+              else f"{_value_digest(a)}|{_value_digest(b)}")
+        b_data = None if squared else b.data
+        tracer = get_tracer()
+        entry = self._exec_cache.get(ck)
+        if entry is None:
+            with tracer.span("pack", fingerprint=plan.fingerprint,
+                             scheme=plan.scheme,
+                             kind="sq" if squared else "ab"):
+                _faults.maybe_fault("pack")
+                ap, src = a, None
+                if plan.perm is not None:
+                    ap, src = a.permuted(plan.perm, symmetric=squared)
+                bh = ap if squared else b
+                pattern = kernel_ops.pack_spgemm_pattern(
+                    ap, bh, block_k=select_block_k(bh), a_src=src,
+                    b_src=src if squared else None,
+                    b_dtype=self.pallas_b_dtype)
+                slot = (vk, *pattern.fill(a.data, b_data))
+                # a list: the value slot is replaced in place
+                entry = ["pallas", pattern, slot]
+                self._exec_put(ck, entry)
+            self._note_pack()
+        else:
+            pattern, slot = entry[1], entry[2]
+            if slot[0] != vk:
+                with tracer.span("pack", fingerprint=plan.fingerprint,
+                                 scheme=plan.scheme, kind="refill"):
+                    slot = (vk, *pattern.fill(a.data, b_data))
+                entry[2] = slot
+                obs_metrics.get_registry().counter(
+                    "exec_cache_refills").inc()
+        _, values, tiled = slot
+        return self._unpermuted(lambda: pattern.run(values, tiled),
+                                plan.perm, rows_only=not squared)
 
     def _exec_put(self, key: str, packed: tuple) -> None:
         while len(self._exec_cache) >= self._exec_cache_cap:

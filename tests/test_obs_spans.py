@@ -122,10 +122,10 @@ def test_one_request_is_one_trace_across_threads(workload, traced):
         assert parents("upload") == {"execute", "kernel"}
         assert one("kernel_variant").attrs == {"variant": "spmm_compact"}
     else:
-        # BCC and tiles upload while packing, BCC comes back for the
-        # compact stream
+        # the new values upload in the refill; nothing comes back but C
         assert parents("upload") == {"pack", "kernel"}
-        assert parents("fetch") == {"pack", "kernel"}
+        assert parents("fetch") == {"kernel"}
+        assert one("pack").attrs["kind"] == "refill"
     # the shapes were warm: nothing compiled
     assert not [s for s in spans if s.name == "compile"]
 
@@ -148,27 +148,44 @@ def test_transfer_bytes_of_the_spmm_path(traced):
 
 def test_transfer_bytes_of_the_a2_path(traced):
     resp, spans, (a, _) = _serve_twice("a2", traced)
+    kv = next(s for s in spans if s.name == "kernel_variant")
+    assert kv.attrs["variant"] in ("resident", "streamed", "streamed_db")
+    # the pattern was packed by the first request: the second refills
+    # its values on the device from A's data, and the launch finds every
+    # operand there already
+    assert _bytes(spans, "upload", "pack") == a.data.nbytes == a.nnz * 4
+    assert _bytes(spans, "upload", "kernel") == 0
+    assert _bytes(spans, "fetch", "pack") == 0
+    assert _bytes(spans, "fetch", "kernel") == resp.result.nbytes \
+        == a.nrows * a.ncols * 4
+
+
+def test_transfer_bytes_of_the_a2_pattern_pack(traced):
+    """The first request of a pattern packs it: the layout, the maps and
+    the first values go up once, and nothing comes back but C."""
+    a = _graph()
+    srv = _server(a, "a2")
+    try:
+        resp = srv.submit_wait(a, None, reuse_hint=HINT)
+    finally:
+        srv.close()
+    spans = traced.spans()
+    pack, = [s for s in spans if s.name == "pack"]
+    assert pack.attrs["kind"] == "sq"
     bk = select_block_k(a)
     bcc = bcc_from_host(a, block_k=bk)
     tiled = tiled_csr_from_host(a, block_k=bk)
     stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
     pairs = ops.build_live_pairs(bcc, tiled, stream)
-    kv = next(s for s in spans if s.name == "kernel_variant")
-    assert kv.attrs["variant"] in ("resident", "streamed", "streamed_db")
 
     def nbytes(*arrays):
         return sum(np.asarray(x).nbytes for x in arrays)
 
-    bcc_b = nbytes(bcc.tile_ids, bcc.values, bcc.ntiles)
-    assert _bytes(spans, "upload", "pack") == bcc_b + nbytes(tiled.tiles,
-                                                             tiled.table)
-    assert _bytes(spans, "upload", "kernel") == nbytes(stream[2], *pairs)
-    # the BCC read back for the compact stream, ntiles and B's table for
-    # the live pairs, then C
-    assert _bytes(spans, "fetch", "pack") == bcc_b + nbytes(bcc.ntiles,
-                                                            tiled.table)
-    assert _bytes(spans, "fetch", "kernel") == resp.result.nbytes \
-        == a.nrows * a.ncols * 4
+    maps = 2 * 2 * 4 * a.nnz     # (src, dst) int32 of A's and B's maps
+    assert _bytes(spans, "upload", "pack") == nbytes(
+        stream[0], stream[1], tiled.table, *pairs) + maps + a.data.nbytes
+    assert _bytes(spans, "fetch", "pack") == 0
+    assert _bytes(spans, "fetch", "kernel") == resp.result.nbytes
 
 
 def test_fresh_jit_compiles_once_in_a_trace_of_its_own(traced):

@@ -424,7 +424,8 @@ def test_service_packs_shard_partition(monkeypatch):
     got = planner.execute(plan, a)
     np.testing.assert_allclose(got, spgemm_reference(a, a),
                                rtol=1e-3, atol=1e-3)
-    packed = [v for v in planner._exec_cache.values() if v[0] == "pallas"]
-    assert packed and packed[0][5] is not None      # shard_pack cached
-    ranges, sp, wb = packed[0][5]
+    packed = [v[1] for v in planner._exec_cache.values()
+              if v[0] == "pallas"]
+    assert packed and packed[0].shard_pack is not None  # shard_pack cached
+    ranges, sp, wb = packed[0].shard_pack
     assert len(sp) == 2 and wb is None

@@ -73,7 +73,10 @@ def summarize(spans: list[dict]) -> dict:
         "plan_calls": len(plans),
         "plan_cache_hits": hits,
         "plan_cache_hit_rate": hits / len(plans) if plans else 0.0,
-        "exec_cache_packs": sum(1 for s in spans if s["name"] == "pack"),
+        "exec_cache_packs": sum(1 for s in spans if s["name"] == "pack"
+                                and s["attrs"].get("kind") != "refill"),
+        "exec_cache_refills": sum(1 for s in spans if s["name"] == "pack"
+                                  and s["attrs"].get("kind") == "refill"),
     }
 
     drift: dict[str, dict] = {}
@@ -200,9 +203,11 @@ def render(summary: dict) -> None:
            [[name, v["count"], f"{v['total_s']:.4f}", f"{v['self_s']:.4f}"]
             for name, v in rows])
     c = summary["cache"]
-    _table("caches", ["plan_calls", "hits", "hit_rate", "exec_packs"],
+    _table("caches", ["plan_calls", "hits", "hit_rate", "exec_packs",
+                      "exec_refills"],
            [[c["plan_calls"], c["plan_cache_hits"],
-             f"{c['plan_cache_hit_rate']:.2f}", c["exec_cache_packs"]]])
+             f"{c['plan_cache_hit_rate']:.2f}", c["exec_cache_packs"],
+             c["exec_cache_refills"]]])
     _table("cost-model drift (log-space residual, per scheme)",
            ["scheme", "n", "mean_abs_residual", "regret"],
            [[s, v["n"], f"{v['mean_abs_residual']:.4f}",
