@@ -656,6 +656,16 @@ class SpGEMMPattern:
             pairs=self.pairs, shard_pack=self.shard_pack,
             sparse_c=self.sparse_c, sparse_pairs=self.sparse_pairs)
 
+    def run_sparse(self, values: jax.Array, tiled: TiledCSR) -> CompactedC:
+        """C = A @ B on one value set, left in the sparse-C output tier
+        (:func:`bcc_spgemm_sparse_c`); the pattern must have been packed
+        with ``sparse_out=True``."""
+        if self.sparse_pairs is None:
+            raise ValueError("pattern packed without its sparse-C stream")
+        return bcc_spgemm_sparse_c(
+            self.a, tiled, stream=(*self.stream_ids, values),
+            pairs=self.pairs, sparse_pairs=self.sparse_pairs)
+
 
 @functools.partial(jax.jit, static_argnames=("values_shape", "tiles_shape",
                                              "tiles_dtype"))
@@ -685,7 +695,8 @@ def _value_map(pos: np.ndarray, src: np.ndarray | None, size: int) -> tuple:
 def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
                         a_src: np.ndarray | None = None,
                         b_src: np.ndarray | None = None,
-                        b_dtype=jnp.float32) -> SpGEMMPattern:
+                        b_dtype=jnp.float32,
+                        sparse_out: bool = False) -> SpGEMMPattern:
     """Pack ``ap @ bh`` for the Sp×Sp kernel from the patterns alone.
 
     ``a_src``/``b_src`` map each nonzero of ``ap``/``bh`` to the nonzero
@@ -695,6 +706,11 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
     and the shard partition come from the host layouts
     (:func:`bcc_layout`, :func:`tiled_layout`), at the serving path's
     blocking: ``block_r`` 8 and ``bn`` 128, the packers' defaults.
+
+    ``sparse_out=True`` packs for :meth:`SpGEMMPattern.run_sparse`: the
+    window-major sparse-C stream is built whatever C's predicted density,
+    and the product is not sharded. B must then be narrow enough for the
+    compacted grid (:func:`compact_grid_ok_ncols`).
     """
     block_r, bn = 8, 128
     tile_ids, ntiles, tpb, a_pos = bcc_layout(ap, block_r, block_k)
@@ -715,17 +731,23 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
     nblocks = ntiles.shape[0]
     nnb = (bh.ncols + bn - 1) // bn
     pairs = shard_pack = sparse_c = sparse_pairs = None
-    if compact_grid_ok_ncols(nnb * bn, block_r=block_r, bn=bn):
+    compact = compact_grid_ok_ncols(nnb * bn, block_r=block_r, bn=bn)
+    if sparse_out and not compact:
+        raise ValueError(f"B of {bh.ncols} columns is too wide for the "
+                         "sparse-C output tier")
+    if compact:
         pairs = _live_pairs(stream_ids, ntiles, table, nnb=nnb,
                             nblocks=nblocks)
-        shards = pallas_shard_count()
+        shards = 1 if sparse_out else pallas_shard_count()
         if shards > 1:
             ranges, shard_pairs = partition_pair_stream(
                 pairs, nblocks=nblocks, num_shards=shards)
             shard_pack = (ranges, [to_device(*p) for p in shard_pairs],
                           None)
-        sparse_c = (shard_pack is None and predict_c_window_density(
-            pairs, nblocks=nblocks, nnb=nnb) <= _SPARSE_C_DENSITY)
+        sparse_c = sparse_out or (shard_pack is None and
+                                  predict_c_window_density(
+                                      pairs, nblocks=nblocks, nnb=nnb)
+                                  <= _SPARSE_C_DENSITY)
         if sparse_c:
             *streams, nslabs = _sparse_c_pairs(pairs, nblocks=nblocks,
                                                nnb=nnb)

@@ -75,6 +75,10 @@ _HOST_METRICS: dict[str, tuple[str, str]] = {
         "label (count)"),
     "chain_hops": (
         "counter", "chain-workload hops executed (count)"),
+    "chain_carry_bytes": (
+        "counter", "bytes a chain's intermediates moved between hops: "
+        "each one's fetch, and its upload when the next hop packed or "
+        "refilled (bytes)"),
     "audit_records": (
         "counter", "drift-audit samples recorded (count)"),
     "audit_flagged": (

@@ -22,14 +22,16 @@ tall-skinny SpMM workload) and always returns the product in the
 *original* row/column order — permutations are internal to the plan.
 
 ``execute_chain`` is the chained-product entry point (A³, Markov steps,
-MoE routing masks): each hop re-fingerprints the sparse intermediate,
-plans it under ``workload="chain"``, and — on pallas-scheme hops — runs
-the sparse-C tier so the intermediate round-trips as
-``CompactedC → HostCSR`` without a dense materialization.
+MoE routing masks, AMG's Galerkin product R·A·P): it picks the
+association from the operands' shapes, plans each hop's left operand,
+and — on pallas-scheme hops — runs the sparse-C tier, so that an
+intermediate comes back to the host as a ``HostCSR`` of its symbolic
+pattern without a dense materialization.
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import hashlib
 import threading
 import time
@@ -44,15 +46,14 @@ from repro.core.clustering import (DEFAULT_MAX_CLUSTER,
                                    hierarchical_clusters,
                                    variable_length_clusters)
 from repro.core.formats import (HostCSR, bcc_from_host,
-                                compacted_c_to_host, csr_cluster_from_host,
-                                csr_from_host, select_block_k,
-                                tiled_csr_from_host)
+                                csr_cluster_from_host, csr_from_host,
+                                select_block_k)
 from repro.core.reorder import reorder as apply_reorder
 from repro.core.spgemm import (length_bins, slot_rows_host,
                                spgemm_clusterwise_dense_binned,
                                spgemm_rowwise_dense_binned, spmm_clusterwise,
                                spmm_rowwise)
-from repro.core.transfer import device_nbytes, to_device
+from repro.core.transfer import device_nbytes, to_device, to_host
 from repro.kernels import ops as kernel_ops
 from repro.obs import audit as obs_audit
 from repro.obs import metrics as obs_metrics
@@ -70,7 +71,7 @@ from repro.resilience.policy import (ResiliencePolicy, fallback_chain,
                                      get_policy)
 
 __all__ = ["Planner", "plan_spgemm", "execute", "execute_chain",
-           "default_planner", "reset_default_planner"]
+           "chain_order", "default_planner", "reset_default_planner"]
 
 
 # ---------------------------------------------------------------------------
@@ -184,6 +185,150 @@ def _apply_plan_perm(a: HostCSR, plan: Plan, *, symmetric: bool) -> HostCSR:
 
 
 # ---------------------------------------------------------------------------
+# chained products: the association and the sparse product's carry
+# ---------------------------------------------------------------------------
+
+# a chain hop the XLA schemes would serve through a dense C larger than
+# this (rows × columns × 4 B) is planned on the Pallas candidates, whose
+# sparse-C route returns C sparse
+_CHAIN_DENSE_C_BUDGET = 256 * 2**20
+
+
+def chain_order(shapes: Sequence[tuple], nnzs: Sequence[int]):
+    """The association of the product ``M₀·M₁·…·M_m`` from the operands'
+    shapes and nnz alone: a binary tree of operand indices, e.g.
+    ``(0, (1, 2))`` for ``M₀·(M₁·M₂)``.
+
+    Orders are ranked by, in turn: the number of products (every
+    intermediate and the result) wider than the sparse-C tier's C row
+    strip (:func:`repro.kernels.ops.compact_grid_ok_ncols`); the
+    estimated flops, ``2·nnz(X)·nnz(Y)/rows(Y)`` a product, with
+    ``nnz(X·Y) ≈ min(flops/2, rows·cols)``; the intermediates' summed
+    column counts; and last the left-most split, so ``A·A·A`` stays
+    ``(A·A)·A``.
+
+    >>> chain_order([(4, 8), (8, 8), (8, 4)], [8, 24, 8])
+    (0, (1, 2))
+    >>> chain_order([(8, 8)] * 3, [24] * 3)
+    ((0, 1), 2)
+    """
+    from fractions import Fraction
+    m = len(shapes)
+    # best[(i, j)] = (rank, tree, est. nnz) of the product M_i … M_j
+    best: dict = {(i, i): ((0, Fraction(0), 0), i, Fraction(nnzs[i]))
+                  for i in range(m)}
+    for span in range(1, m):
+        for i in range(m - span):
+            j = i + span
+            rows, cols = shapes[i][0], shapes[j][1]
+            wide = 0 if kernel_ops.compact_grid_ok_ncols(cols) else 1
+            inner = cols if (i, j) != (0, m - 1) else 0
+            cands = []
+            for s in range(j - 1, i - 1, -1):      # left-most split last
+                (wl, fl, cl), tl, nl = best[(i, s)]
+                (wr, fr, cr), tr, nr = best[(s + 1, j)]
+                flops = 2 * nl * nr / max(shapes[s + 1][0], 1)
+                rank = (wl + wr + wide, fl + fr + flops, cl + cr + inner)
+                cands.append((rank, (tl, tr),
+                              min(flops / 2, Fraction(rows * cols))))
+            best[(i, j)] = min(cands, key=lambda c: c[0])
+    return best[(0, m - 1)][1]
+
+
+def _chain_steps(tree, m: int) -> list[tuple[int, int]]:
+    """The hops of an association tree in the order they run, each
+    ``(left, right)`` indexing the operands ``0 … m-1`` and then the
+    hops' results ``m, m+1, …``."""
+    steps: list[tuple[int, int]] = []
+
+    def walk(t) -> int:
+        if isinstance(t, int):
+            return t
+        left, right = walk(t[0]), walk(t[1])
+        steps.append((left, right))
+        return m + len(steps) - 1
+    walk(tree)
+    return steps
+
+
+@jax.jit
+def _take(slabs, positions):
+    return slabs.reshape(-1)[positions]
+
+
+@dataclasses.dataclass(frozen=True)
+class _ProductPattern:
+    """The symbolic pattern of a sparse-C hop's product, in the original
+    row/column order, and where each of its nonzeros sits in the
+    :class:`repro.core.formats.CompactedC` slabs: the same for every
+    value set on the hop's operand patterns."""
+
+    indptr: np.ndarray
+    indices: np.ndarray
+    shape: tuple
+    positions: jax.Array           # int32, into the flattened slabs
+
+    @classmethod
+    def of(cls, pattern, a: HostCSR, b: Optional[HostCSR],
+           perm: Optional[np.ndarray]) -> "_ProductPattern":
+        """Run the packed ``pattern`` once on unit values (a sum of
+        products of ones is zero only off the symbolic pattern) and read
+        the nonzeros' slab positions back; ``b=None`` is the squared
+        product under a symmetric ``perm``."""
+        ones = pattern.fill(np.ones(a.nnz, np.float32),
+                            None if b is None else np.ones(b.nnz,
+                                                           np.float32))
+        cc = pattern.run_sparse(*ones)
+        table, slabs = to_host(cc.table, cc.slabs)
+        table = table.reshape(cc.nblocks, cc.nnb)
+        blk, j = np.nonzero(table > 0)
+        slab = table[blk, j].astype(np.int64)
+        w, rr, col = np.nonzero(slabs[slab])
+        rows = blk[w].astype(np.int64) * cc.block_r + rr
+        cols = j[w].astype(np.int64) * cc.bn + col
+        pos = (slab[w] * cc.block_r + rr) * cc.bn + col
+        if pos.size and pos[-1] >= 2**31:
+            raise ValueError("sparse-C slab store too large for int32 "
+                             "positions")
+        if perm is not None:
+            p = np.asarray(perm, dtype=np.int64)
+            rows = p[rows]
+            if b is None:
+                cols = p[cols]
+        nrows, ncols = a.nrows, (a if b is None else b).ncols
+        order = np.lexsort((cols, rows))
+        indptr = np.zeros(nrows + 1, np.int64)
+        np.cumsum(np.bincount(rows, minlength=nrows), out=indptr[1:])
+        positions, = to_device(pos[order].astype(np.int32))
+        return cls(indptr, cols[order].astype(np.int32), (nrows, ncols),
+                   positions)
+
+    def take(self, cc) -> jax.Array:
+        """The product's nonzeros from its slabs, on the device."""
+        return _take(cc.slabs, self.positions)
+
+
+class _DeviceCSR:
+    """A sparse-C hop's product still on the device: its nonzeros'
+    values, in the order of its :class:`_ProductPattern`."""
+
+    def __init__(self, values: jax.Array, product: _ProductPattern):
+        self.values, self.product = values, product
+        self.shape = product.shape
+
+    @property
+    def nbytes(self) -> int:
+        return device_nbytes(self.values)
+
+    def to_host(self) -> HostCSR:
+        """The copy to the host (a ``fetch`` span) as a
+        :class:`HostCSR`."""
+        data, = to_host(self.values)
+        p = self.product
+        return HostCSR(p.indptr, p.indices, data, p.shape)
+
+
+# ---------------------------------------------------------------------------
 # the planner
 # ---------------------------------------------------------------------------
 
@@ -279,7 +424,8 @@ class Planner:
     def plan(self, a: HostCSR, reuse_hint: Optional[int] = 1, *,
              measure: bool = False,
              candidates: Optional[Sequence[Candidate]] = None,
-             use_cache: bool = True, workload: str = "a2") -> Plan:
+             use_cache: bool = True, workload: str = "a2",
+             out_cols: Optional[int] = None) -> Plan:
         """Choose and materialize a (reorder, scheme) plan for ``a``.
 
         The do-nothing identity plan (original order, row-wise) is the
@@ -317,7 +463,8 @@ class Planner:
                                        measure=measure,
                                        candidates=candidates,
                                        use_cache=use_cache,
-                                       workload=workload)
+                                       workload=workload,
+                                       out_cols=out_cols)
             sp.set(fingerprint=plan.fingerprint, scheme=plan.scheme,
                    reorder=plan.reorder, cache_hit=plan.from_cache)
         reg = obs_metrics.get_registry()
@@ -333,7 +480,8 @@ class Planner:
     def _plan_impl(self, a: HostCSR, reuse_hint: int, *, fp: str,
                    measure: bool,
                    candidates: Optional[Sequence[Candidate]],
-                   use_cache: bool, workload: str) -> Plan:
+                   use_cache: bool, workload: str,
+                   out_cols: Optional[int] = None) -> Plan:
         """:meth:`plan` minus the span/metric/single-flight bookkeeping."""
         reuse_hint = max(int(reuse_hint), 1)
         if workload not in ("a2", "spmm", "chain", "batch"):
@@ -400,9 +548,14 @@ class Planner:
             pool = [s for s in ranked if s.measured] or ranked
         else:
             pool = ranked
-        chosen = next((s for s in pool if s.amortizes),
-                      self.cost_model.score(feats, IDENTITY, reuse_hint,
-                                            fp_w))
+        chosen = next((s for s in pool if s.amortizes), None)
+        if (out_cols is not None
+                and 4 * a.nrows * out_cols > _CHAIN_DENSE_C_BUDGET):
+            chosen = next((s for s in pool
+                           if s.candidate.scheme == "pallas"), chosen)
+        if chosen is None:
+            chosen = self.cost_model.score(feats, IDENTITY, reuse_hint,
+                                           fp_w)
 
         cand = chosen.candidate
         art = self._artifacts.pop((fp_w, cand.key), None)
@@ -720,63 +873,152 @@ class Planner:
 
     # -- chained products (workload="chain") ---------------------------------
 
-    def execute_chain(self, a: HostCSR, *, hops: int = 2,
+    def execute_chain(self, a: HostCSR,
+                      operands: Optional[Sequence[HostCSR]] = None, *,
+                      hops: Optional[int] = None,
                       reuse_hint: Optional[int] = None,
                       measure: bool = False,
-                      candidates: Optional[Sequence[Candidate]] = None
+                      candidates: Optional[Sequence[Candidate]] = None,
+                      workload: str = "chain"
                       ) -> tuple[HostCSR, list[Plan]]:
-        """Chained sparse product ``A^(hops+1)`` — left-chained hops
-        ``C₁ = A·A``, ``C₂ = C₁·A``, … (``hops=2`` is the A³ demo).
+        """Chained sparse product ``a · operands[0] · … · operands[-1]``
+        of distinct, possibly rectangular operands; ``hops=k`` is the
+        special case ``operands = (a,) * k``, ``A^(k+1)`` (``hops=2``,
+        the default, is the A³ demo).
 
-        Each hop re-fingerprints the *current* sparse intermediate and
-        plans it under ``workload="chain"`` — the plan cache keys on the
-        per-hop fingerprint, so a repeated chain (the A³ / Markov-step
-        serving pattern) hits the cache at every hop of the second call.
-        Pallas-scheme hops run the sparse-C tier
-        (:func:`repro.kernels.ops.bcc_spgemm_sparse_c`) and feed the
-        ``CompactedC → HostCSR`` conversion straight back as the next
-        hop's operand — the intermediate is repacked through
-        ``tiled_csr_from_host`` on the next hop without ever
-        materializing a dense matrix; XLA-scheme hops densify and
-        re-sparsify.
+        The association comes from the operands' shapes and nnz alone
+        (:func:`chain_order`): every intermediate narrow enough for the
+        sparse-C tier where some order allows it, then the fewest
+        estimated flops. ``R·A·P`` of AMG set-up runs as ``R·(A·P)``,
+        ``A³`` as ``(A·A)·A``.
+
+        Each hop is one Sp×Sp product: it plans its left operand under
+        ``workload`` (``"chain"``; an operand chain served by
+        :class:`repro.serve.engine.SpGEMMServer` plans under ``"a2"``,
+        so that a plan cached for the operand's plain products serves
+        the hop too). The plan cache keys on each operand's fingerprint,
+        so a repeated chain hits the cache at every hop. Pallas-scheme
+        hops run the sparse-C tier on a pattern-keyed exec entry (packed
+        once per pattern, refilled on the device for new values, as
+        :meth:`_pallas_runner`'s) and leave their product on the device;
+        it comes back to the host as a :class:`HostCSR` of the hop's
+        symbolic pattern, the same for every value set, under a
+        ``carry`` span that ends after the next hop's refill. XLA-scheme
+        hops densify and re-sparsify.
 
         Returns ``(C, plans)``: ``C`` a :class:`HostCSR` in the original
-        row/column order, ``plans`` the per-hop plans (``len == hops``).
+        row/column order, ``plans`` the per-hop plans, in the order the
+        hops ran.
         """
-        if a.nrows != a.ncols:
-            raise ValueError("chain workload needs a square matrix")
-        hops = int(hops)
-        if hops < 1:
-            raise ValueError(f"hops must be >= 1, got {hops}")
+        if operands is None:
+            hops = 2 if hops is None else int(hops)
+            if hops < 1:
+                raise ValueError(f"hops must be >= 1, got {hops}")
+            operands = (a,) * hops
+        elif hops is not None:
+            raise ValueError("give either operands or hops, not both")
+        mats = [a, *operands]
+        if len(mats) < 2:
+            raise ValueError("a chain needs at least two operands")
+        for x, y in zip(mats, mats[1:]):
+            if x.ncols != y.nrows:
+                raise ValueError(f"chain shapes do not match: {x.shape} · "
+                                 f"{y.shape}")
         if reuse_hint is None and self.hint_provider is None:
             # each hop's plan serves one product per chain call; the
             # chain itself is the reuse unit, so default to expecting a
             # handful of repeated chains (the serving pattern). With a
             # hint provider injected, None flows through to plan() so
-            # every hop's intermediate gets its own live estimate.
-            reuse_hint = max(hops, 2)
-        cur = a
+            # every hop's operand gets its own live estimate.
+            reuse_hint = max(len(operands), 2)
+        steps = _chain_steps(chain_order([m.shape for m in mats],
+                                         [m.nnz for m in mats]), len(mats))
+        vals: list = list(mats)           # operand, then each hop's result
         plans: list[Plan] = []
         tracer = get_tracer()
-        hop_counter = obs_metrics.get_registry().counter("chain_hops")
-        for k in range(hops):
-            with tracer.span("hop", hop=k, hops=hops) as sp:
-                t0 = time.perf_counter()
-                plan = self.plan(cur, reuse_hint, measure=measure,
-                                 candidates=candidates, workload="chain")
-                # per-hop planning wall time, annotated on the returned
-                # plan so the serving layer can report a truthful plan_s
-                # for chain requests (cache hits annotate ~0)
-                plan.plan_wall_s = time.perf_counter() - t0
-                plans.append(plan)
-                sp.set(fingerprint=plan.fingerprint, scheme=plan.scheme)
-                cur = self._chain_hop(plan, cur, None if k == 0 else a)
-            hop_counter.inc()
-        return cur, plans
+        reg = obs_metrics.get_registry()
+        for k, (li, ri) in enumerate(steps):
+            with tracer.span("hop", hop=k, hops=len(steps)) as sp:
+                x, y = vals[li], vals[ri]
+                plan = (None if isinstance(x, _DeviceCSR)
+                        else self._plan_hop(x, y.shape[1], reuse_hint,
+                                            measure, candidates, workload))
+                with contextlib.ExitStack() as carry:
+                    release = None
+                    if isinstance(x, _DeviceCSR) or isinstance(y,
+                                                               _DeviceCSR):
+                        release = self._carry(carry, (x, y))
+                    x, y = (v.to_host() if isinstance(v, _DeviceCSR)
+                            else v for v in (x, y))
+                    if plan is None:
+                        plan = self._plan_hop(x, y.shape[1], reuse_hint,
+                                              measure, candidates, workload)
+                    plans.append(plan)
+                    sp.set(fingerprint=plan.fingerprint, scheme=plan.scheme)
+                    out = self._hop(plan, x, None if y is x else y,
+                                    release=release)
+                if k == len(steps) - 1 and isinstance(out, _DeviceCSR):
+                    out = out.to_host()
+                    self._check_finite(plan, out.data)
+                vals.append(out)
+            reg.counter("chain_hops").inc()
+        return vals[-1], plans
+
+    def _plan_hop(self, x: HostCSR, out_cols: int, reuse_hint, measure,
+                  candidates, workload) -> Plan:
+        t0 = time.perf_counter()
+        plan = self.plan(x, reuse_hint, measure=measure,
+                         candidates=candidates, workload=workload,
+                         out_cols=out_cols)
+        # per-hop planning wall time, annotated on the returned plan so
+        # the serving layer can report a truthful plan_s for chain
+        # requests (cache hits annotate ~0)
+        plan.plan_wall_s = time.perf_counter() - t0
+        return plan
+
+    @staticmethod
+    def _carry(stack: contextlib.ExitStack, pair) -> Callable:
+        """Open the ``carry`` span of an intermediate's trip to the host
+        and back on ``stack``; returns the ``release(filled)`` the next
+        hop calls once its operands are on the device, which records the
+        bytes moved (the intermediate's fetch, and its upload when the
+        hop packed or refilled) and closes the span."""
+        sp = stack.enter_context(get_tracer().span("carry"))
+        moved = [v.nbytes for v in pair if isinstance(v, _DeviceCSR)]
+        done = []
+
+        def release(filled: bool) -> None:
+            if done:
+                return
+            done.append(True)
+            nbytes = sum(moved) * (2 if filled else 1)
+            sp.set(bytes=nbytes)
+            obs_metrics.get_registry().counter(
+                "chain_carry_bytes").inc(nbytes)
+            stack.close()
+        return release
+
+    def _check_finite(self, plan: Plan, data: np.ndarray) -> None:
+        """The chain result's finiteness check, the ``guard`` span, while
+        the resilience ladder is armed."""
+        if not self.resilience.ladder:
+            return
+        with get_tracer().span("guard"):
+            if not np.isfinite(np.sum(data, dtype=np.float64)):
+                raise NonFiniteOutputError(plan.scheme)
 
     def _chain_hop(self, plan: Plan, cur: HostCSR,
                    b: Optional[HostCSR]) -> HostCSR:
-        """One hop ``cur · (b if b is not None else cur)`` → HostCSR.
+        """One hop ``cur · (b if b is not None else cur)`` → HostCSR."""
+        out = self._hop(plan, cur, b)
+        return out.to_host() if isinstance(out, _DeviceCSR) else out
+
+    def _hop(self, plan: Plan, cur: HostCSR, b: Optional[HostCSR], *,
+             release: Optional[Callable] = None):
+        """One hop ``cur · (b if b is not None else cur)``: a
+        :class:`_DeviceCSR` from the sparse-C route, or a
+        :class:`HostCSR` from the dense one. ``release`` (see
+        :meth:`_carry`) is called once the operands are on the device.
 
         With the ladder armed, a failing sparse-C route degrades to the
         dense :meth:`execute` path (itself ladder-guarded), recording
@@ -785,7 +1027,7 @@ class Planner:
         policy = self.resilience
         if plan.scheme == "pallas":
             try:
-                host = self._chain_hop_sparse(plan, cur, b)
+                dev = self._chain_hop_sparse(plan, cur, b, release)
             except Exception as e:       # noqa: BLE001 — ladder catches all
                 if not policy.ladder:
                     raise
@@ -798,64 +1040,41 @@ class Planner:
                     fallback="dense_route")
                 obs_metrics.get_registry().counter(
                     "serve_fallbacks", scheme=plan.scheme).inc()
-                host = None
-            if host is not None:
-                return host
-        dense = self.execute(plan, cur, b)
-        return HostCSR.from_dense(dense)
+                dev = None
+            if dev is not None:
+                return dev
+        if release is not None:
+            release(False)
+        return HostCSR.from_dense(self.execute(plan, cur, b))
 
     def _chain_hop_sparse(self, plan: Plan, cur: HostCSR,
-                          b: Optional[HostCSR]) -> Optional[HostCSR]:
+                          b: Optional[HostCSR],
+                          release: Optional[Callable] = None
+                          ) -> Optional[_DeviceCSR]:
         """The sparse-C route of a pallas chain hop, or ``None`` when the
-        compacted grid does not apply (wide B → padded per-tile grid →
-        dense fallback through :meth:`execute`). The packed operands —
-        including the window-major sparse-pair stream — are exec-cached
-        exactly like the dense paths', so the second chain call skips
-        all host packing."""
-        bh_cols = (cur if b is None else b).ncols
-        if not kernel_ops.compact_grid_ok_ncols(bh_cols):
+        compacted grid does not apply (B too wide for a C row strip →
+        dense fallback through :meth:`execute`). Its exec entry is
+        pattern-keyed like :meth:`_pallas_runner`'s and also holds the
+        product's :class:`_ProductPattern`; the product stays on the
+        device."""
+        if not kernel_ops.compact_grid_ok_ncols((cur if b is None
+                                                 else b).ncols):
             return None
-        vk = (_value_digest(cur) if b is None else
-              f"{_value_digest(cur)}|{fingerprint(b)}|{_value_digest(b)}")
-        ck = (f"{plan.fingerprint}|{_plan_digest(plan)}|chain"
-              f"|{'sq' if b is None else 'ab'}|{vk}")
+        entry, slot, filled = self._pattern_slot(plan, cur, b,
+                                                 sparse_out=True)
+        if release is not None:
+            release(filled)
+        _, pattern, _, product = entry
+        _, values, tiled = slot
         tracer = get_tracer()
-        cached = self._exec_cache.get(ck)
-        if cached is None:
-            with tracer.span("pack", fingerprint=plan.fingerprint,
-                             scheme=plan.scheme, kind="sparse_c"):
-                _faults.maybe_fault("pack")
-                ap = _apply_plan_perm(cur, plan, symmetric=b is None)
-                bh = ap if b is None else b
-                bk = select_block_k(bh)
-                bcc = bcc_from_host(ap, block_k=bk)
-                tiled = tiled_csr_from_host(bh, block_k=bk,
-                                            dtype=self.pallas_b_dtype)
-                if not kernel_ops.compact_grid_ok(bcc, tiled):
-                    return None
-                stream = kernel_ops.bcc_compact_stream(
-                    bcc, cover_all_blocks=True)
-                pairs = kernel_ops.build_live_pairs(bcc, tiled, stream)
-                sparse_pairs = kernel_ops.build_sparse_c_pairs(
-                    bcc, tiled, pairs, stream)
-                cached = ("chain", bcc, tiled, stream, pairs, sparse_pairs)
-                self._exec_put(ck, cached)
-            self._note_pack()
-        _, bcc, tiled, stream, pairs, sparse_pairs = cached
         with tracer.span("kernel", scheme=plan.scheme, variant="sparse_c"):
             t0 = time.perf_counter()
-            cc = kernel_ops.bcc_spgemm_sparse_c(
-                bcc, tiled, stream=stream, pairs=pairs,
-                sparse_pairs=sparse_pairs)
-            jax.block_until_ready(cc.slabs)
+            vals = product.take(pattern.run_sparse(values, tiled))
+            with tracer.span("sync"):
+                vals = jax.block_until_ready(vals)
             kernel_s = time.perf_counter() - t0
         self.auditor.record(plan, kernel_s)
-        host = compacted_c_to_host(cc)
-        if plan.perm is not None:
-            inv = np.argsort(np.asarray(plan.perm, dtype=np.int64))
-            host = (host.permute_symmetric(inv) if b is None
-                    else host.permute_rows(inv))
-        return host
+        return _DeviceCSR(vals, product)
 
     def _build_runner(self, plan: Plan, a: HostCSR,
                       b: HostCSR | np.ndarray | None):
@@ -966,20 +1185,37 @@ class Planner:
         return self._unpermuted(out, perm, rows_only=not squared)
 
     def _pallas_runner(self, plan: Plan, a: HostCSR, b: HostCSR | None):
-        """The Pallas Sp×Sp tier, BCC(A) × TiledCSR(B) on the MXU.
+        """The Pallas Sp×Sp tier, BCC(A) × TiledCSR(B) on the MXU, on the
+        pattern-keyed exec entry of :meth:`_pattern_slot`."""
+        entry, slot, _ = self._pattern_slot(plan, a, b)
+        pattern, (_, values, tiled) = entry[1], slot
+        return self._unpermuted(lambda: pattern.run(values, tiled),
+                                plan.perm, rows_only=b is not None)
 
-        Its exec-cache entry is keyed by the operands' patterns: the plan
-        and B's fingerprint. It holds the :class:`SpGEMMPattern`, packed
-        once (the adaptive k-tile height, the compact A stream's ids, the
-        live pairs, the shard partition, all on the device), and one
-        value slot ``(value digest, A's stream values, B's TiledCSR)``. A
-        request whose values differ from the slot's refills both arrays on
-        the device from the sent ``data`` (a ``pack`` span of
+    def _pattern_slot(self, plan: Plan, a: HostCSR, b: HostCSR | None, *,
+                      sparse_out: bool = False) -> tuple:
+        """The exec entry of the Pallas product ``a · (b or a)`` and the
+        value slot holding ``a``'s and ``b``'s values on the device.
+
+        The entry is keyed by the operands' patterns: the plan and B's
+        fingerprint. It holds the :class:`SpGEMMPattern`, packed once
+        (the adaptive k-tile height, the compact A stream's ids, the live
+        pairs, the shard partition, all on the device), and one value
+        slot ``(value digest, A's stream values, B's TiledCSR)``. A
+        request whose values differ from the slot's refills both arrays
+        on the device from the sent ``data`` (a ``pack`` span of
         ``kind="refill"``); the same values again go straight to the
         kernel. A request builds its own slot and launches on it, so
-        concurrent value sets never mix."""
+        concurrent value sets never mix. ``sparse_out`` packs for the
+        sparse-C tier (a chain hop's entry, apart from the dense one) and
+        adds the product's :class:`_ProductPattern`.
+
+        Returns ``(entry, slot, filled)``, ``filled`` true when this call
+        packed or refilled."""
         squared = b is None
+        kind = "chain" if sparse_out else "pallas"
         ck = (f"{plan.fingerprint}|{_plan_digest(plan)}|"
+              + ("chain|" if sparse_out else "")
               + ("sq" if squared else f"ab|{fingerprint(b)}"))
         vk = (_value_digest(a) if squared
               else f"{_value_digest(a)}|{_value_digest(b)}")
@@ -989,7 +1225,8 @@ class Planner:
         if entry is None:
             with tracer.span("pack", fingerprint=plan.fingerprint,
                              scheme=plan.scheme,
-                             kind="sq" if squared else "ab"):
+                             kind=("sparse_c" if sparse_out
+                                   else "sq" if squared else "ab")):
                 _faults.maybe_fault("pack")
                 ap, src = a, None
                 if plan.perm is not None:
@@ -998,24 +1235,28 @@ class Planner:
                 pattern = kernel_ops.pack_spgemm_pattern(
                     ap, bh, block_k=select_block_k(bh), a_src=src,
                     b_src=src if squared else None,
-                    b_dtype=self.pallas_b_dtype)
-                slot = (vk, *pattern.fill(a.data, b_data))
+                    b_dtype=self.pallas_b_dtype, sparse_out=sparse_out)
                 # a list: the value slot is replaced in place
-                entry = ["pallas", pattern, slot]
+                entry = [kind, pattern, None]
+                if sparse_out:
+                    entry.append(_ProductPattern.of(
+                        pattern, a, None if squared else b, plan.perm))
+                slot = (vk, *pattern.fill(a.data, b_data))
+                entry[2] = slot
                 self._exec_put(ck, entry)
             self._note_pack()
-        else:
-            pattern, slot = entry[1], entry[2]
-            if slot[0] != vk:
-                with tracer.span("pack", fingerprint=plan.fingerprint,
-                                 scheme=plan.scheme, kind="refill"):
-                    slot = (vk, *pattern.fill(a.data, b_data))
-                entry[2] = slot
-                obs_metrics.get_registry().counter(
-                    "exec_cache_refills").inc()
-        _, values, tiled = slot
-        return self._unpermuted(lambda: pattern.run(values, tiled),
-                                plan.perm, rows_only=not squared)
+            return entry, slot, True
+        pattern, slot = entry[1], entry[2]
+        if slot[0] == vk:
+            return entry, slot, False
+        # the old values leave the device before the new ones arrive
+        entry[2] = (None, None, None)
+        with tracer.span("pack", fingerprint=plan.fingerprint,
+                         scheme=plan.scheme, kind="refill"):
+            slot = (vk, *pattern.fill(a.data, b_data))
+        entry[2] = slot
+        obs_metrics.get_registry().counter("exec_cache_refills").inc()
+        return entry, slot, True
 
     def _exec_put(self, key: str, packed: tuple) -> None:
         while len(self._exec_cache) >= self._exec_cache_cap:
@@ -1112,8 +1353,8 @@ def execute(plan: Plan, a: HostCSR,
     return default_planner().execute(plan, a, b)
 
 
-def execute_chain(a: HostCSR, *, hops: int = 2,
+def execute_chain(a: HostCSR, operands: Optional[Sequence[HostCSR]] = None,
                   **kwargs) -> tuple[HostCSR, list]:
-    """Chained product ``A^(hops+1)`` via the default planner (see
-    :meth:`Planner.execute_chain`)."""
-    return default_planner().execute_chain(a, hops=hops, **kwargs)
+    """Chained product ``a · operands[0] · …`` (``hops=k``: ``A^(k+1)``)
+    via the default planner (see :meth:`Planner.execute_chain`)."""
+    return default_planner().execute_chain(a, operands, **kwargs)

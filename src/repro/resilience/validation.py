@@ -107,8 +107,10 @@ def validate_dense_operand(b, a_ncols: int) -> None:
 
 def validate_request_pair(a, b=None, *, skip=None) -> None:
     """The :meth:`SpGEMMServer.submit` boundary check: ``a`` (always a
-    sparse CSR), plus ``b`` when present — a second CSR (shape-chained)
-    or a dense SpMM operand.
+    sparse CSR), plus ``b`` when present — a second CSR (shape-chained),
+    a tuple or list of CSR operands (a chain ``a · b[0] · … · b[-1]``,
+    each checked and the whole shape chain with it) or a dense SpMM
+    operand.
 
     ``skip`` is an optional ``obj -> bool`` predicate (the policy's
     validation memo): a True return skips that object's O(nnz) content
@@ -120,12 +122,24 @@ def validate_request_pair(a, b=None, *, skip=None) -> None:
         validate_host_csr(a, "a")
     if b is None:
         return
-    if hasattr(b, "indptr"):            # HostCSR-shaped
-        if skip is None or not skip(b):
-            validate_host_csr(b, "b")
-        if a.shape[1] != b.shape[0]:
+    if isinstance(b, (tuple, list)):    # a chain of CSR operands
+        if not b or not all(hasattr(m, "indptr") for m in b):
             raise InvalidOperandError(
-                "shape", "a.ncols must equal b.nrows",
-                a_ncols=a.shape[1], b_nrows=b.shape[0])
+                "shape", "a chain's b must be one or more CSR operands",
+                operands=len(b))
+        names = [f"b[{i}]" for i in range(len(b))]
+    elif hasattr(b, "indptr"):          # HostCSR-shaped
+        b, names = (b,), ["b"]
     else:
         validate_dense_operand(b, a.shape[1])
+        return
+    left, left_name = a, "a"
+    for m, name in zip(b, names):
+        if skip is None or not skip(m):
+            validate_host_csr(m, name)
+        if left.shape[1] != m.shape[0]:
+            raise InvalidOperandError(
+                "shape", f"{left_name}.ncols must equal {name}.nrows",
+                **{f"{left_name}_ncols": left.shape[1],
+                   f"{name}_nrows": m.shape[0]})
+        left, left_name = m, name

@@ -75,14 +75,16 @@ class BatchPolicy:
 def batchable(req: QueuedRequest, policy: BatchPolicy) -> bool:
     """Whether one request is eligible for block-diagonal packing.
 
-    Chain requests (``hops``) and dense-B SpMM are excluded — their
-    results are not diagonal blocks of a packed product (a chain
-    re-fingerprints per hop; a dense B has no column band to own).
-    Sparse A·B pairs and square A² requests qualify when the member is
-    sub-threshold. Requests already routed to the identity rung by an
-    admission downgrade keep their guaranteed-cheap single path.
+    Chain requests (``hops``, or a tuple of operands as ``b``) and
+    dense-B SpMM are excluded — their results are not diagonal blocks of
+    a packed product (a chain re-fingerprints per hop; a dense B has no
+    column band to own). Sparse A·B pairs and square A² requests qualify
+    when the member is sub-threshold. Requests already routed to the
+    identity rung by an admission downgrade keep their guaranteed-cheap
+    single path.
     """
-    if not policy.enabled or req.hops is not None or req.downgrade:
+    if (not policy.enabled or req.hops is not None or req.downgrade
+            or isinstance(req.b, (tuple, list))):
         return False
     a = req.a
     if not isinstance(a, HostCSR) or a.nrows > policy.max_member_rows:

@@ -106,7 +106,7 @@ class SpGEMMServer:
         self.plan_hits = 0
 
     def submit(self, a: HostCSR,
-               b: HostCSR | np.ndarray | None = None, *,
+               b: HostCSR | np.ndarray | tuple | None = None, *,
                reuse_hint: Optional[int] = None,
                hops: Optional[int] = None) -> SpGEMMResponse:
         """Plan (or fetch the cached plan for) ``a``, then execute a·b.
@@ -115,13 +115,20 @@ class SpGEMMServer:
         workload — its plan is scored (and measured) on the tall-skinny
         kernel menu, cached separately from the same pattern's A² plan.
 
-        ``hops`` routes the request through the planner's ``chain``
-        workload instead: the result is ``A^(hops+1)`` computed by
-        :meth:`repro.planner.service.Planner.execute_chain` (``b`` must
-        be ``None``), ``result`` is the sparse :class:`HostCSR` product,
-        and the response reports the first hop's plan — with
+        A tuple (or list) of :class:`HostCSR` as ``b`` asks for the
+        chained product of distinct, possibly rectangular operands,
+        ``a · b[0] · … · b[-1]`` — AMG's Galerkin product is
+        ``submit(R, (A, P))``. ``hops=k`` is the special case
+        ``b = (a,) * k``, ``A^(k+1)`` (``b`` must be ``None``). Both run
+        under workload ``"chain"`` through
+        :meth:`repro.planner.service.Planner.execute_chain`, which picks
+        the association from the shapes; each hop of an operand chain is
+        planned as the Sp×Sp product it is (``"a2"``), a power chain's
+        under ``"chain"``. ``result`` is the sparse :class:`HostCSR`
+        product, and the response reports the first hop's plan — with
         ``plan_cache_hit`` true only when *every* hop hit the cache (the
-        steady serving state for a recurring chain).
+        steady serving state for a recurring chain) and ``kernel_path``
+        ``"pallas"`` only when every hop was planned on it.
 
         Each request runs under a ``request`` span, after a ``validate``
         span for the operand checks (its trace id is returned as
@@ -152,6 +159,7 @@ class SpGEMMServer:
         if hops is not None and b is not None:
             raise ValueError("chain requests take b=None (A^k workload)")
         workload = ("chain" if hops is not None
+                    or isinstance(b, (tuple, list))
                     else "spmm" if (b is not None
                                     and not isinstance(b, HostCSR))
                     else "a2")
@@ -171,8 +179,9 @@ class SpGEMMServer:
                                 field=e.field).inc()
                     raise
                 policy.mark_validated(a)
-                if b is not None and hasattr(b, "indptr"):
-                    policy.mark_validated(b)
+                for m in (b if isinstance(b, (tuple, list)) else (b,)):
+                    if hasattr(m, "indptr"):
+                        policy.mark_validated(m)
         with tracer.span("request", tenant=self.tenant,
                          workload=workload) as root:
             resp = self._submit_impl(a, b, hint=hint, hops=hops,
@@ -189,10 +198,12 @@ class SpGEMMServer:
         result is ready before the closing ``perf_counter`` read."""
         policy = self.planner.resilience
         inc0 = policy.fallbacks
-        if hops is not None:
+        if workload == "chain":
             t0 = time.perf_counter()
             out, plans = self.planner.execute_chain(
-                a, hops=hops, reuse_hint=hint, measure=self.measure)
+                a, (a,) * hops if hops is not None else tuple(b),
+                reuse_hint=hint, measure=self.measure,
+                workload="chain" if hops is not None else "a2")
             t1 = time.perf_counter()
             hit = all(p.from_cache for p in plans)
             if hit:
@@ -205,7 +216,7 @@ class SpGEMMServer:
             return SpGEMMResponse(
                 result=out, fingerprint=lead.fingerprint,
                 reorder=lead.reorder, scheme=lead.scheme, workload="chain",
-                kernel_path=("pallas" if any(p.scheme == "pallas"
+                kernel_path=("pallas" if all(p.scheme == "pallas"
                                              for p in plans) else "xla"),
                 plan_cache_hit=hit, plan_s=plan_s,
                 execute_s=max(t1 - t0 - plan_s, 0.0),

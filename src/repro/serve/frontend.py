@@ -501,14 +501,17 @@ class AsyncSpGEMMServer:
 
     def _coalesce_key(self, fp: str, a, b, hops) -> str:
         """Identity key for single-flight result sharing: pattern AND
-        values of every operand (plus the workload shape). Requests that
-        differ only in values share plan/pack through the planner's
-        caches instead."""
+        values of every operand — each of a chain's ``b`` too — plus the
+        workload shape. Requests that differ only in values share
+        plan/pack through the planner's caches instead."""
         try:
             if b is None:
                 bpart = f"sq|h{hops if hops is not None else 0}"
             elif isinstance(b, HostCSR):
                 bpart = f"csr|{fingerprint(b)}|{_value_digest(b)}"
+            elif isinstance(b, (tuple, list)):      # a chain of operands
+                bpart = "chain|" + "|".join(
+                    f"{fingerprint(m)}|{_value_digest(m)}" for m in b)
             else:
                 import hashlib
                 d = hashlib.blake2b(digest_size=8)
