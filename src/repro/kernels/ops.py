@@ -45,7 +45,7 @@ __all__ = ["on_tpu", "pallas_shard_count", "stream_chunk", "bcc_spmm",
            "build_sparse_c_pairs", "predict_c_window_density",
            "compact_grid_ok", "compact_grid_ok_ncols", "bcc_spgemm_tiled",
            "bcc_spgemm_sparse_c", "SpGEMMPattern", "pack_spgemm_pattern",
-           "flash_mha", "fused_ssd"]
+           "pack_spmm_stream", "flash_mha", "fused_ssd"]
 
 # VMEM budget for pinning TiledCSR's tile store on-chip (leave headroom for
 # the A slab / C tile double buffers out of the 16 MiB core budget)
@@ -207,17 +207,23 @@ def bcc_compact_stream_reference(a: BCC, *, cover_all_blocks: bool = False
     return block_ids, tile_ids[keep].astype(np.int32), vals
 
 
-def bcc_spmm_compact(a: BCC, b: jax.Array, *, bn: int = 128,
+def bcc_spmm_compact(a: BCC | BCCShape, b: jax.Array, *, bn: int = 128,
                      interpret: bool | None = None,
                      stream: tuple | None = None) -> jax.Array:
-    """C = A_bcc @ B via the compact-stream kernel (no padding compute)."""
+    """C = A_bcc @ B via the compact-stream kernel (no padding compute).
+
+    ``stream`` is A's compact stream (:func:`bcc_compact_stream`, or
+    :func:`pack_spmm_stream` for a :class:`BCCShape` ``a``); a stream
+    already on the device launches as it is, with no ``upload``."""
     if interpret is None:
         interpret = not on_tpu()
     if stream is None:
         # cover_all_blocks: a block with no live tiles must still appear
         # once so the compact-grid kernel zero-initializes its C strip
         stream = bcc_compact_stream(a, cover_all_blocks=True)
-    block_ids, tile_ids, values = to_device(*stream)
+    if not all(isinstance(s, jax.Array) for s in stream):
+        stream = to_device(*stream)
+    block_ids, tile_ids, values = stream
     k_needed = ((a.ncols + a.block_k - 1) // a.block_k) * a.block_k
     if b.shape[0] < k_needed:
         b = jnp.pad(b, ((0, k_needed - b.shape[0]), (0, 0)))
@@ -600,6 +606,38 @@ def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
     return out[: a.nrows, : b.ncols]
 
 
+def _compact_layout(h: HostCSR, block_r: int, block_k: int) -> tuple:
+    """The value-free part of ``bcc_compact_stream(bcc_from_host(h),
+    cover_all_blocks=True)``, on the host: ``(stream_ids, ntiles, pos,
+    values_shape)``, where ``stream_ids`` is ``(block_ids, tile_ids)``
+    and ``pos[i]`` is the flat index of nonzero ``i`` in the stream's
+    ``values_shape`` slabs."""
+    tile_ids, ntiles, tpb, pos = bcc_layout(h, block_r, block_k)
+    keep, live = _compact_keep(ntiles, tpb, cover_all_blocks=True)
+    stream_ids = ((keep // tpb).astype(np.int32),
+                  tile_ids[keep].astype(np.int32))
+    # the lattice position → its step of the compact stream
+    slab = block_r * block_k
+    step_of = np.zeros(ntiles.shape[0] * tpb, dtype=np.int64)
+    step_of[keep[:live]] = np.arange(live)
+    pos = step_of[pos // slab] * slab + pos % slab
+    return stream_ids, ntiles, pos, (keep.shape[0], block_r, block_k)
+
+
+def pack_spmm_stream(h: HostCSR, *, block_r: int = 8, block_k: int = 128
+                     ) -> tuple[BCCShape, tuple]:
+    """A's compact stream for :func:`bcc_spmm_compact`, built on the host
+    from its layout and ``data`` and uploaded once: ``(shape, (block_ids,
+    tile_ids, values))``, the arrays on the device and the same as
+    ``bcc_compact_stream(bcc_from_host(h), cover_all_blocks=True)``, with
+    no padded value lattice built, uploaded or read back."""
+    stream_ids, _, pos, values_shape = _compact_layout(h, block_r, block_k)
+    values = np.zeros(values_shape, dtype=np.float32)
+    values.reshape(-1)[pos] = h.data
+    return (BCCShape(h.nrows, h.ncols, block_r, block_k),
+            to_device(*stream_ids, values))
+
+
 @dataclasses.dataclass(frozen=True)
 class SpGEMMPattern:
     """The Sp×Sp operands packed from their patterns alone, on the device.
@@ -713,17 +751,9 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
     compacted grid (:func:`compact_grid_ok_ncols`).
     """
     block_r, bn = 8, 128
-    tile_ids, ntiles, tpb, a_pos = bcc_layout(ap, block_r, block_k)
+    stream_ids, ntiles, a_pos, values_shape = _compact_layout(
+        ap, block_r, block_k)
     table, tile_cap, b_pos = tiled_layout(bh, block_k, bn)
-    keep, live = _compact_keep(ntiles, tpb, cover_all_blocks=True)
-    stream_ids = ((keep // tpb).astype(np.int32),
-                  tile_ids[keep].astype(np.int32))
-    # A's lattice position → its step of the compact stream
-    slab = block_r * block_k
-    step_of = np.zeros(ntiles.shape[0] * tpb, dtype=np.int64)
-    step_of[keep[:live]] = np.arange(live)
-    a_pos = step_of[a_pos // slab] * slab + a_pos % slab
-    values_shape = (keep.shape[0], block_r, block_k)
     tiles_shape = (tile_cap, block_k, bn)
     a_map = _value_map(a_pos, a_src, math.prod(values_shape))
     b_map = _value_map(b_pos, b_src, math.prod(tiles_shape))

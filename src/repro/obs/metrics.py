@@ -68,6 +68,9 @@ _HOST_METRICS: dict[str, tuple[str, str]] = {
     "exec_cache_refills": (
         "counter", "Sp×Sp value refills on the device: a pattern hit "
         "with new operand values (count)"),
+    "exec_cache_hits": (
+        "counter", "launches on a cached exec entry with no pack and no "
+        "refill, by entry kind label (count)"),
     "exec_cache_entries": (
         "gauge", "packed operand sets resident in the exec cache (count)"),
     "kernel_launches": (

@@ -45,9 +45,8 @@ from repro.core.clustering import (DEFAULT_MAX_CLUSTER,
                                    fixed_length_clusters,
                                    hierarchical_clusters,
                                    variable_length_clusters)
-from repro.core.formats import (HostCSR, bcc_from_host,
-                                csr_cluster_from_host, csr_from_host,
-                                select_block_k)
+from repro.core.formats import (HostCSR, csr_cluster_from_host,
+                                csr_from_host, select_block_k)
 from repro.core.reorder import reorder as apply_reorder
 from repro.core.spgemm import (length_bins, slot_rows_host,
                                spgemm_clusterwise_dense_binned,
@@ -1112,10 +1111,10 @@ class Planner:
                         dev = csr_from_host(ap)
                         cached = ("spmm_row", dev)
                     elif plan.scheme == "pallas":
-                        bcc = bcc_from_host(ap)
-                        stream = kernel_ops.bcc_compact_stream(
-                            bcc, cover_all_blocks=True)
-                        cached = ("spmm_pallas", bcc, stream)
+                        # the compact stream, on the device: a hit
+                        # uploads only X
+                        cached = ("spmm_pallas",
+                                  *kernel_ops.pack_spmm_stream(ap))
                     else:
                         cc = csr_cluster_from_host(
                             ap, self._bounds(plan, ap),
@@ -1123,14 +1122,16 @@ class Planner:
                         cached = ("spmm_cluster", cc)
                     self._exec_put(ck, cached)
                 self._note_pack()
+            else:
+                self._note_hit(cached[0])
             kind = cached[0]
             if kind == "spmm_row":
                 op = cached[1]
                 out = lambda: spmm_rowwise(op, bd)         # noqa: E731
             elif kind == "spmm_pallas":
-                _, bcc, stream = cached
+                _, shape, stream = cached
                 out = lambda: kernel_ops.bcc_spmm_compact(  # noqa: E731
-                    bcc, bd, stream=stream)
+                    shape, bd, stream=stream)
             else:
                 op = cached[1]
                 out = lambda: spmm_clusterwise(op, bd)     # noqa: E731
@@ -1173,6 +1174,8 @@ class Planner:
                     cached = ("cluster", cc, dev_b, bins, sclust)
                 self._exec_put(ck, cached)
             self._note_pack()
+        else:
+            self._note_hit(cached[0])
         kind = cached[0]
         if kind == "row":
             _, op_a, op_b, bins, srows = cached
@@ -1248,6 +1251,7 @@ class Planner:
             return entry, slot, True
         pattern, slot = entry[1], entry[2]
         if slot[0] == vk:
+            self._note_hit(entry[0])
             return entry, slot, False
         # the old values leave the device before the new ones arrive
         entry[2] = (None, None, None)
@@ -1268,6 +1272,13 @@ class Planner:
         reg = obs_metrics.get_registry()
         reg.counter("exec_cache_packs").inc()
         reg.gauge("exec_cache_entries").set(len(self._exec_cache))
+
+    @staticmethod
+    def _note_hit(kind: str) -> None:
+        """Account one launch on a cached exec entry, with no pack and no
+        refill, labelled by the entry's kind."""
+        obs_metrics.get_registry().counter("exec_cache_hits",
+                                           kind=kind).inc()
 
     def _note_probe_skip(self) -> None:
         """Account one wall-clock-capped probe skip."""
