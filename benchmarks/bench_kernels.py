@@ -25,10 +25,7 @@ matrix:
   counter under-reported the padded grid's A traffic ``nnb``-fold.
 * **bf16 tile store**: B bytes of the fp32 tile store over the bf16 one
   (≈ 2× — same live lattice, half the bytes per slot).
-* **revisit + sharding counters** (ISSUE 5): ``b_tile_refetches`` of the
-  (block, s, j)-ordered stream over the B-fetch-deduping revisit order
-  (gate: ≥ 1.15× geomean — triples sharing a tile made adjacent across
-  blocks within VMEM-budget windows), and the worst per-core live-pair
+* **sharding counters** (ISSUE 5): the worst per-core live-pair
   imbalance of the 4-way contiguous-block-range partition over the ideal
   split (gate: ≤ 1.2, i.e. within 20% of ideal).
 * **sparse-C output counters** (ISSUE 6): C bytes the dense row strips
@@ -61,6 +58,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from unittest import mock
 
 import jax.numpy as jnp
 import numpy as np
@@ -71,8 +69,7 @@ from repro.core.formats import (COUNTER_UNITS, CompactedC, bcc_from_host,
                                 compacted_c_counters, compacted_c_table,
                                 compacted_c_to_host, csr_from_host,
                                 live_pair_counters, partition_balance,
-                                partition_pair_stream, revisit_pair_stream,
-                                revisit_window_blocks, tiled_csr_from_host,
+                                partition_pair_stream, tiled_csr_from_host,
                                 tiled_live_tiles)
 from repro.core.reorder import reorder
 from repro.core.spgemm import (b_bytes_rowwise_binned, b_bytes_tiled,
@@ -92,8 +89,6 @@ GATE_STEPS_PER_MXU = 1.1          # compacted grid: ≤ this, geomean
 GATE_A_BYTES_RATIO = 2.0          # padded-grid A bytes / compacted, ≥
 GATE_B_ROUTED_RATIO = 1.2         # routed B-traffic ratio vs XLA, ≥
 GATE_BF16_RATIO = 1.9             # fp32 / bf16 B tile store bytes, ≥
-GATE_B_REFETCH_RATIO = 1.15       # B tile refetches, unordered over
-                                  # revisit-ordered, geomean ≥
 GATE_SHARD_BALANCE = 1.2          # worst per-core live-pair imbalance
                                   # over the ideal split, ≤ (within 20%)
 GATE_C_BYTES_RATIO = 2.0          # dense-strip / CompactedC C bytes
@@ -118,8 +113,7 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
     specs = tier_specs(tier)
     rows = []
     ratios_tiled, ratios_routed = [], []
-    steps_per_mxu, a_ratios, bf16_ratios = [], [], []
-    refetch_ratios, balances = [], []
+    steps_per_mxu, a_ratios, bf16_ratios, balances = [], [], [], []
     c_ratios_sparse, c_densities = [], []
     smallest = None              # (nnz, HostCSR) for the parity check below
     for spec in specs:
@@ -137,7 +131,9 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
         bcc = bcc_from_host(best_mat, block_r=BLOCK_R, block_k=BLOCK_K)
         stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
         tiled_b = tiled_csr_from_host(best_mat, BLOCK_K, BN)
-        pairs = ops.build_live_pairs(bcc, tiled_b, stream)
+        pattern = ops.pack_spgemm_pattern(best_mat, best_mat,
+                                          block_k=BLOCK_K)
+        pairs = pattern.pairs
         routed_b = min(xla_b, best_b)
         ratio_tiled = xla_b / max(best_b, 1)
         ratio_routed = xla_b / max(routed_b, 1)
@@ -158,21 +154,7 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
         cnt = live_pair_counters(pairs, block_r=BLOCK_R, block_k=BLOCK_K,
                                  bn=BN)
         a_ratio = a_bytes_padded / max(cnt["a_bytes"], 1)
-        # B-fetch-deduping revisit order (ISSUE 5): within VMEM-budget
-        # windows of C strips, triples sharing a B tile sit adjacent
-        # across blocks — the streamed kernel's DMA elision then fetches
-        # each live tile once per window instead of once per touching
-        # block. The gate is on the refetch excess (fetches beyond one
-        # per distinct tile), floored at 1 so a fully-deduped stream
-        # (0 refetches) still yields a finite ratio.
         nblocks = (best_mat.nrows + BLOCK_R - 1) // BLOCK_R
-        wb = min(revisit_window_blocks(tiled_b.nnb, block_r=BLOCK_R,
-                                       bn=BN), nblocks)
-        rv = revisit_pair_stream(pairs, window_blocks=wb)
-        cnt_rv = live_pair_counters(rv, block_r=BLOCK_R, block_k=BLOCK_K,
-                                    bn=BN)
-        refetch_ratio = (max(cnt["b_tile_refetches"], 1)
-                         / max(cnt_rv["b_tile_refetches"], 1))
         # multi-core partition: contiguous block ranges balanced by
         # live-pair count — worst per-core load over the ideal split
         _, shard_pairs = partition_pair_stream(
@@ -229,10 +211,6 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
             "b_bytes_bf16_ratio": bf16_ratio,
             "b_tile_fetches": cnt["b_tile_fetches"],
             "b_tile_refetches": cnt["b_tile_refetches"],
-            "b_tile_refetches_revisit": cnt_rv["b_tile_refetches"],
-            "b_tile_refetch_ratio": refetch_ratio,
-            "revisit_window_blocks": wb,
-            "a_fetches_revisit": cnt_rv["a_fetches"],
             "shard_balance": balance,
             "c_window_density": c_density,
             "c_routed": "sparse" if c_sparse_routed else "dense",
@@ -242,16 +220,14 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
         steps_per_mxu.append(cnt["steps_per_mxu"])
         a_ratios.append(a_ratio)
         bf16_ratios.append(bf16_ratio)
-        refetch_ratios.append(refetch_ratio)
         balances.append(balance)
         c_densities.append(c_density)
         if c_sparse_routed:
             c_ratios_sparse.append(c_ratio)
         if ops.on_tpu():
             # compiled wall-clock — only meaningful on the real MXU
-            t_pal = time_fn(
-                lambda: ops.bcc_spgemm_tiled(bcc, tiled_b, stream=stream,
-                                             pairs=pairs))
+            filled = pattern.fill(best_mat.data)
+            t_pal = time_fn(lambda: pattern.run(*filled))
             dev = csr_from_host(a)
             bins = length_bins(a.row_nnz()[a.indices],
                                pad_sentinel=dev.nnz_cap)
@@ -274,30 +250,32 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
     # fp32 compacted grid (bit-level vs reference tolerance) and the bf16
     # tile store (documented looser bound)
     sm = _principal_submatrix(smallest[1], 192)
-    bcc = bcc_from_host(sm, block_r=BLOCK_R, block_k=BLOCK_K)
-    tiled = tiled_csr_from_host(sm, BLOCK_K, BN)
     want = spgemm_reference(sm, sm)
+
+    def product(**kw):
+        pattern = ops.pack_spgemm_pattern(sm, sm, block_k=BLOCK_K, **kw)
+        return np.asarray(pattern.run(*pattern.fill(sm.data)))
     t0 = time.perf_counter()
-    got = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True))
+    got = product()
     t_interp = time.perf_counter() - t0
     err = float(np.abs(got - want).max())
-    tiled16 = tiled_csr_from_host(sm, BLOCK_K, BN, dtype=jnp.bfloat16)
-    got16 = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled16, interpret=True))
+    got16 = product(b_dtype=jnp.bfloat16)
     scale = max(float(np.abs(want).max()), 1e-9)
     err16 = float(np.abs(got16 - want).max()) / scale
-    # sharded (serial partition) + revisit-ordered variants: bit-identical
-    # to the unsharded compacted grid by construction, so the parity bound
-    # is the same 1e-4
-    got_sh = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True,
-                                             shards=2, revisit=True))
+    # sharded (serial partition): bit-identical to the unsharded
+    # compacted grid by construction, so the parity bound is the same
+    # 1e-4
+    with mock.patch.object(ops, "pallas_shard_count", lambda: 2):
+        got_sh = product()
     err_sh = float(np.abs(got_sh - want).max())
     # sparse-C kernel epilogue end-to-end: windowed-scatter compaction in
     # the kernel, CompactedC → HostCSR — same s-ascending fp32
     # accumulation per window as the dense-strip kernel, so the round
     # trip must reproduce its output bit for bit (and its reference
     # error exactly)
-    cc_sm = ops.bcc_spgemm_sparse_c(bcc, tiled, interpret=True,
-                                    epilogue="kernel")
+    pattern_sc = ops.pack_spgemm_pattern(sm, sm, block_k=BLOCK_K,
+                                         sparse_out=True)
+    cc_sm = ops._sparse_c_kernel(pattern_sc, *pattern_sc.fill(sm.data))
     got_sc = compacted_c_to_host(cc_sm).to_dense()
     assert np.array_equal(got_sc, got[:got_sc.shape[0], :got_sc.shape[1]]), \
         "sparse-C round trip diverged from the dense-strip kernel"
@@ -310,7 +288,6 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
         "grid_steps_per_mxu_gm": geomean(steps_per_mxu),
         "a_bytes_ratio_compact_gm": geomean(a_ratios),
         "b_bytes_bf16_ratio_gm": geomean(bf16_ratios),
-        "b_tile_refetch_ratio_gm": geomean(refetch_ratios),
         "shard_balance_worst": max(balances) if balances else float("nan"),
         "c_bytes_ratio_gm": (geomean(c_ratios_sparse)
                              if c_ratios_sparse else float("nan")),
@@ -398,7 +375,6 @@ def check_gates(summary: dict) -> list[str]:
         ("a_bytes_ratio_compact_gm", ">=", GATE_A_BYTES_RATIO),
         ("b_bytes_ratio_routed_gm", ">=", GATE_B_ROUTED_RATIO),
         ("b_bytes_bf16_ratio_gm", ">=", GATE_BF16_RATIO),
-        ("b_tile_refetch_ratio_gm", ">=", GATE_B_REFETCH_RATIO),
         ("shard_balance_worst", "<=", GATE_SHARD_BALANCE),
         ("c_bytes_ratio_gm", ">=", GATE_C_BYTES_RATIO),
     ]

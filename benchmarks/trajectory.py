@@ -70,9 +70,6 @@ _GATED = [
     ("kernels", ("grid_steps_per_mxu_gm",), False),
     ("kernels", ("a_bytes_ratio_compact_gm",), True),
     ("kernels", ("b_bytes_bf16_ratio_gm",), True),
-    # B-fetch-deduping revisit order (ISSUE 5): unordered-over-revisit
-    # B tile refetch excess (higher is better — the dedup win)
-    ("kernels", ("b_tile_refetch_ratio_gm",), True),
     # sparse-C output tier (ISSUE 6): dense-strip over CompactedC C bytes
     # written, geomean over the sparse-routed (output-density ≤ threshold)
     # families — the ≥2× acceptance gate lives in bench_kernels; here the
@@ -201,7 +198,7 @@ def _sum_kernels(res: dict) -> dict:
             "routed_pallas_pct", "interp_parity_max_err",
             "interp_parity_bf16_rel_err", "grid_steps_per_mxu_gm",
             "a_bytes_ratio_compact_gm", "b_bytes_bf16_ratio_gm",
-            "b_tile_refetch_ratio_gm", "shard_balance_worst",
+            "shard_balance_worst",
             "interp_parity_sharded_max_err", "pallas_wallclock_speedup_gm",
             "c_bytes_ratio_gm", "c_window_density_gm",
             "interp_parity_sparse_c_max_err")

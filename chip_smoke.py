@@ -33,8 +33,8 @@ script exits non-zero before doing any work.
 
 ``--chips 4`` runs phase (a) on the default multi-chip path, which shards
 the pair stream over all four chips. It compares that result with the
-one-chip kernel (``shards=1``, device 0) and with scipy, and checks that
-the sharded output spans the four devices.
+one-chip kernel (the pattern packed for one core, on device 0) and with
+scipy, and checks that the sharded output spans the four devices.
 """
 from __future__ import annotations
 
@@ -244,20 +244,26 @@ def phase_d(seed: int, scale: int = SCALE):
 
 def four_chips(seed: int, scale: int = SCALE):
     """Phase (a) on the default sharded path, then the same product
-    through the kernel tier directly: sharded over every chip and on one
-    chip (``shards=1``, device 0), bit for bit."""
+    through the kernel tier directly: the pattern packed for every chip
+    and for one chip (device 0), bit for bit."""
+    from unittest import mock
+
     import jax
-    from repro.core.formats import (bcc_from_host, select_block_k,
-                                    tiled_csr_from_host)
+    from repro.core.formats import select_block_k
     from repro.kernels import ops
     a, ref, served = phase_a(seed, scale, variant="sharded")
     bk = select_block_k(a)
+
+    def product():
+        pattern = ops.pack_spgemm_pattern(a, a, block_k=bk)
+        return pattern.route, jax.block_until_ready(
+            pattern.run(*pattern.fill(a.data)))
     with jax.default_device(jax.devices()[0]):
-        bcc = bcc_from_host(a, block_k=bk)
-        tiled = tiled_csr_from_host(a, block_k=bk)
-        sharded = jax.block_until_ready(ops.bcc_spgemm_tiled(bcc, tiled))
-        one = jax.block_until_ready(ops.bcc_spgemm_tiled(bcc, tiled,
-                                                         shards=1))
+        route, sharded = product()
+        with mock.patch.object(ops, "pallas_shard_count", lambda: 1):
+            one_route, one = product()
+    if route != "sharded" or one_route == "sharded":
+        _fail(f"routes {route} (four cores) and {one_route} (one core)")
     spans = sorted(d.id for d in sharded.sharding.device_set)
     if len(spans) != len(jax.devices()):
         _fail(f"sharded output spans devices {spans}")
@@ -266,7 +272,7 @@ def four_chips(seed: int, scale: int = SCALE):
     if not (np.array_equal(sharded, one) and np.array_equal(served, one)):
         _fail("sharded result differs from the one-chip result")
     print(f"four chips: sharded output spans devices {spans} "
-          f"({sharded.shape}); shards=1 on devices {one_on}; served, "
+          f"({sharded.shape}); one core on devices {one_on}; served, "
           f"sharded and one-chip results bit-identical; "
           f"max_abs_err={_check('four chips', one, ref)}", flush=True)
 

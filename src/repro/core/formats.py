@@ -58,8 +58,6 @@ __all__ = [
     "partition_pair_stream",
     "partition_pair_stream_reference",
     "partition_balance",
-    "revisit_pair_stream",
-    "revisit_window_blocks",
     "tile_col_occupancy",
     "symbolic_strip_nnz",
     "symbolic_strip_nnz_reference",
@@ -1203,10 +1201,8 @@ def live_pair_counters(pairs, *, block_r: int, block_k: int,
       equal A stream indices.
     * ``b_tile_fetches`` — live B tile traffic of the *streamed* kernels:
       one fetch per run of equal (live) slots. ``b_tile_refetches`` is the
-      excess over fetching each distinct tile once — exactly what the
-      revisit ordering (:func:`revisit_pair_stream`) removes, and the
-      quantity ``bench_kernels`` gates. ``b_bytes`` needs ``bn`` (the
-      tile width) and is omitted when it is not given.
+      excess over fetching each distinct tile once. ``b_bytes`` needs
+      ``bn`` (the tile width) and is omitted when it is not given.
 
     >>> blocks = [0, 0, 1, 1]; js = [0, 1, 0, 1]
     >>> slots  = [3, 5, 3, 5]; a_idx = [0, 0, 2, 2]
@@ -1242,7 +1238,7 @@ def live_pair_counters(pairs, *, block_r: int, block_k: int,
 
 
 # ---------------------------------------------------------------------------
-# multi-core sharding + B-fetch-deduping revisit order of the pair stream
+# multi-core sharding of the pair stream
 # ---------------------------------------------------------------------------
 
 
@@ -1380,69 +1376,6 @@ def partition_balance(shard_pairs) -> float:
     if total == 0 or not live:
         return 1.0
     return max(live) / (total / len(live))
-
-
-def revisit_window_blocks(nnb: int, *, block_r: int = 8, bn: int = 128,
-                          budget_bytes: int = 2 * 2 ** 20,
-                          value_bytes: int = 4) -> int:
-    """Row-block capacity of the revisit kernel's C window: how many
-    consecutive block strips of ``(block_r, nnb*bn)`` fp32 fit the VMEM
-    accumulator budget. The revisit reorder (:func:`revisit_pair_stream`)
-    may only interleave blocks *within* one such window — the kernel
-    zero-initializes and owns one window at a time.
-
-    >>> revisit_window_blocks(2, block_r=8, bn=128)   # 8 KiB per strip
-    256
-    >>> revisit_window_blocks(10 ** 6)                # huge strip: >= 1
-    1
-    """
-    strip = block_r * nnb * bn * value_bytes
-    return max(1, budget_bytes // max(strip, 1))
-
-
-def revisit_pair_stream(pairs, *, window_blocks: int, block_base: int = 0
-                        ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
-                                   np.ndarray]:
-    """B-fetch-deduping revisit order of a live-pair stream.
-
-    The (block, s, j) order of :func:`live_pair_stream` fetches a B tile
-    once per *block* that touches it — the cross-block reuse the paper's
-    cluster-wise argument (and Nagasaka et al.'s column-blocked multicore
-    SpGEMM) says to exploit. This reorder makes triples sharing a B tile
-    adjacent across blocks, so the streamed kernels' DMA elision collapses
-    them into one fetch: within each window of ``window_blocks``
-    consecutive row blocks (bounded so the C strips fit the VMEM
-    accumulator budget — :func:`revisit_window_blocks`), triples sort by
-    ``(j, slot, block)``.
-
-    Output is **bit-identical** to the unordered kernel: for a fixed
-    ``(block, j)`` C strip the B slot is monotone in the A stream step
-    (table slots are assigned in ascending (kb, nb) key order), so sorting
-    by slot preserves each strip's accumulation order; fp32 addition sees
-    the same operand sequence per element. Zero-slot sentinels and tail
-    pads ride along (they issue no MXU op wherever they land).
-
-    ``block_base`` localizes windows for a shard's sub-stream (windows are
-    relative to the shard's first block). The sort is stable; note that
-    even ``window_blocks=1`` rewrites a block's *internal* order from
-    (s, j) to (j, slot) — only the per-(block, j) accumulation order (and
-    hence the output) is invariant, not the stream itself.
-
-    >>> blocks = [0, 0, 1, 1]; js = [0, 1, 0, 1]
-    >>> slots  = [3, 5, 3, 5]; a_idx = [0, 0, 2, 2]
-    >>> b, j, s, a = revisit_pair_stream((blocks, js, slots, a_idx),
-    ...                                  window_blocks=2)
-    >>> s.tolist()                    # tile 3's fetches now adjacent
-    [3, 3, 5, 5]
-    >>> b.tolist()
-    [0, 1, 0, 1]
-    """
-    blocks, js, slots, a_idx = (np.asarray(p) for p in pairs)
-    if window_blocks < 1:
-        raise ValueError("window_blocks must be >= 1")
-    win = (blocks.astype(np.int64) - block_base) // window_blocks
-    order = np.lexsort((blocks, slots, js, win))
-    return (blocks[order], js[order], slots[order], a_idx[order])
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,7 @@ Two variants, differing in where B lives:
     dynamically. For suite-sized operands this makes B's total HBM
     traffic equal its live-tile footprint — the "pays the bandwidth of
     *its* footprint" endpoint. Use when ``tiles.nbytes`` fits the VMEM
-    budget (the ops-layer wrapper auto-selects).
+    budget (``repro.kernels.ops.pack_spgemm_pattern`` selects it).
 
 Accumulator re-initialization on block-id change mirrors
 ``cluster_spmm_compact``; dead table slots predicate away their MXU issue
@@ -69,8 +69,8 @@ with manual async copies — the tile for step t+1 is in flight while step
 t contracts). All three accept fp32 or bf16 B tiles; bf16 halves B's HBM
 bytes and is upcast at the MXU input, accumulation stays fp32.
 
-Multi-core sharding + B-fetch-deduping revisit order (v3)
----------------------------------------------------------
+Multi-core sharding (v3)
+------------------------
 
 ``cluster_spgemm_pairs_sharded`` scales the pair stream across TPU cores:
 the host partitions the stream into contiguous block ranges balanced by
@@ -95,15 +95,6 @@ window footprint. Within a window pairs stay s-ascending, so each C
 element sees the same fp32 accumulation order as the dense-strip kernels
 — bit-identical values, compacted layout.
 
-``cluster_spgemm_pairs_window`` runs a *revisit-ordered* stream
-(:func:`repro.core.formats.revisit_pair_stream`): triples sharing a B
-tile sit adjacent across blocks, so the streamed-B DMA elision fetches
-each live tile once per window instead of once per touching block. The
-price is a wider C output window — ``window_blocks`` consecutive block
-strips, zero-initialized on window entry — and the loss of A-slab
-adjacency (A refetches rise; the ``live_pair_counters`` report both
-sides of that trade, and ``bench_kernels`` gates the B-refetch win).
-
 Chunked launches
 ----------------
 
@@ -111,7 +102,8 @@ A scalar-prefetched stream lives whole in SMEM (1 MiB on a v5e), so every
 pair-stream kernel runs its stream as launches of at most ``chunk`` steps
 (:mod:`repro.kernels.chunked`), bit-identical to one launch. The padded
 ``(nnb, S)`` grid is not chunked: it prefetches B's whole tile table, and
-the ops layer refuses it when that does not fit.
+``repro.kernels.ops.pack_spgemm_pattern`` refuses it when that does not
+fit.
 """
 from __future__ import annotations
 
@@ -133,9 +125,8 @@ _FP32 = jax.lax.Precision.HIGHEST
 
 __all__ = ["cluster_spgemm_tiled", "cluster_spgemm_resident",
            "cluster_spgemm_pairs", "cluster_spgemm_pairs_resident",
-           "cluster_spgemm_pairs_db", "cluster_spgemm_pairs_window",
-           "cluster_spgemm_pairs_sharded", "cluster_spgemm_pairs_sparse",
-           "cluster_spgemm_pairs_sparse_db"]
+           "cluster_spgemm_pairs_db", "cluster_spgemm_pairs_sharded",
+           "cluster_spgemm_pairs_sparse", "cluster_spgemm_pairs_sparse_db"]
 
 
 def _is_block_start(block_ids_ref, s):
@@ -508,77 +499,6 @@ def cluster_spgemm_pairs_db(blocks: jax.Array, js: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# v3: B-fetch-deduping revisit order (windowed C accumulator)
-# ---------------------------------------------------------------------------
-
-
-def _spgemm_kernel_pairs_window(bn, block_r, window_blocks, meta_ref,
-                                win_ref, blk_ref, j_ref, slot_ref, aidx_ref,
-                                a_ref, b_ref, c_hbm, o_ref, sem):
-    t = pl.program_id(0)
-    # one zero-fill per *window* of strips
-    open_window(t, win_ref, meta_ref, o_ref,
-                _rows(c_hbm, window_blocks * block_r), sem)
-
-    @pl.when(slot_ref[t] > 0)        # sentinels / tail pads: no MXU issue
-    def _acc():
-        col = pl.multiple_of(j_ref[t] * bn, bn)
-        row = pl.multiple_of(
-            (blk_ref[t] - win_ref[t] * window_blocks) * block_r, block_r)
-        prod = jnp.dot(a_ref[0], b_ref[0].astype(jnp.float32),
-                       preferred_element_type=jnp.float32,
-                       precision=_FP32)
-        o_ref[pl.ds(row, block_r), pl.ds(col, bn)] += prod.astype(
-            o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "block_r", "block_k", "bn", "nblocks", "nnb", "window_blocks", "chunk",
-    "interpret"))
-def cluster_spgemm_pairs_window(wins: jax.Array, blocks: jax.Array,
-                                js: jax.Array, slots: jax.Array,
-                                a_idx: jax.Array, a_values: jax.Array,
-                                b_tiles: jax.Array, *, block_r: int,
-                                block_k: int, bn: int, nblocks: int,
-                                nnb: int, window_blocks: int,
-                                chunk: int | None = None,
-                                interpret: bool = False) -> jax.Array:
-    """C = A_bcc @ B_tiled over a revisit-ordered pair stream.
-
-    Same contract as :func:`cluster_spgemm_pairs` except the stream is
-    ordered by :func:`repro.core.formats.revisit_pair_stream` — triples
-    sharing a B tile are adjacent across blocks, so the streamed-B DMA is
-    elided down to one fetch per tile per window — and the C output
-    window covers ``window_blocks`` consecutive block strips
-    (``wins[t] = blocks[t] // window_blocks`` must be non-decreasing; the
-    window is zero-initialized on entry, so every strip it owns reads
-    back exactly its accumulated value, dead strips included).
-
-    Returns: (nblocks * block_r, nnb * bn) dense fp32 C.
-    """
-    assert a_values.shape[1:] == (block_r, block_k)
-    assert b_tiles.shape[1:] == (block_k, bn)
-    nwin = (nblocks + window_blocks - 1) // window_blocks
-    in_specs = [
-        pl.BlockSpec((1, block_r, block_k),
-                     lambda t, m, w, blks, js_, sl, ai: (ai[t], 0, 0)),
-        pl.BlockSpec((1, block_k, bn),
-                     lambda t, m, w, blks, js_, sl, ai: (sl[t], 0, 0)),
-    ]
-    out_spec = pl.BlockSpec((window_blocks * block_r, nnb * bn),
-                            lambda t, m, w, blks, js_, sl, ai: (w[t], 0))
-    out = _stream_call(
-        functools.partial(_spgemm_kernel_pairs_window, bn, block_r,
-                          window_blocks),
-        (wins, blocks, js, slots, a_idx), a_values, b_tiles,
-        name="cluster_spgemm_pairs_window", slot_pos=3,
-        chunk=chunk, in_specs=in_specs, out_spec=out_spec,
-        out_shape=(nwin * window_blocks * block_r, nnb * bn),
-        interpret=interpret)
-    return out[: nblocks * block_r]
-
-
-# ---------------------------------------------------------------------------
 # v3: multi-core sharded pair stream (shard_map over a 1-D core mesh)
 # ---------------------------------------------------------------------------
 
@@ -601,38 +521,21 @@ def _stack_shard_streams(shard_pairs) -> tuple:
 
 
 def _shard_local_call(blocks, js, slots, a_idx, a_values, b_tiles, *,
-                      start, block_r, block_k, bn, max_blocks, nnb,
-                      window_blocks, resident, double_buffer, chunk,
-                      interpret):
+                      start, kernel, block_r, block_k, bn, max_blocks, nnb,
+                      chunk, interpret):
     """One core's kernel launch: localize block ids to the shard's range
-    and run the flat pair grid (windowed when revisit-ordered)."""
-    local = blocks - start
-    if window_blocks is None:
-        if resident:
-            kernel = cluster_spgemm_pairs_resident
-        elif double_buffer:
-            kernel = cluster_spgemm_pairs_db
-        else:
-            kernel = cluster_spgemm_pairs
-        return kernel(
-            local, js, slots, a_idx, a_values, b_tiles,
-            block_r=block_r, block_k=block_k, bn=bn,
-            nblocks=max_blocks, nnb=nnb, chunk=chunk, interpret=interpret)
-    wins = local // window_blocks
-    return cluster_spgemm_pairs_window(
-        wins, local, js, slots, a_idx, a_values, b_tiles,
-        block_r=block_r, block_k=block_k, bn=bn, nblocks=max_blocks,
-        nnb=nnb, window_blocks=window_blocks, chunk=chunk,
-        interpret=interpret)
+    and run the flat pair grid."""
+    return kernel(blocks - start, js, slots, a_idx, a_values, b_tiles,
+                  block_r=block_r, block_k=block_k, bn=bn,
+                  nblocks=max_blocks, nnb=nnb, chunk=chunk,
+                  interpret=interpret)
 
 
 def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
                                  a_values: jax.Array, b_tiles: jax.Array,
                                  *, block_r: int, block_k: int, bn: int,
                                  nblocks: int, nnb: int,
-                                 window_blocks: int | None = None,
-                                 resident: bool = False,
-                                 double_buffer: bool = False,
+                                 kernel=cluster_spgemm_pairs,
                                  chunk: int | None = None,
                                  interpret: bool = False,
                                  use_shard_map: bool | None = None
@@ -641,22 +544,14 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
 
     Args:
       shard_pairs: per-core ``(blocks, js, slots, a_idx)`` sub-streams
-        from :func:`repro.core.formats.partition_pair_stream` (each
-        optionally revisit-ordered relative to its own first block —
-        pass ``window_blocks`` iff so).
+        from :func:`repro.core.formats.partition_pair_stream`.
       block_ranges: (S, 2) contiguous ``[start, end)`` block ranges of
         the same partition — shard ``i`` owns C rows
         ``start_i*block_r .. end_i*block_r``.
       a_values / b_tiles: the full (replicated) A slab array and B tile
         store — every core indexes them through its own sub-stream.
-      window_blocks: the revisit window of each shard's sub-stream, or
-        ``None`` for (block, s, j)-ordered shards.
-      resident: pin B's tile store in each core's VMEM (only for
-        unordered shards — the revisit order exists to dedup *streamed*
-        tile fetches, which a resident store does not pay).
-      double_buffer: run each core's streamed sub-stream through the
-        two-slot manual-DMA prefetch kernel (unordered shards only;
-        ignored when ``resident`` or ``window_blocks`` applies).
+      kernel: the pair kernel each core runs on its sub-stream
+        (:func:`cluster_spgemm_pairs`, ``_resident`` or ``_db``).
       chunk: most pairs per launch of each core's sub-stream (see
         :func:`cluster_spgemm_pairs`).
       use_shard_map: force the ``shard_map`` dispatch (needs one device
@@ -676,10 +571,9 @@ def cluster_spgemm_pairs_sharded(shard_pairs, block_ranges,
     if use_shard_map is None:
         use_shard_map = (not interpret and n_shards > 1
                          and jax.device_count() >= n_shards)
-    kw = dict(block_r=block_r, block_k=block_k, bn=bn,
-              max_blocks=max_blocks, nnb=nnb,
-              window_blocks=window_blocks, resident=resident,
-              double_buffer=double_buffer, chunk=chunk, interpret=interpret)
+    kw = dict(kernel=kernel, block_r=block_r, block_k=block_k, bn=bn,
+              max_blocks=max_blocks, nnb=nnb, chunk=chunk,
+              interpret=interpret)
     if not use_shard_map:
         # serial fallback: the same partition, one launch per shard
         outs = []
@@ -764,9 +658,10 @@ def cluster_spgemm_pairs_sparse(c_slots: jax.Array, slots: jax.Array,
       c_slots: (T,) int32, non-decreasing — destination slab of each pair
         (``CompactedC.table[blk*nnb + j]``). The stream MUST be
         window-major (sorted by (blk, j), s ascending within a window —
-        :func:`repro.kernels.ops.build_sparse_c_pairs`) so each output
-        slab is visited contiguously: Pallas writes an output block back
-        when its index changes, and revisiting it later would clobber.
+        the sparse-C stream of
+        :func:`repro.kernels.ops.pack_spgemm_pattern`) so each output slab
+        is visited contiguously: Pallas writes an output block back when
+        its index changes, and a later second visit would clobber.
         Slot 0 (the reserved zero slab) is visited by one leading
         sentinel pair so it initializes.
       slots: (T,) int32 — B tile slot per pair, 0 = no MXU issue (the

@@ -10,6 +10,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +20,7 @@ from repro.core.formats import (BCC, BCCShape, CompactedC, HostCSR,
                                 TiledCSR, bcc_layout, compacted_c_counters,
                                 compacted_c_from_dense, compacted_c_table,
                                 live_pair_counters, live_pair_stream,
-                                partition_pair_stream, revisit_pair_stream,
-                                revisit_window_blocks, scatter_map,
+                                partition_pair_stream, scatter_map,
                                 tiled_layout)
 from repro.core.segment import rank_in_segment
 from repro.core.transfer import to_device, to_host
@@ -41,11 +41,14 @@ from repro.resilience import faults as _faults
 
 __all__ = ["on_tpu", "pallas_shard_count", "stream_chunk", "bcc_spmm",
            "bcc_compact_stream", "bcc_compact_stream_reference",
-           "bcc_spmm_compact", "build_live_pairs", "build_shard_pack",
-           "build_sparse_c_pairs", "predict_c_window_density",
-           "compact_grid_ok", "compact_grid_ok_ncols", "bcc_spgemm_tiled",
+           "bcc_spmm_compact", "predict_c_window_density",
+           "compact_grid_ok_ncols", "bcc_spgemm_tiled",
            "bcc_spgemm_sparse_c", "SpGEMMPattern", "pack_spgemm_pattern",
            "pack_spmm_stream", "flash_mha", "fused_ssd"]
+
+# the serving path's blocking of the Sp×Sp operands: A's row block and
+# B's tile width
+_BLOCK_R, _BN = 8, 128
 
 # VMEM budget for pinning TiledCSR's tile store on-chip (leave headroom for
 # the A slab / C tile double buffers out of the 16 MiB core budget)
@@ -65,7 +68,7 @@ _SMEM_STREAM_BUDGET = 2**19
 _COMPACT_C_STRIP_BUDGET = 2 * 2**20
 
 # predicted C window density (live (blk, j) windows / all windows) at or
-# below which bcc_spgemm_tiled routes through the sparse-C output tier:
+# below which pack_spgemm_pattern routes through the sparse-C output tier:
 # at 0.5 the compacted slab writes are at most half the dense strips'
 # bytes, so the 2× C-bytes gate holds by construction on routed families
 _SPARSE_C_DENSITY = 0.5
@@ -241,48 +244,29 @@ def bcc_spmm_compact(a: BCC | BCCShape, b: jax.Array, *, bn: int = 128,
     return out[: a.nrows, : n0]
 
 
-def compact_grid_ok_ncols(ncols: int, *, block_r: int = 8,
-                          bn: int = 128) -> bool:
-    """ncols-level form of :func:`compact_grid_ok` at the serving path's
-    default packing — the cost model's pre-packing gate for the per-core
-    shard discount (one source of truth for the strip-budget rule)."""
+def compact_grid_ok_ncols(ncols: int, *, block_r: int = _BLOCK_R,
+                          bn: int = _BN) -> bool:
+    """Whether the live-pair compacted grid applies to a B of ``ncols``
+    columns: its C output window is a whole ``(block_r, nnb*bn)`` row
+    strip, so B wide enough to blow the strip budget takes the padded
+    per-tile grid. :func:`pack_spgemm_pattern` routes on it, and the
+    cost model and the chain planner read it before any packing (one
+    source of truth for the strip-budget rule)."""
     nnb = (max(ncols, 1) + bn - 1) // bn
     return block_r * nnb * bn * 4 <= _COMPACT_C_STRIP_BUDGET
 
 
-def compact_grid_ok(a: BCC, b: TiledCSR) -> bool:
-    """Whether the live-pair compacted grid applies to this operand pair:
-    its C output window is a whole ``(block_r, nnb*bn)`` row strip, so B
-    matrices wide enough to blow the strip budget fall back to the padded
-    per-tile grid. Callers that pre-pack the pair stream (the planner's
-    serving path) gate the build on this — the intersection would be
-    discarded otherwise."""
-    return compact_grid_ok_ncols(b.nnb * b.bn, block_r=a.block_r, bn=b.bn)
-
-
-def build_live_pairs(a: BCC, b: TiledCSR, stream: tuple | None = None
-                     ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
-                                np.ndarray]:
-    """Host-side: intersect A's compact stream with B's tile table into
-    the live-pair compacted grid (the v2 Sp×Sp kernels' input). Packed
-    once per cached operand pair by the planner's serving path.
-
-    Synthetic stream steps — ``cover_all_blocks`` zero slabs of empty
-    blocks and the tail padding — are masked out of the pair expansion
-    (their slabs are all-zero; the pair grid re-covers their blocks with
-    its own zero-slot sentinels).
-    """
-    if stream is None:
-        stream = bcc_compact_stream(a, cover_all_blocks=True)
-    ntiles, table = to_host(a.ntiles, b.table)
-    return _live_pairs(stream, ntiles, table, nnb=b.nnb,
-                       nblocks=(a.nrows + a.block_r - 1) // a.block_r)
-
-
 def _live_pairs(stream, ntiles: np.ndarray, table: np.ndarray, *, nnb: int,
                 nblocks: int) -> tuple:
-    """:func:`build_live_pairs` on host arrays: A's ``ntiles`` and B's
-    tile ``table``."""
+    """Intersect A's compact stream with B's tile ``table`` into the
+    live-pair compacted grid (the pair kernels' input), on the host.
+
+    Synthetic stream steps — the ``cover_all_blocks`` zero slabs of
+    empty blocks (``ntiles`` is A's live tile count per block) and the
+    tail padding — are masked out of the pair expansion (their slabs are
+    all-zero; the pair grid re-covers their blocks with its own
+    zero-slot sentinels).
+    """
     block_ids, tile_ids = np.asarray(stream[0]), np.asarray(stream[1])
     step_live = rank_in_segment(block_ids.astype(np.int64)) \
         < ntiles[block_ids]
@@ -290,61 +274,30 @@ def _live_pairs(stream, ntiles: np.ndarray, table: np.ndarray, *, nnb: int,
                             nblocks=nblocks, step_live=step_live)
 
 
-def build_shard_pack(a: BCC, b: TiledCSR, pairs: tuple, *,
-                     shards: int | None = None,
-                     revisit: bool = False) -> tuple | None:
-    """Host-side: partition the live-pair stream into per-core contiguous
-    block ranges (balanced by live-pair count) and optionally revisit-order
-    each core's sub-stream so B tile fetches dedup across blocks. Packed
-    once per cached operand pair by the planner's serving path.
-
-    Returns ``(ranges, shard_pairs, window_blocks)`` — the input of
-    :func:`repro.kernels.cluster_spgemm.cluster_spgemm_pairs_sharded` —
-    or ``None`` when there is nothing to do (one core, no revisit).
-    """
-    if shards is None:
-        shards = pallas_shard_count()
-    if shards <= 1 and not revisit:
-        return None
-    nblocks = (a.nrows + a.block_r - 1) // a.block_r
-    ranges, shard_pairs = partition_pair_stream(
-        pairs, nblocks=nblocks, num_shards=shards)
-    wb = None
-    if revisit:
-        wb = revisit_window_blocks(b.nnb, block_r=a.block_r, bn=b.bn)
-        shard_pairs = [
-            revisit_pair_stream(p, window_blocks=wb, block_base=int(s))
-            for p, (s, _) in zip(shard_pairs, ranges)]
-    return ranges, shard_pairs, wb
-
-
 def predict_c_window_density(pairs, *, nblocks: int, nnb: int) -> float:
     """Predicted density of C's ``(block_r, bn)`` window lattice: distinct
     live ``(blk, j)`` windows over all ``nblocks × nnb`` windows — known
     *before* the numeric phase from the live-pair stream alone (a window
-    with no live pair is provably zero). This is the output-density
-    threshold :func:`bcc_spgemm_tiled` auto-selects dense-strip vs
-    sparse-C on: the sparse tier's C bytes are exactly ``density`` of the
-    dense strips'."""
+    with no live pair is provably zero). :func:`pack_spgemm_pattern`
+    routes dense-strip vs sparse-C on it: the sparse tier's C bytes are
+    exactly ``density`` of the dense strips'."""
     blocks, js, slots, _ = (np.asarray(p) for p in pairs)
     live = slots > 0
     key = blocks[live].astype(np.int64) * nnb + js[live].astype(np.int64)
     return np.unique(key).size / max(nblocks * nnb, 1)
 
 
-def build_sparse_c_pairs(a: BCC, b: TiledCSR, pairs: tuple | None = None,
-                         stream: tuple | None = None, *, pad_to: int = 8
-                         ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
-                                    np.ndarray, int]:
-    """Host-side: re-sort the live-pair stream window-major for the
-    sparse-C kernels and tag each pair with its destination
-    :class:`repro.core.formats.CompactedC` slab.
+def _sparse_c_pairs(pairs, *, nblocks: int, nnb: int, pad_to: int = 8
+                    ) -> tuple:
+    """Re-sort the live-pair stream window-major for the sparse-C kernels
+    and tag each pair with its destination
+    :class:`repro.core.formats.CompactedC` slab, on the host.
 
     The dense kernels need (block, s, j) order — one C *strip* per block,
     visited once. The sparse-C kernels' output block is one ``(blk, j)``
     *window*, so the stream re-sorts by (blk, j, s): every slab is
     visited contiguously (Pallas writes an output block back when its
-    index changes; revisiting would clobber), and within a window pairs
+    index changes; a second visit would clobber), and within a window pairs
     stay s-ascending — the same per-element fp32 accumulation order as
     the dense kernels, hence bit-identical values.
 
@@ -359,17 +312,6 @@ def build_sparse_c_pairs(a: BCC, b: TiledCSR, pairs: tuple | None = None,
     CompactedC lookup table and slab count (live windows + the zero
     slab).
     """
-    if stream is None:
-        stream = bcc_compact_stream(a, cover_all_blocks=True)
-    if pairs is None:
-        pairs = build_live_pairs(a, b, stream)
-    return _sparse_c_pairs(pairs, nblocks=(a.nrows + a.block_r - 1)
-                           // a.block_r, nnb=b.nnb, pad_to=pad_to)
-
-
-def _sparse_c_pairs(pairs, *, nblocks: int, nnb: int, pad_to: int = 8
-                    ) -> tuple:
-    """:func:`build_sparse_c_pairs` of a packed live-pair stream."""
     table, nlive = compacted_c_table(pairs, nblocks=nblocks, nnb=nnb)
     blocks, js, slots, a_idx = (np.asarray(p) for p in pairs)
     live = slots > 0
@@ -393,217 +335,105 @@ def _sparse_c_pairs(pairs, *, nblocks: int, nnb: int, pad_to: int = 8
             al.astype(np.int32), table, nlive + 1)
 
 
-def bcc_spgemm_sparse_c(a: BCC, b: TiledCSR, *,
-                        interpret: bool | None = None,
-                        stream: tuple | None = None,
-                        pairs: tuple | None = None,
-                        sparse_pairs: tuple | None = None,
-                        double_buffer: bool | None = None,
-                        epilogue: str | None = None) -> CompactedC:
-    """C = A_bcc @ B_tiled into the sparse-C output tier: the numeric
-    phase accumulates each live C window in VMEM exactly like the
-    dense-strip kernels but writes back *only* the live windows as
-    packed :class:`repro.core.formats.CompactedC` slabs — C bytes to HBM
-    scale with nnz(C)'s window footprint, not ``rows × nnb·bn``.
-
-    ``epilogue`` selects where the compaction happens:
-      * ``"kernel"`` — the windowed-scatter epilogue runs inside the
-        Pallas kernel (its output BlockSpec scatters straight into the
-        slab store). Default on TPU; also interpret-capable, which is
-        what the bit-identity tests exercise.
-      * ``"xla"`` — dense-strip product first, then an XLA
-        segment-compaction gather of the live windows
-        (:func:`repro.core.formats.compacted_c_from_dense`). Default
-        off-TPU; same table, bit-identical slabs.
-
-    ``sparse_pairs`` overrides the packed window-major stream
-    (:func:`build_sparse_c_pairs` — cached per operand pair by the
-    planner's chain workload).
-    """
-    _faults.maybe_fault("kernel_launch")
-    if interpret is None:
-        interpret = not on_tpu()
-    if a.block_k != b.block_k:
-        raise ValueError(f"A block_k {a.block_k} != B block_k {b.block_k}")
-    if stream is None:
-        stream = bcc_compact_stream(a, cover_all_blocks=True)
-    if sparse_pairs is None:
-        sparse_pairs = build_sparse_c_pairs(a, b, pairs, stream)
-    c_slots, slots, a_idx, table, nslabs = sparse_pairs
-    if epilogue is None:
-        epilogue = "kernel" if on_tpu() else "xla"
-    if epilogue == "xla":
-        dense = bcc_spgemm_tiled(a, b, interpret=interpret, stream=stream,
-                                 pairs=pairs, sparse_c=False)
-        return compacted_c_from_dense(dense, table, nrows=a.nrows,
-                                      ncols=b.ncols, block_r=a.block_r,
-                                      bn=b.bn)
-    if epilogue != "kernel":
-        raise ValueError(f"unknown epilogue '{epilogue}'")
+def _sparse_c_kernel(pattern: "SpGEMMPattern", values: jax.Array,
+                     tiled: TiledCSR) -> CompactedC:
+    """The sparse-C product with the kernel epilogue: the pattern's
+    sparse-C kernel accumulates each live C window in VMEM and its
+    output BlockSpec scatters the window straight into the slab store."""
+    a = pattern.a
+    c_slots, slots, a_idx, table, nslabs = pattern.sparse_pairs
     values, c_slots, slots, a_idx, table = to_device(
-        stream[2], c_slots, slots, a_idx, table)
-    db = double_buffer if double_buffer is not None else on_tpu()
-    kernel = (cluster_spgemm_pairs_sparse_db if db
-              else cluster_spgemm_pairs_sparse)
+        values, c_slots, slots, a_idx, table)
     with get_tracer().span("kernel_variant", variant="sparse_c",
                            epilogue="kernel"):
-        slabs = kernel(c_slots, slots, a_idx, values, b.tiles,
-                       block_r=a.block_r, block_k=a.block_k, bn=b.bn,
-                       nslabs=int(nslabs), chunk=stream_chunk(3),
-                       interpret=interpret)
+        slabs = pattern.kernel(c_slots, slots, a_idx, values, tiled.tiles,
+                               block_r=a.block_r, block_k=a.block_k,
+                               bn=tiled.bn, nslabs=int(nslabs),
+                               chunk=stream_chunk(3), interpret=not on_tpu())
     out = CompactedC(slabs=slabs, table=table,
-                     nrows=a.nrows, ncols=b.ncols,
-                     block_r=a.block_r, bn=b.bn)
+                     nrows=a.nrows, ncols=tiled.ncols,
+                     block_r=a.block_r, bn=tiled.bn)
     _note_kernel_launch("sparse_c", cc=out)
     return out
 
 
-def bcc_spgemm_tiled(a: BCC, b: TiledCSR, *,
-                     interpret: bool | None = None,
-                     stream: tuple | None = None,
-                     pairs: tuple | None = None,
-                     resident: bool | None = None,
-                     compact: bool | None = None,
-                     double_buffer: bool | None = None,
-                     shards: int | None = None,
-                     revisit: bool = False,
-                     shard_pack: tuple | None = None,
-                     sparse_c: bool | None = None,
-                     sparse_pairs: tuple | None = None) -> jax.Array:
-    """C = A_bcc @ B_tiled via the Pallas Sp×Sp kernel tier. Returns the
-    dense ``(a.nrows, b.ncols)`` product (fp32 — bf16 B tiles are upcast
-    at the MXU input, accumulation stays fp32).
+def _sparse_c_xla(pattern: "SpGEMMPattern", values: jax.Array,
+                  tiled: TiledCSR) -> CompactedC:
+    """The sparse-C product with the XLA epilogue: the dense strips of
+    the pattern's live pairs on the streamed compacted grid (through
+    :func:`bcc_spgemm_tiled`), then an XLA segment-compaction gather of
+    the live windows (:func:`repro.core.formats.compacted_c_from_dense`)
+    — the same table, bit-identical slabs."""
+    a = pattern.a
+    dense = bcc_spgemm_tiled(
+        dataclasses.replace(pattern, route="streamed",
+                            kernel=cluster_spgemm_pairs), values, tiled)
+    return compacted_c_from_dense(dense, pattern.sparse_pairs[3],
+                                  nrows=a.nrows, ncols=tiled.ncols,
+                                  block_r=a.block_r, bn=tiled.bn)
 
-    Variant selection:
-      * ``compact`` — run the live-pair compacted grid (v2, default) vs
-        the PR-3 padded ``(nnb, S)`` grid. Auto-falls back to the padded
-        grid when the C row-strip window would exceed its VMEM budget.
-      * ``resident`` pins B's tile store in VMEM (one HBM fetch for all
-        of B); default: auto — resident when the store fits
-        ``_RESIDENT_B_BUDGET``.
-      * ``double_buffer`` — for the compact *streamed* path, prefetch the
-        next B tile into a two-slot scratch while the current one
-        contracts. Default: on for compiled TPU runs, off in interpret
-        mode (correct there too, just slower to simulate).
-      * ``stream`` / ``pairs`` override the packed A compact stream and
-        the live-pair grid (packed once per operand by callers that
-        reuse the plan).
-      * ``shards`` — fan the compacted grid out over this many cores
-        (contiguous block ranges balanced by live-pair count, disjoint C
-        row strips, no cross-core accumulation). Default: auto —
-        ``pallas_shard_count()``, i.e. every TPU core and 1 off-TPU
-        (where the identical partition runs serially).
-      * ``revisit`` — B-fetch-deduping revisit order: each core's
-        sub-stream is resorted (j, slot, block) within VMEM-budget
-        windows so the streamed-B DMA elision fetches each live tile
-        once per window instead of once per touching block. Bit-identical
-        output; counter-visible in ``live_pair_counters`` /
-        ``bench_kernels``. Off by default (the resident variants already
-        fetch B once; the win is for streamed, HBM-resident B).
-      * ``shard_pack`` overrides the packed partition
-        (:func:`build_shard_pack`, cached by the planner's serving path).
-      * ``sparse_c`` — route the unsharded compact path through the
-        sparse-C output tier (:func:`bcc_spgemm_sparse_c`) and densify
-        the :class:`repro.core.formats.CompactedC` result on the way out
-        (bit-identical values; C HBM writes scale with the live-window
-        count). Default: auto — sparse when the predicted C window
-        density (:func:`predict_c_window_density`) is at most
-        ``_SPARSE_C_DENSITY`` and the product is not sharded; callers
-        that want the compacted format itself call
-        :func:`bcc_spgemm_sparse_c` directly.
-      * ``sparse_pairs`` overrides the sparse-C route's packed
-        window-major stream (:func:`build_sparse_c_pairs`).
 
-    ``a`` may be the :class:`repro.core.formats.BCCShape` of the BCC
-    when ``stream`` holds its compact stream and ``pairs`` (or the
-    padded grid) is decided: nothing else of A is read then.
+def bcc_spgemm_sparse_c(pattern: "SpGEMMPattern", values: jax.Array,
+                        tiled: TiledCSR) -> CompactedC:
+    """C = A @ B on one value set of a pattern packed for the sparse-C
+    output tier (route ``sparse_c``): the numeric phase accumulates each
+    live C window in VMEM like the dense-strip kernels but writes back
+    *only* the live windows, as packed
+    :class:`repro.core.formats.CompactedC` slabs — C bytes to HBM scale
+    with nnz(C)'s window footprint, not ``rows × nnb·bn``.
+
+    The compaction runs in the kernel on a TPU and as an XLA gather of
+    the dense strips elsewhere (the same table, bit-identical slabs).
     """
     _faults.maybe_fault("kernel_launch")
-    if interpret is None:
-        interpret = not on_tpu()
-    if a.block_k != b.block_k:
-        raise ValueError(f"A block_k {a.block_k} != B block_k {b.block_k}")
-    nkb_needed = (a.ncols + a.block_k - 1) // a.block_k
-    if b.nkb < nkb_needed:
-        raise ValueError(f"B covers {b.nkb} k-blocks, A addresses "
-                         f"{nkb_needed}")
-    if stream is None:
-        stream = bcc_compact_stream(a, cover_all_blocks=True)
-    if compact is None:
-        # an explicitly pre-packed pair stream means the caller already
-        # decided (and paid) for the compacted grid — honor it
-        compact = True if pairs is not None else compact_grid_ok(a, b)
-    if resident is None:
-        resident = b.nbytes_tiles() <= _RESIDENT_B_BUDGET
-    nblocks = (a.nrows + a.block_r - 1) // a.block_r
-    if compact:
-        if pairs is None:
-            pairs = build_live_pairs(a, b, stream)
-        if shard_pack is None:
-            shard_pack = build_shard_pack(a, b, pairs, shards=shards,
-                                          revisit=revisit)
-        if sparse_c is None:
-            sparse_c = (shard_pack is None
-                        and predict_c_window_density(
-                            pairs, nblocks=nblocks, nnb=b.nnb)
-                        <= _SPARSE_C_DENSITY)
-        if sparse_c and shard_pack is None:
-            cc = bcc_spgemm_sparse_c(
-                a, b, interpret=interpret, stream=stream, pairs=pairs,
-                sparse_pairs=sparse_pairs, double_buffer=double_buffer,
-                epilogue="kernel")
-            return cc.to_dense()
-        if shard_pack is not None:
-            ranges, shard_pairs, wb = shard_pack
-            variant = "sharded_revisit" if wb is not None else "sharded"
-            values, = to_device(stream[2])
-            with get_tracer().span("kernel_variant", variant=variant,
-                                   shards=len(shard_pairs)):
-                out = cluster_spgemm_pairs_sharded(
-                    shard_pairs, ranges, values, b.tiles,
-                    block_r=a.block_r, block_k=a.block_k, bn=b.bn,
-                    nblocks=nblocks, nnb=b.nnb, window_blocks=wb,
-                    resident=bool(resident) and wb is None,
-                    double_buffer=(double_buffer
-                                   if double_buffer is not None
-                                   else on_tpu()),
-                    chunk=stream_chunk(4 if wb is None else 5),
-                    interpret=interpret)
-            _note_kernel_launch(variant, pairs=pairs, block_r=a.block_r,
-                                block_k=a.block_k, bn=b.bn)
-            return out[: a.nrows, : b.ncols]
-        values, blocks, js, slots, a_idx = to_device(stream[2], *pairs)
-        if resident:
-            kernel, variant = cluster_spgemm_pairs_resident, "resident"
-        elif double_buffer if double_buffer is not None else on_tpu():
-            kernel, variant = cluster_spgemm_pairs_db, "streamed_db"
-        else:
-            kernel, variant = cluster_spgemm_pairs, "streamed"
-        with get_tracer().span("kernel_variant", variant=variant):
-            out = kernel(blocks, js, slots, a_idx, values, b.tiles,
-                         block_r=a.block_r, block_k=a.block_k, bn=b.bn,
-                         nblocks=nblocks, nnb=b.nnb, chunk=stream_chunk(4),
-                         interpret=interpret)
-        _note_kernel_launch(variant, pairs=pairs, block_r=a.block_r,
-                            block_k=a.block_k, bn=b.bn)
-        return out[: a.nrows, : b.ncols]
-    # the padded grid prefetches its whole stream and B's whole table
-    prefetch_bytes = 4 * (2 * len(stream[0]) + b.nkb * b.nnb)
-    if prefetch_bytes > _SMEM_STREAM_BUDGET:
-        raise ValueError(
-            f"padded Sp×Sp grid needs {prefetch_bytes} B of SMEM for its "
-            f"stream and B's tile table, over the {_SMEM_STREAM_BUDGET} B "
-            f"budget (C row strip of {b.nnb * b.bn} columns is too wide "
-            "for the compacted grid)")
-    block_ids, tile_ids, values = to_device(*stream)
-    kernel = cluster_spgemm_resident if resident else cluster_spgemm_tiled
-    with get_tracer().span("kernel_variant", variant="padded",
-                           resident=bool(resident)):
-        out = kernel(block_ids, tile_ids, b.table, values, b.tiles,
-                     block_r=a.block_r, block_k=a.block_k, bn=b.bn,
-                     nblocks=nblocks, nnb=b.nnb, interpret=interpret)
-    _note_kernel_launch("padded")
-    return out[: a.nrows, : b.ncols]
+    if on_tpu():
+        return _sparse_c_kernel(pattern, values, tiled)
+    return _sparse_c_xla(pattern, values, tiled)
+
+
+def bcc_spgemm_tiled(pattern: "SpGEMMPattern", values: jax.Array,
+                     tiled: TiledCSR) -> jax.Array:
+    """C = A @ B on one value set of a packed pattern (``values`` and
+    ``tiled`` from :meth:`SpGEMMPattern.fill`), launched on the route
+    :func:`pack_spgemm_pattern` recorded. Returns the dense ``(nrows,
+    ncols)`` product (fp32 — bf16 B tiles are upcast at the MXU input,
+    accumulation stays fp32); the ``sparse_c`` route densifies its
+    :class:`repro.core.formats.CompactedC` on the way out."""
+    _faults.maybe_fault("kernel_launch")
+    p, a = pattern, pattern.a
+    if p.route == "sparse_c":
+        return _sparse_c_kernel(p, values, tiled).to_dense()
+    tracer = get_tracer()
+    kw = dict(block_r=a.block_r, block_k=a.block_k, bn=tiled.bn,
+              nblocks=(a.nrows + a.block_r - 1) // a.block_r,
+              nnb=tiled.nnb, interpret=not on_tpu())
+    pair_kw = dict(pairs=p.pairs, block_r=a.block_r, block_k=a.block_k,
+                   bn=tiled.bn)
+    # the pattern and the fill hold every operand on the device already:
+    # each to_device below moves nothing, its upload span reads 0 bytes
+    if p.route == "padded":
+        block_ids, tile_ids, values = to_device(*p.stream_ids, values)
+        with tracer.span("kernel_variant", variant="padded",
+                         resident=p.kernel is cluster_spgemm_resident):
+            out = p.kernel(block_ids, tile_ids, tiled.table, values,
+                           tiled.tiles, **kw)
+        _note_kernel_launch("padded")
+    elif p.route == "sharded":
+        ranges, shard_pairs = p.shards
+        values, = to_device(values)
+        with tracer.span("kernel_variant", variant="sharded",
+                         shards=len(shard_pairs)):
+            out = cluster_spgemm_pairs_sharded(
+                shard_pairs, ranges, values, tiled.tiles, kernel=p.kernel,
+                chunk=stream_chunk(4), **kw)
+        _note_kernel_launch("sharded", **pair_kw)
+    else:
+        values, *pairs = to_device(values, *p.pairs)
+        with tracer.span("kernel_variant", variant=p.route):
+            out = p.kernel(*pairs, values, tiled.tiles,
+                           chunk=stream_chunk(4), **kw)
+        _note_kernel_launch(p.route, **pair_kw)
+    return out[: a.nrows, : tiled.ncols]
 
 
 def _compact_layout(h: HostCSR, block_r: int, block_k: int) -> tuple:
@@ -640,30 +470,42 @@ def pack_spmm_stream(h: HostCSR, *, block_r: int = 8, block_k: int = 128
 
 @dataclasses.dataclass(frozen=True)
 class SpGEMMPattern:
-    """The Sp×Sp operands packed from their patterns alone, on the device.
+    """The Sp×Sp operands packed from their patterns alone, on the device,
+    and the route that launches them.
 
-    Everything :func:`bcc_spgemm_tiled` reads except two value arrays is
-    a function of the patterns: A's blocking and compact stream ids, the
-    live pairs, B's tile table, the shard partition, the sparse-C
-    decision and its window-major stream. The two value arrays, A's
-    stream slabs and B's tile store, are zeros with the operands' values
-    at fixed positions; ``a_map``/``b_map`` (``(src, dst)`` of
+    Everything a launch reads except two value arrays is a function of
+    the patterns: A's blocking and compact stream ids, B's tile table,
+    the live pairs, the shard partition, the sparse-C window-major stream
+    and the route itself. The two value arrays, A's stream slabs and B's
+    tile store, are zeros with the operands' values at fixed positions;
+    ``a_map``/``b_map`` (``(src, dst)`` of
     :func:`repro.core.formats.scatter_map`) hold those positions, with
     ``src`` indexing the ``data`` of the operands as they were sent
     (before the plan's permutation). :meth:`fill` builds both arrays from
     a value set on the device, and :meth:`run` launches the kernel on
     them: the same slabs and tiles, in the same stream order, as a full
     :func:`bcc_from_host`/:func:`tiled_csr_from_host` pack.
+
+    ``route`` names the launch, as the ``kernel_variant`` span and the
+    ``kernel_launches`` counter name it: ``padded`` (the per-tile
+    ``(nnb, S)`` grid), ``resident``, ``streamed`` or ``streamed_db``
+    (the live-pair grid with B pinned in VMEM, streamed, or streamed
+    behind a two-slot prefetch), ``sharded`` (the live-pair grid split
+    over the cores) or ``sparse_c`` (the window-major sparse-C grid).
+    ``kernel`` is the Pallas kernel the route launches (each core's, for
+    ``sharded``).
     """
 
     a: BCCShape
     b_shape: tuple                 # (nrows, ncols, block_k, bn) of B
     table: jax.Array               # B's tile table
     stream_ids: tuple              # (block_ids, tile_ids)
-    pairs: tuple | None            # None: the padded grid runs
-    shard_pack: tuple | None
-    sparse_c: bool | None
-    sparse_pairs: tuple | None
+    route: str
+    kernel: Callable
+    pairs: tuple | None            # the live pairs; None on padded
+    shards: tuple | None           # (ranges, per-core pairs) on sharded
+    sparse_pairs: tuple | None     # (c_slots, slots, a_idx, table,
+                                   # nslabs) on sparse_c
     a_map: tuple
     b_map: tuple
     values_shape: tuple
@@ -688,21 +530,16 @@ class SpGEMMPattern:
                                 ncols=ncols, block_k=block_k, bn=bn)
 
     def run(self, values: jax.Array, tiled: TiledCSR) -> jax.Array:
-        """C = A @ B on one value set that :meth:`fill` built."""
-        return bcc_spgemm_tiled(
-            self.a, tiled, stream=(*self.stream_ids, values),
-            pairs=self.pairs, shard_pack=self.shard_pack,
-            sparse_c=self.sparse_c, sparse_pairs=self.sparse_pairs)
+        """C = A @ B, dense, on one value set that :meth:`fill` built."""
+        return bcc_spgemm_tiled(self, values, tiled)
 
     def run_sparse(self, values: jax.Array, tiled: TiledCSR) -> CompactedC:
         """C = A @ B on one value set, left in the sparse-C output tier
         (:func:`bcc_spgemm_sparse_c`); the pattern must have been packed
         with ``sparse_out=True``."""
-        if self.sparse_pairs is None:
+        if self.route != "sparse_c":
             raise ValueError("pattern packed without its sparse-C stream")
-        return bcc_spgemm_sparse_c(
-            self.a, tiled, stream=(*self.stream_ids, values),
-            pairs=self.pairs, sparse_pairs=self.sparse_pairs)
+        return bcc_spgemm_sparse_c(self, values, tiled)
 
 
 @functools.partial(jax.jit, static_argnames=("values_shape", "tiles_shape",
@@ -735,7 +572,8 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
                         b_src: np.ndarray | None = None,
                         b_dtype=jnp.float32,
                         sparse_out: bool = False) -> SpGEMMPattern:
-    """Pack ``ap @ bh`` for the Sp×Sp kernel from the patterns alone.
+    """Pack ``ap @ bh`` for the Sp×Sp kernels from the patterns alone, and
+    choose the route that launches it.
 
     ``a_src``/``b_src`` map each nonzero of ``ap``/``bh`` to the nonzero
     of the operand as sent (``HostCSR.permuted``'s ``src``; ``None``: the
@@ -743,51 +581,80 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
     Nothing is read back from the device: the stream ids, the live pairs
     and the shard partition come from the host layouts
     (:func:`bcc_layout`, :func:`tiled_layout`), at the serving path's
-    blocking: ``block_r`` 8 and ``bn`` 128, the packers' defaults.
+    blocking: ``block_r`` 8 and ``bn`` 128.
+
+    The route (:class:`SpGEMMPattern`) is chosen here, once, from what
+    the patterns and the platform show:
+
+    * B's width against the C row-strip budget
+      (:func:`compact_grid_ok_ncols`): the live-pair grid, or the padded
+      grid when B is too wide — refused when its stream and B's tile
+      table overflow the SMEM budget;
+    * B's tile store against ``_RESIDENT_B_BUDGET``: pinned in VMEM, or
+      streamed — behind the two-slot prefetch on a TPU;
+    * :func:`pallas_shard_count`: sharded over the cores;
+    * C's predicted window density (:func:`predict_c_window_density`)
+      against ``_SPARSE_C_DENSITY``, unsharded: the sparse-C grid.
 
     ``sparse_out=True`` packs for :meth:`SpGEMMPattern.run_sparse`: the
-    window-major sparse-C stream is built whatever C's predicted density,
-    and the product is not sharded. B must then be narrow enough for the
-    compacted grid (:func:`compact_grid_ok_ncols`).
+    sparse-C route whatever C's predicted density, unsharded. B must then
+    be narrow enough for the compacted grid.
     """
-    block_r, bn = 8, 128
+    block_r, bn = _BLOCK_R, _BN
     stream_ids, ntiles, a_pos, values_shape = _compact_layout(
         ap, block_r, block_k)
     table, tile_cap, b_pos = tiled_layout(bh, block_k, bn)
     tiles_shape = (tile_cap, block_k, bn)
     a_map = _value_map(a_pos, a_src, math.prod(values_shape))
     b_map = _value_map(b_pos, b_src, math.prod(tiles_shape))
-    a = BCCShape(ap.nrows, ap.ncols, block_r, block_k)
     nblocks = ntiles.shape[0]
     nnb = (bh.ncols + bn - 1) // bn
-    pairs = shard_pack = sparse_c = sparse_pairs = None
-    compact = compact_grid_ok_ncols(nnb * bn, block_r=block_r, bn=bn)
-    if sparse_out and not compact:
-        raise ValueError(f"B of {bh.ncols} columns is too wide for the "
-                         "sparse-C output tier")
-    if compact:
-        pairs = _live_pairs(stream_ids, ntiles, table, nnb=nnb,
-                            nblocks=nblocks)
-        shards = 1 if sparse_out else pallas_shard_count()
-        if shards > 1:
+    double_buffer = on_tpu()
+    resident = (math.prod(tiles_shape) * jnp.dtype(b_dtype).itemsize
+                <= _RESIDENT_B_BUDGET)
+    pairs = shards = sparse_pairs = None
+    if not compact_grid_ok_ncols(nnb * bn, block_r=block_r, bn=bn):
+        if sparse_out:
+            raise ValueError(f"B of {bh.ncols} columns is too wide for the "
+                             "sparse-C output tier")
+        # the padded grid prefetches its whole stream and B's whole table
+        prefetch_bytes = 4 * (2 * len(stream_ids[0]) + table.size)
+        if prefetch_bytes > _SMEM_STREAM_BUDGET:
+            raise ValueError(
+                f"padded Sp×Sp grid needs {prefetch_bytes} B of SMEM for "
+                f"its stream and B's tile table, over the "
+                f"{_SMEM_STREAM_BUDGET} B budget (C row strip of "
+                f"{nnb * bn} columns is too wide for the compacted grid)")
+        route = "padded"
+        kernel = cluster_spgemm_resident if resident else cluster_spgemm_tiled
+    else:
+        host_pairs = _live_pairs(stream_ids, ntiles, table, nnb=nnb,
+                                 nblocks=nblocks)
+        route, kernel = (
+            ("resident", cluster_spgemm_pairs_resident) if resident
+            else ("streamed_db", cluster_spgemm_pairs_db) if double_buffer
+            else ("streamed", cluster_spgemm_pairs))
+        n_shards = 1 if sparse_out else pallas_shard_count()
+        if n_shards > 1:
             ranges, shard_pairs = partition_pair_stream(
-                pairs, nblocks=nblocks, num_shards=shards)
-            shard_pack = (ranges, [to_device(*p) for p in shard_pairs],
-                          None)
-        sparse_c = sparse_out or (shard_pack is None and
-                                  predict_c_window_density(
-                                      pairs, nblocks=nblocks, nnb=nnb)
-                                  <= _SPARSE_C_DENSITY)
-        if sparse_c:
-            *streams, nslabs = _sparse_c_pairs(pairs, nblocks=nblocks,
+                host_pairs, nblocks=nblocks, num_shards=n_shards)
+            route = "sharded"
+            shards = (ranges, [to_device(*p) for p in shard_pairs])
+        elif sparse_out or predict_c_window_density(
+                host_pairs, nblocks=nblocks, nnb=nnb) <= _SPARSE_C_DENSITY:
+            route = "sparse_c"
+            kernel = (cluster_spgemm_pairs_sparse_db if double_buffer
+                      else cluster_spgemm_pairs_sparse)
+            *streams, nslabs = _sparse_c_pairs(host_pairs, nblocks=nblocks,
                                                nnb=nnb)
             sparse_pairs = (*to_device(*streams), nslabs)
-        pairs = to_device(*pairs)
+        pairs = to_device(*host_pairs)
     table, *ids = to_device(table, *stream_ids)
     return SpGEMMPattern(
-        a=a, b_shape=(bh.nrows, bh.ncols, block_k, bn), table=table,
-        stream_ids=tuple(ids), pairs=pairs, shard_pack=shard_pack,
-        sparse_c=sparse_c, sparse_pairs=sparse_pairs,
+        a=BCCShape(ap.nrows, ap.ncols, block_r, block_k),
+        b_shape=(bh.nrows, bh.ncols, block_k, bn), table=table,
+        stream_ids=tuple(ids), route=route, kernel=kernel, pairs=pairs,
+        shards=shards, sparse_pairs=sparse_pairs,
         a_map=to_device(*a_map), b_map=to_device(*b_map),
         values_shape=values_shape, tiles_shape=tiles_shape,
         tiles_dtype=jnp.dtype(b_dtype))

@@ -94,10 +94,9 @@ def cluster_spgemm_pairs_ref(blocks, js, slots, a_idx, a_values, b_tiles,
 def cluster_spgemm_pairs_sharded_ref(shard_pairs, block_ranges, a_values,
                                      b_tiles, *, block_r, block_k, bn,
                                      nblocks, nnb):
-    """Oracle for the sharded (and revisit-ordered) pair kernels: walk
-    every shard's sub-stream into the global C — the pair order within a
-    shard is irrelevant to the oracle (strips are disjoint and += is the
-    same per-element sequence), so one oracle covers both orderings."""
+    """Oracle for the sharded pair kernel: walk every shard's sub-stream
+    into the global C, checking that each pair lies in its shard's block
+    range (strips are disjoint, so the shards' order is irrelevant)."""
     a_values = np.asarray(a_values, dtype=np.float32)
     b_tiles = np.asarray(b_tiles, dtype=np.float32)
     c = np.zeros((nblocks * block_r, nnb * bn), dtype=np.float32)

@@ -7,7 +7,7 @@ fuses one (batch·head, chunk) step entirely in VMEM:
 
   grid = (B·H, n_chunks); the inter-chunk state recurrence rides in a VMEM
   scratch accumulator that persists across the (serial) chunk dimension —
-  the same revisiting idiom as the cluster kernel's output accumulation.
+  the same resident-output idiom as the cluster kernel's output accumulation.
 
 Per grid step, entirely in VMEM:
     L       = exp(segsum(a))            (Q, Q) lower-tri

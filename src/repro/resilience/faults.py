@@ -10,11 +10,12 @@ four injection sites the serving path threads hooks through:
   :class:`~repro.resilience.errors.FaultInjectedError`, modeling a
   malformed packed format or host OOM. Exercised in
   ``planner/service.py``'s pack paths.
-* ``kernel_launch`` — the kernel wrapper raises, modeling a pallas
+* ``kernel_launch`` — the Sp×Sp launch raises, modeling a pallas
   compile failure or VMEM budget violation (the memory-pressure failure
   mode of Nagasaka's memory-saving SpGEMM work, arxiv 1804.01698).
-  Exercised at the top of ``kernels/ops.py::bcc_spgemm_tiled`` /
-  ``bcc_spgemm_sparse_c``.
+  Exercised at the top of the launchers of a packed pattern,
+  ``kernels/ops.py::bcc_spgemm_tiled`` and ``bcc_spgemm_sparse_c``
+  (``SpGEMMPattern.run`` and ``run_sparse``).
 * ``output`` — a NaN is poked into the produced array
   (:func:`corrupt_output`), modeling the non-finite blowup of the
   bf16-B path. Exercised in ``planner/service.py::Planner.execute``

@@ -18,14 +18,15 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.formats import partition_pair_stream, revisit_window_blocks
+from repro.core.formats import partition_pair_stream
 from repro.kernels import ops
 from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs,
                                           cluster_spgemm_pairs_db,
                                           cluster_spgemm_pairs_resident,
                                           cluster_spgemm_pairs_sharded,
                                           cluster_spgemm_pairs_sparse_db,
-                                          cluster_spgemm_pairs_window)
+                                          cluster_spgemm_resident,
+                                          cluster_spgemm_tiled)
 from repro.kernels.cluster_spmm import cluster_spmm_compact
 
 pytestmark = pytest.mark.pallas
@@ -100,19 +101,34 @@ def test_dense_pair_kernels_compile(one_chip, kernel, block_k, dtype):
     _compile(fn, *_streams(4, 4, one_chip), a, b)
 
 
+# the padded grid at a B too wide for the compacted grid's C row strip:
+# 1,024 column tiles (131,072 columns), 64 k-blocks and a stream of
+# 16,384 steps, so the stream and B's table fill 384 KiB of the SMEM
+# budget
+PAD_NNB, PAD_NKB, PAD_S = 1024, 64, 16384
+
+
 @BK
 @DT
-def test_window_kernel_compiles(one_chip, block_k, dtype):
-    wb = revisit_window_blocks(NNB, block_r=BLOCK_R, bn=BN)
-    a = _sds((N_SLABS, BLOCK_R, block_k), jnp.float32, one_chip)
-    b = _sds((TILE_CAP, block_k, BN), dtype, one_chip)
+@pytest.mark.parametrize("kernel", [cluster_spgemm_tiled,
+                                    cluster_spgemm_resident])
+def test_padded_grid_compiles(one_chip, kernel, block_k, dtype):
+    assert not ops.compact_grid_ok_ncols(PAD_NNB * BN)
+    assert 4 * (2 * PAD_S + PAD_NKB * PAD_NNB) <= ops._SMEM_STREAM_BUDGET
+    if kernel is cluster_spgemm_resident:         # B at the VMEM budget
+        cap = ops._RESIDENT_B_BUDGET // (block_k * BN
+                                         * jnp.dtype(dtype).itemsize)
+    else:
+        cap = TILE_CAP
+    ids = _sds((PAD_S,), jnp.int32, one_chip)
+    table = _sds((PAD_NKB * PAD_NNB,), jnp.int32, one_chip)
+    a = _sds((PAD_S, BLOCK_R, block_k), jnp.float32, one_chip)
+    b = _sds((cap, block_k, BN), dtype, one_chip)
 
-    def fn(wins, blocks, js, slots, a_idx, a, b):
-        return cluster_spgemm_pairs_window(
-            wins, blocks, js, slots, a_idx, a, b, block_r=BLOCK_R,
-            block_k=block_k, bn=BN, nblocks=NBLOCKS, nnb=NNB,
-            window_blocks=wb, chunk=ops.stream_chunk(5))
-    _compile(fn, *_streams(5, 5, one_chip), a, b)
+    def fn(block_ids, tile_ids, table, a, b):
+        return kernel(block_ids, tile_ids, table, a, b, block_r=BLOCK_R,
+                      block_k=block_k, bn=BN, nblocks=NBLOCKS, nnb=PAD_NNB)
+    _compile(fn, ids, ids, table, a, b)
 
 
 @BK
@@ -164,7 +180,7 @@ def test_sharded_kernel_compiles_on_four_chips(topo, monkeypatch):
     def fn(a, b):
         return cluster_spgemm_pairs_sharded(
             shard_pairs, ranges, a, b, block_r=BLOCK_R, block_k=128, bn=BN,
-            nblocks=NBLOCKS, nnb=NNB, double_buffer=True,
+            nblocks=NBLOCKS, nnb=NNB, kernel=cluster_spgemm_pairs_db,
             chunk=ops.stream_chunk(4), use_shard_map=True)
     compiled = _compile(fn, a, b)
     assert "tpu_custom_call" in compiled.as_text()

@@ -6,8 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.formats import (HostCSR, bcc_from_host, revisit_pair_stream,
-                                tiled_csr_from_host)
+from repro.core.formats import HostCSR, bcc_from_host
 from repro.core.spgemm import spgemm_reference
 from repro.kernels import ops
 from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs,
@@ -15,10 +14,11 @@ from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs,
                                           cluster_spgemm_pairs_resident,
                                           cluster_spgemm_pairs_sharded,
                                           cluster_spgemm_pairs_sparse,
-                                          cluster_spgemm_pairs_sparse_db,
-                                          cluster_spgemm_pairs_window)
+                                          cluster_spgemm_pairs_sparse_db)
 from repro.kernels.cluster_spmm import cluster_spmm_compact
 from repro.core.formats import partition_pair_stream
+
+from _packing import pack, product
 
 pytestmark = pytest.mark.pallas
 
@@ -34,12 +34,12 @@ def rand_host(n, m, density, seed):
 
 @pytest.fixture(scope="module")
 def packed():
+    """A², its sparse-C pattern, the pattern's value set, live pairs and
+    window-major sparse-C stream."""
     a = rand_host(72, 72, 0.1, 7)       # 9 row blocks of ragged length
-    bcc = bcc_from_host(a, block_r=8, block_k=16)
-    tiled = tiled_csr_from_host(a, block_k=16, bn=16)
-    stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
-    pairs = ops.build_live_pairs(bcc, tiled, stream)
-    return a, bcc, tiled, stream, pairs
+    pattern = pack(a, a, block_k=16, bn=16, sparse_out=True)
+    values, tiled = pattern.fill(a.data)
+    return a, values, tiled, pattern.pairs, pattern.sparse_pairs
 
 
 def _straddles(key, chunk) -> bool:
@@ -56,10 +56,9 @@ def _straddles(key, chunk) -> bool:
                                     cluster_spgemm_pairs_resident])
 @pytest.mark.parametrize("chunk", [8, 24, 40])
 def test_dense_pairs_chunked_bitwise(packed, kernel, chunk):
-    _, _, tiled, stream, pairs = packed
+    _, values, tiled, pairs, _ = packed
     assert _straddles(pairs[0], chunk)
-    args = (*(jnp.asarray(p) for p in pairs), jnp.asarray(stream[2]),
-            tiled.tiles)
+    args = (*pairs, values, tiled.tiles)
     kw = dict(KW, nblocks=9, nnb=tiled.nnb, interpret=True)
     whole = np.asarray(kernel(*args, **kw))
     got = np.asarray(kernel(*args, chunk=chunk, **kw))
@@ -71,11 +70,10 @@ def test_stream_one_chunk_long(packed, short):
     """A stream exactly one chunk long (one launch), and one a step
     group longer than its chunk (two launches, the second mostly tail
     padding)."""
-    _, _, tiled, stream, pairs = packed
+    _, values, tiled, pairs, _ = packed
     t = pairs[0].shape[0]
     assert t % 8 == 0
-    args = (*(jnp.asarray(p) for p in pairs), jnp.asarray(stream[2]),
-            tiled.tiles)
+    args = (*pairs, values, tiled.tiles)
     kw = dict(KW, nblocks=9, nnb=tiled.nnb, interpret=True)
     whole = np.asarray(cluster_spgemm_pairs_db(*args, **kw))
     got = np.asarray(cluster_spgemm_pairs_db(*args, chunk=t - short, **kw))
@@ -83,10 +81,10 @@ def test_stream_one_chunk_long(packed, short):
 
 
 def test_empty_stream_returns_zero_c(packed):
-    _, _, tiled, stream, _ = packed
+    _, values, tiled, _, _ = packed
     empty = jnp.zeros((0,), jnp.int32)
     got = np.asarray(cluster_spgemm_pairs(
-        empty, empty, empty, empty, jnp.asarray(stream[2]), tiled.tiles,
+        empty, empty, empty, empty, values, tiled.tiles,
         nblocks=9, nnb=tiled.nnb, chunk=8, interpret=True, **KW))
     assert got.shape == (72, tiled.nnb * 16) and not got.any()
 
@@ -96,37 +94,19 @@ def test_all_zero_operand_through_ops(monkeypatch):
     chunked, reads back an all-zero C."""
     monkeypatch.setattr(ops, "_SMEM_STREAM_BUDGET", 4 * 4 * 8)
     a = HostCSR.from_dense(np.zeros((40, 40), np.float32))
-    bcc = bcc_from_host(a, block_r=8, block_k=16)
-    tiled = tiled_csr_from_host(a, block_k=16, bn=16)
-    got = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True,
-                                          sparse_c=False))
+    pattern = pack(a, a, block_k=16, bn=16, _SPARSE_C_DENSITY=-1.0)
+    assert pattern.route == "resident"
+    got = product(pattern, a, a)
     assert got.shape == (40, 40) and not got.any()
-
-
-def test_window_kernel_chunked_bitwise(packed):
-    _, _, tiled, stream, pairs = packed
-    wb = 2
-    rv = revisit_pair_stream(pairs, window_blocks=wb)
-    wins = (np.asarray(rv[0]) // wb).astype(np.int32)
-    assert _straddles(wins, 16)
-    args = (jnp.asarray(wins), *(jnp.asarray(p) for p in rv),
-            jnp.asarray(stream[2]), tiled.tiles)
-    kw = dict(KW, nblocks=9, nnb=tiled.nnb, window_blocks=wb,
-              interpret=True)
-    whole = np.asarray(cluster_spgemm_pairs_window(*args, **kw))
-    got = np.asarray(cluster_spgemm_pairs_window(*args, chunk=16, **kw))
-    np.testing.assert_array_equal(got, whole)
 
 
 @pytest.mark.parametrize("kernel", [cluster_spgemm_pairs_sparse,
                                     cluster_spgemm_pairs_sparse_db])
 def test_sparse_c_window_straddling_chunks_bitwise(packed, kernel):
-    _, bcc, tiled, stream, pairs = packed
-    c_slots, slots, a_idx, _, nslabs = ops.build_sparse_c_pairs(
-        bcc, tiled, pairs, stream)
+    _, values, tiled, _, sparse_pairs = packed
+    c_slots, slots, a_idx, _, nslabs = sparse_pairs
     assert _straddles(c_slots, 8)
-    args = (jnp.asarray(c_slots), jnp.asarray(slots), jnp.asarray(a_idx),
-            jnp.asarray(stream[2]), tiled.tiles)
+    args = (c_slots, slots, a_idx, values, tiled.tiles)
     kw = dict(KW, nslabs=int(nslabs), interpret=True)
     whole = np.asarray(kernel(*args, **kw))
     got = np.asarray(kernel(*args, chunk=8, **kw))
@@ -134,9 +114,9 @@ def test_sparse_c_window_straddling_chunks_bitwise(packed, kernel):
 
 
 def test_spmm_compact_chunked_bitwise(packed):
-    _, bcc, _, _, _ = packed
+    a = packed[0]
     block_ids, tile_ids, values = ops.bcc_compact_stream(
-        bcc, cover_all_blocks=True)
+        bcc_from_host(a, block_r=8, block_k=16), cover_all_blocks=True)
     assert _straddles(block_ids, 8)
     b = jnp.asarray(np.random.default_rng(3).standard_normal((80, 32)),
                     jnp.float32)
@@ -152,10 +132,9 @@ def test_spmm_compact_chunked_bitwise(packed):
 
 
 def test_sharded_chunked_bitwise(packed):
-    _, _, tiled, stream, pairs = packed
+    _, values, tiled, pairs, _ = packed
     ranges, sp = partition_pair_stream(pairs, nblocks=9, num_shards=3)
     kw = dict(KW, nblocks=9, nnb=tiled.nnb, interpret=True)
-    values = jnp.asarray(stream[2])
     whole = np.asarray(cluster_spgemm_pairs_sharded(
         sp, ranges, values, tiled.tiles, **kw))
     got = np.asarray(cluster_spgemm_pairs_sharded(
@@ -165,19 +144,21 @@ def test_sharded_chunked_bitwise(packed):
 
 @pytest.mark.parametrize("sparse_c", [False, True])
 def test_ops_chunks_at_the_smem_budget(monkeypatch, packed, sparse_c):
-    """The ops wrappers size chunks from the SMEM budget: a budget of a
+    """The launchers size chunks from the SMEM budget: a budget of a
     few dozen steps gives the same product as the reference."""
-    a, bcc, tiled, _, _ = packed
+    a = packed[0]
     monkeypatch.setattr(ops, "_SMEM_STREAM_BUDGET", 4 * 5 * 16)
     assert ops.stream_chunk(4) == 16
-    got = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True,
-                                          sparse_c=sparse_c))
+    pattern = pack(a, a, block_k=16, bn=16,
+                   _SPARSE_C_DENSITY=1.0 if sparse_c else -1.0)
+    assert pattern.route == ("sparse_c" if sparse_c else "resident")
+    got = product(pattern, a, a)
     np.testing.assert_allclose(got, spgemm_reference(a, a), rtol=1e-5,
                                atol=1e-5)
 
 
 def test_padded_grid_over_smem_budget_raises(monkeypatch, packed):
-    _, bcc, tiled, _, _ = packed
+    a = packed[0]
     monkeypatch.setattr(ops, "_SMEM_STREAM_BUDGET", 64)
     with pytest.raises(ValueError, match="SMEM"):
-        ops.bcc_spgemm_tiled(bcc, tiled, compact=False, interpret=True)
+        pack(a, a, block_k=16, bn=16, _COMPACT_C_STRIP_BUDGET=0)

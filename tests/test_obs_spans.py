@@ -204,7 +204,7 @@ def test_transfer_bytes_of_the_a2_pattern_pack(traced):
     bcc = bcc_from_host(a, block_k=bk)
     tiled = tiled_csr_from_host(a, block_k=bk)
     stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
-    pairs = ops.build_live_pairs(bcc, tiled, stream)
+    pairs = ops.pack_spgemm_pattern(a, a, block_k=bk).pairs
 
     def nbytes(*arrays):
         return sum(np.asarray(x).nbytes for x in arrays)

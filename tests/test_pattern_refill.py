@@ -3,11 +3,16 @@
 The planner packs A·B once per pattern (``SpGEMMPattern``) and, for each
 new value set, refills A's stream values and B's tiles on the device.
 Covers:
-  * the product of a refilled request is bit-identical to a full pack
-    of the same operands (``bcc_from_host`` → ``bcc_compact_stream`` →
-    ``bcc_spgemm_tiled``), over value sets that change and come back, a
-    permuted plan, an A·B pair, empty row blocks with tail padding, a
-    repeated (row, col) entry and bf16 B tiles;
+  * the slabs and tiles a refilled request launches on are byte for
+    byte those of a full host pack of the same operands
+    (``bcc_from_host`` → ``bcc_compact_stream``, ``tiled_csr_from_host``),
+    and its product is the launch on the full pack's arrays, over value
+    sets that change and come back, a permuted plan, an A·B pair, empty
+    row blocks with tail padding, a repeated (row, col) entry and bf16 B
+    tiles;
+  * the route is chosen once, at the pack, from what the pattern shows:
+    one case per route the CPU can take, each against
+    ``spgemm_reference``;
   * one pattern packs once whatever its values: ``exec_cache_refills``
     counts the value changes, the same values twice refill nothing, and
     the exec cache holds one entry;
@@ -21,6 +26,7 @@ import pytest
 from repro.core.formats import (HostCSR, bcc_from_host, bcc_layout,
                                 scatter_map, select_block_k,
                                 tiled_csr_from_host)
+from repro.core.spgemm import spgemm_reference
 from repro.kernels import ops
 from repro.kernels.cluster_spgemm import _stack_shard_streams
 from repro.obs.metrics import get_registry
@@ -97,8 +103,8 @@ CASES = ("values", "perm", "ab", "empty_blocks", "repeat", "bf16")
 
 
 def _full_pack(a, b, perm, dtype):
-    """The product as a full pack computes it: the operands packed on the
-    host, uploaded whole, the stream read back from the BCC."""
+    """The operands packed whole on the host, as a full pack does: A's
+    compact stream slabs, read back from its BCC, and B's TiledCSR."""
     if perm is None:
         ap = a
     elif b is None:
@@ -107,13 +113,13 @@ def _full_pack(a, b, perm, dtype):
         ap = a.permute_rows(perm)
     bh = ap if b is None else b
     bk = select_block_k(bh)
-    bcc = bcc_from_host(ap, block_k=bk)
-    tiled = tiled_csr_from_host(bh, block_k=bk, dtype=dtype)
-    stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
-    pairs = (ops.build_live_pairs(bcc, tiled, stream)
-             if ops.compact_grid_ok(bcc, tiled) else None)
-    c = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, stream=stream,
-                                        pairs=pairs))
+    values = ops.bcc_compact_stream(bcc_from_host(ap, block_k=bk),
+                                    cover_all_blocks=True)[2]
+    return values, tiled_csr_from_host(bh, block_k=bk, dtype=dtype)
+
+
+def _unpermuted(c, b, perm):
+    """The plan's product ``c`` in the operands' own order."""
     if perm is None:
         return c
     out = np.empty_like(c)
@@ -122,6 +128,14 @@ def _full_pack(a, b, perm, dtype):
     else:
         out[perm] = c
     return out
+
+
+def _product(a, b=None):
+    """``a @ (b or a)`` through a pattern of its own, in the operands'
+    order."""
+    b = a if b is None else b
+    pattern = ops.pack_spgemm_pattern(a, b, block_k=select_block_k(b))
+    return np.asarray(pattern.run(*pattern.fill(a.data, b.data)))
 
 
 def _planner(dtype=None):
@@ -149,7 +163,17 @@ def test_refilled_product_is_bit_identical_to_a_full_pack(case):
         ai = _revalued(a, seed)
         bi = None if b is None else _revalued(b, seed + 10)
         got = planner.execute(plan, ai, bi)
-        np.testing.assert_array_equal(got, _full_pack(ai, bi, perm, dtype))
+        (_, pattern, (_, values, tiled)), = planner._exec_cache.values()
+        full_values, full_tiled = _full_pack(ai, bi, perm, dtype)
+        np.testing.assert_array_equal(np.asarray(values), full_values)
+        np.testing.assert_array_equal(np.asarray(tiled.tiles),
+                                      np.asarray(full_tiled.tiles))
+        np.testing.assert_array_equal(np.asarray(tiled.table),
+                                      np.asarray(full_tiled.table))
+        full = ops.bcc_spgemm_tiled(pattern, jnp.asarray(full_values),
+                                    full_tiled)
+        np.testing.assert_array_equal(
+            got, _unpermuted(np.asarray(full), bi, perm))
     assert len(planner._exec_cache) == 1
 
 
@@ -158,7 +182,29 @@ def test_empty_blocks_case_has_cover_steps_tail_padding_and_sparse_c():
     _, ntiles, tpb, _ = bcc_layout(a, 8, select_block_k(a))
     keep, live = ops._compact_keep(ntiles, tpb, cover_all_blocks=True)
     assert (ntiles == 0).sum() == 3 and keep.size > live
-    assert ops.pack_spgemm_pattern(a, a, block_k=select_block_k(a)).sparse_c
+    assert ops.pack_spgemm_pattern(
+        a, a, block_k=select_block_k(a)).route == "sparse_c"
+
+
+@pytest.mark.parametrize("route", ["padded", "resident", "streamed",
+                                   "sharded", "sparse_c"])
+def test_route_is_chosen_from_what_the_pattern_shows(route, monkeypatch):
+    """Each route the CPU can take, chosen by the pack from the widths,
+    budgets, cores and C density it observes, and recorded in the
+    pattern; the product through the pattern is the reference's."""
+    a = _with_empty_blocks() if route == "sparse_c" else _random(
+        64, 64, 0.2, 14)
+    if route == "padded":
+        monkeypatch.setattr(ops, "_COMPACT_C_STRIP_BUDGET", 0)
+    if route == "streamed":
+        monkeypatch.setattr(ops, "_RESIDENT_B_BUDGET", 0)
+    if route == "sharded":
+        monkeypatch.setattr(ops, "pallas_shard_count", lambda: 2)
+    pattern = ops.pack_spgemm_pattern(a, a, block_k=select_block_k(a))
+    assert pattern.route == route
+    got = np.asarray(pattern.run(*pattern.fill(a.data)))
+    np.testing.assert_allclose(got, spgemm_reference(a, a), rtol=1e-5,
+                               atol=1e-5)
 
 
 def test_repeated_entry_keeps_the_value_written_last():
@@ -267,7 +313,7 @@ def test_concurrent_value_sets_never_mix():
     planner = _planner()
     plan = _plan(a)
     sets = [_revalued(a, s) for s in range(3)]
-    want = [_full_pack(x, None, None, jnp.float32) for x in sets]
+    want = [_product(x) for x in sets]
     wrong, done = [], []
 
     def worker(k):

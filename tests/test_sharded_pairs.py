@@ -1,8 +1,8 @@
-"""Multi-core sharded pair stream + B-fetch-deduping revisit order
-(ISSUE 5): partitioner edge cases (1 core degenerates bitwise, pair-less
-blocks land in exactly one shard with their sentinel), revisit-ordered
-output bit-identical to the unordered kernel, counters, balance, and the
-planner/cost-model wiring of the sharded variant.
+"""Multi-core sharded pair stream (ISSUE 5): partitioner edge cases
+(1 core degenerates bitwise, pair-less blocks land in exactly one shard
+with their sentinel), sharded output bit-identical to the unsharded
+kernel, counters, balance, and the planner/cost-model wiring of the
+sharded route.
 
 Everything here runs the serial partition (interpret mode / CPU) — the
 shard_map dispatch needs one device per shard and is exercised on TPU
@@ -16,17 +16,16 @@ try:
 except ImportError:          # pragma: no cover - container without hypothesis
     from _hypo_shim import given, settings, st
 
-from repro.core.formats import (HostCSR, bcc_from_host, live_pair_counters,
+from repro.core.formats import (HostCSR, live_pair_counters,
                                 partition_balance, partition_pair_stream,
-                                partition_pair_stream_reference,
-                                revisit_pair_stream, revisit_window_blocks,
-                                tiled_csr_from_host)
+                                partition_pair_stream_reference)
 from repro.core.spgemm import spgemm_reference
 from repro.kernels import ops
 from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs,
-                                          cluster_spgemm_pairs_sharded,
-                                          cluster_spgemm_pairs_window)
+                                          cluster_spgemm_pairs_sharded)
 from repro.kernels.ref import cluster_spgemm_pairs_sharded_ref
+
+from _packing import pack, product
 
 pytestmark = pytest.mark.pallas
 
@@ -38,19 +37,9 @@ def rand_host(n, m, density, seed):
     return HostCSR.from_dense(dense.astype(np.float32))
 
 
-def _pack(a, b, *, block_r=8, block_k=16, bn=16):
-    bcc = bcc_from_host(a, block_r=block_r, block_k=block_k)
-    tiled = tiled_csr_from_host(b, block_k=block_k, bn=bn)
-    stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
-    pairs = ops.build_live_pairs(bcc, tiled, stream)
-    return bcc, tiled, stream, pairs
-
-
-def _run_pairs(pairs, stream, tiled, nblocks, **kw):
-    import jax.numpy as jnp
-    return np.asarray(cluster_spgemm_pairs(
-        *(jnp.asarray(p) for p in pairs), jnp.asarray(stream[2]),
-        tiled.tiles, interpret=True, nblocks=nblocks, nnb=tiled.nnb, **kw))
+def _pairs(a, b):
+    """The live pairs of the packed pattern of ``a @ b``."""
+    return pack(a, b, block_k=16, bn=16).pairs
 
 
 # ---------------------------------------------------------------------------
@@ -68,7 +57,7 @@ def test_property_partition_matches_reference_and_covers(n, m, density,
     streams (minus tail padding) recovers the input stream."""
     a = rand_host(n, m, density, seed)
     b = rand_host(m, n, density, seed + 7)
-    _, _, _, pairs = _pack(a, b)
+    pairs = _pairs(a, b)
     nblocks = (a.nrows + 7) // 8
     r1, sp1 = partition_pair_stream(pairs, nblocks=nblocks,
                                     num_shards=shards)
@@ -102,7 +91,7 @@ def test_property_partition_matches_reference_and_covers(n, m, density,
 
 def test_partition_one_shard_is_bitwise_identity():
     a = rand_host(40, 40, 0.15, 3)
-    _, _, _, pairs = _pack(a, a)
+    pairs = _pairs(a, a)
     ranges, sp = partition_pair_stream(pairs, nblocks=(a.nrows + 7) // 8,
                                        num_shards=1)
     assert ranges.tolist() == [[0, (a.nrows + 7) // 8]]
@@ -120,7 +109,7 @@ def test_pairless_block_sentinel_lands_in_exactly_one_shard():
     dense_b = np.zeros((32, 32), np.float32)
     dense_b[np.arange(8), np.arange(8)] = 2.0
     a, b = HostCSR.from_dense(dense_a), HostCSR.from_dense(dense_b)
-    _, _, _, pairs = _pack(a, b)
+    pairs = _pairs(a, b)
     nblocks = (a.nrows + 7) // 8
     ranges, sp = partition_pair_stream(pairs, nblocks=nblocks, num_shards=3)
     for blk in range(nblocks):
@@ -155,7 +144,7 @@ def test_partition_ties_take_the_earliest_block():
 
 def test_num_shards_clipped_to_nblocks():
     a = rand_host(16, 16, 0.3, 4)          # 2 row blocks
-    _, _, _, pairs = _pack(a, a)
+    pairs = _pairs(a, a)
     ranges, sp = partition_pair_stream(pairs, nblocks=2, num_shards=8)
     assert len(sp) == 2 and ranges.shape == (2, 2)
 
@@ -167,93 +156,39 @@ def test_num_shards_clipped_to_nblocks():
 
 @pytest.mark.parametrize("shards", [1, 2, 3, 5])
 def test_sharded_kernel_bitwise_matches_unsharded(shards):
-    import jax.numpy as jnp
     a = rand_host(64, 48, 0.12, 11)
     b = rand_host(48, 64, 0.12, 12)
-    bcc, tiled, stream, pairs = _pack(a, b)
+    pattern = pack(a, b, block_k=16, bn=16)
+    pairs = pattern.pairs
+    values, tiled = pattern.fill(a.data, b.data)
     nblocks = (a.nrows + 7) // 8
-    base = _run_pairs(pairs, stream, tiled, nblocks, block_r=8, block_k=16,
-                      bn=16)
+    kw = dict(block_r=8, block_k=16, bn=16, nblocks=nblocks, nnb=tiled.nnb)
+    base = np.asarray(cluster_spgemm_pairs(*pairs, values, tiled.tiles,
+                                           interpret=True, **kw))
     ranges, sp = partition_pair_stream(pairs, nblocks=nblocks,
                                        num_shards=shards)
     got = np.asarray(cluster_spgemm_pairs_sharded(
-        sp, ranges, jnp.asarray(stream[2]), tiled.tiles, block_r=8,
-        block_k=16, bn=16, nblocks=nblocks, nnb=tiled.nnb, interpret=True))
+        sp, ranges, values, tiled.tiles, interpret=True, **kw))
     np.testing.assert_array_equal(got, base)
     want = cluster_spgemm_pairs_sharded_ref(
-        sp, ranges, stream[2], np.asarray(tiled.tiles), block_r=8,
-        block_k=16, bn=16, nblocks=nblocks, nnb=tiled.nnb)
+        sp, ranges, values, np.asarray(tiled.tiles), **kw)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
-def test_ops_wrapper_sharded_and_revisit_parity():
-    """bcc_spgemm_tiled(shards=…, revisit=…) — the serving entry point —
-    matches the reference for every knob combination."""
+def test_ops_wrapper_sharded_and_revisit_parity(monkeypatch):
+    """A pattern packed on a two-core backend routes the sharded grid,
+    and its product through ``bcc_spgemm_tiled`` matches the reference
+    with B pinned in VMEM and streamed."""
+    monkeypatch.setattr(ops, "pallas_shard_count", lambda: 2)
     a = rand_host(56, 40, 0.15, 21)
     b = rand_host(40, 56, 0.15, 22)
-    bcc, tiled, _, _ = _pack(a, b)
     want = spgemm_reference(a, b)
-    for kw in ({"shards": 2}, {"shards": 3, "revisit": True},
-               {"shards": 1, "revisit": True},
-               {"shards": 2, "resident": True}):
-        got = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True,
-                                              **kw))
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4,
-                                   err_msg=str(kw))
-
-
-# ---------------------------------------------------------------------------
-# revisit order: bit-identity + counter reduction
-# ---------------------------------------------------------------------------
-
-
-def _revisit(pairs, tiled, nblocks, *, block_r=8, bn=16):
-    wb = min(revisit_window_blocks(tiled.nnb, block_r=block_r, bn=bn),
-             max(nblocks, 1))
-    return revisit_pair_stream(pairs, window_blocks=wb), wb
-
-
-@pytest.mark.parametrize("n,k,density,seed", [
-    (40, 48, 0.10, 0),
-    (64, 64, 0.05, 1),
-    (17, 33, 0.15, 3),      # maximally ragged
-])
-def test_revisit_ordered_kernel_bitwise_matches_unordered(n, k, density,
-                                                          seed):
-    import jax.numpy as jnp
-    a = rand_host(n, k, density, seed)
-    b = rand_host(k, n, density, seed + 31)
-    bcc, tiled, stream, pairs = _pack(a, b)
-    nblocks = (a.nrows + 7) // 8
-    base = _run_pairs(pairs, stream, tiled, nblocks, block_r=8, block_k=16,
-                      bn=16)
-    rv, wb = _revisit(pairs, tiled, nblocks)
-    wins = (np.asarray(rv[0]).astype(np.int64) // wb).astype(np.int32)
-    got = np.asarray(cluster_spgemm_pairs_window(
-        jnp.asarray(wins), *(jnp.asarray(p) for p in rv),
-        jnp.asarray(stream[2]), tiled.tiles, block_r=8, block_k=16, bn=16,
-        nblocks=nblocks, nnb=tiled.nnb, window_blocks=wb, interpret=True))
-    np.testing.assert_array_equal(got, base)
-
-
-def test_revisit_stream_is_window_sorted_permutation():
-    a = rand_host(64, 64, 0.1, 40)
-    _, tiled, _, pairs = _pack(a, a)
-    nblocks = (a.nrows + 7) // 8
-    rv, wb = _revisit(pairs, tiled, nblocks)
-    # a permutation of the input triples
-    key = lambda p: sorted(zip(*(np.asarray(c).tolist() for c in p)))
-    assert key(rv) == key(pairs)
-    blocks, js, slots, _ = (np.asarray(c) for c in rv)
-    wins = blocks.astype(np.int64) // wb
-    assert np.all(np.diff(wins) >= 0)          # windows non-decreasing
-    # within a window, (j, slot) non-decreasing lexicographically
-    wkey = (wins * tiled.nnb + js) * (int(slots.max()) + 2) + slots
-    assert np.all(np.diff(wkey) >= 0)
-    # and the dedup actually reduces refetches on this pattern
-    c0 = live_pair_counters(pairs, block_r=8, block_k=16, bn=16)
-    c1 = live_pair_counters(rv, block_r=8, block_k=16, bn=16)
-    assert c1["b_tile_refetches"] < c0["b_tile_refetches"]
+    for budget in (ops._RESIDENT_B_BUDGET, 0):
+        pattern = pack(a, b, block_k=16, bn=16, _RESIDENT_B_BUDGET=budget)
+        assert pattern.route == "sharded" and len(pattern.shards[1]) == 2
+        np.testing.assert_allclose(product(pattern, a, b), want,
+                                   rtol=1e-4, atol=1e-4,
+                                   err_msg=str(budget))
 
 
 def test_counters_b_fetch_units_and_balance():
@@ -279,44 +214,16 @@ def test_counters_b_fetch_units_and_balance():
 
 def test_quick_tier_partition_balance_and_refetch_reduction():
     """Stream-level acceptance on a quick-tier slice (host-only, no
-    kernels): 4-way partition within 20% of ideal, revisit ordering
-    reduces B tile refetches ≥ 1.15× (the bench gates the full tier)."""
+    kernels): the 4-way partition of the packed pattern's live pairs is
+    within 20% of ideal (the bench gates the full tier)."""
     from repro.benchlib import representative_subset
     from repro.core.suite import generate
     for spec in representative_subset(4):
         a = generate(spec)
-        bcc = bcc_from_host(a, block_r=8, block_k=128)
-        tiled = tiled_csr_from_host(a, 128, 128)
-        stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
-        pairs = ops.build_live_pairs(bcc, tiled, stream)
+        pairs = ops.pack_spgemm_pattern(a, a, block_k=128).pairs
         nblocks = (a.nrows + 7) // 8
         _, sp = partition_pair_stream(pairs, nblocks=nblocks, num_shards=4)
         assert partition_balance(sp) <= 1.2, spec.name
-        rv, _ = _revisit(pairs, tiled, nblocks, bn=128)
-        c0 = live_pair_counters(pairs, block_r=8, block_k=128)
-        c1 = live_pair_counters(rv, block_r=8, block_k=128)
-        ratio = max(c0["b_tile_refetches"], 1) \
-            / max(c1["b_tile_refetches"], 1)
-        assert ratio >= 1.15, (spec.name, ratio)
-
-
-@pytest.mark.slow
-def test_quick_tier_revisit_bitwise_parity():
-    """Acceptance: revisit-ordered output is bit-identical to the
-    unordered kernel across the quick-tier families (interpret mode is
-    minutes-slow at suite sizes, hence the slow marker)."""
-    from repro.benchlib import representative_subset
-    from repro.core.suite import generate
-    for spec in representative_subset(8):
-        a = generate(spec)
-        bcc, tiled, stream, pairs = _pack(a, a, block_k=128, bn=128)
-        nblocks = (a.nrows + 7) // 8
-        base = _run_pairs(pairs, stream, tiled, nblocks, block_r=8,
-                          block_k=128, bn=128)
-        got = np.asarray(ops.bcc_spgemm_tiled(
-            bcc, tiled, interpret=True, revisit=True, resident=False))
-        np.testing.assert_array_equal(
-            got, base[: a.nrows, : a.ncols], err_msg=spec.name)
 
 
 def test_shard_map_dispatch_multi_device_subprocess():
@@ -330,8 +237,7 @@ def test_shard_map_dispatch_multi_device_subprocess():
     prog = (
         "import numpy as np, jax, jax.numpy as jnp\n"
         "assert jax.device_count() == 4, jax.device_count()\n"
-        "from repro.core.formats import (HostCSR, bcc_from_host,\n"
-        "    tiled_csr_from_host, partition_pair_stream)\n"
+        "from repro.core.formats import HostCSR, partition_pair_stream\n"
         "from repro.kernels import ops\n"
         "from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs,\n"
         "    cluster_spgemm_pairs_sharded)\n"
@@ -339,17 +245,16 @@ def test_shard_map_dispatch_multi_device_subprocess():
         "dense = ((r.random((64, 64)) < 0.15)\n"
         "         * r.uniform(0.5, 2.0, (64, 64))).astype(np.float32)\n"
         "a = HostCSR.from_dense(dense)\n"
-        "bcc = bcc_from_host(a, block_r=8, block_k=16)\n"
-        "tiled = tiled_csr_from_host(a, block_k=16, bn=16)\n"
-        "stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)\n"
-        "pairs = ops.build_live_pairs(bcc, tiled, stream)\n"
+        "ops._BN = 16\n"
+        "pattern = ops.pack_spgemm_pattern(a, a, block_k=16)\n"
+        "values, tiled = pattern.fill(a.data)\n"
+        "pairs = pattern.pairs\n"
         "kw = dict(block_r=8, block_k=16, bn=16, nblocks=8, nnb=tiled.nnb)\n"
         "base = np.asarray(cluster_spgemm_pairs(\n"
-        "    *(jnp.asarray(p) for p in pairs), jnp.asarray(stream[2]),\n"
-        "    tiled.tiles, interpret=True, **kw))\n"
+        "    *pairs, values, tiled.tiles, interpret=True, **kw))\n"
         "ranges, sp = partition_pair_stream(pairs, nblocks=8, num_shards=4)\n"
         "got = np.asarray(cluster_spgemm_pairs_sharded(\n"
-        "    sp, ranges, jnp.asarray(stream[2]), tiled.tiles,\n"
+        "    sp, ranges, values, tiled.tiles,\n"
         "    interpret=True, use_shard_map=True, **kw))\n"
         "assert np.array_equal(got, base), 'shard_map mismatch'\n"
         "print('OK')\n"
@@ -366,7 +271,7 @@ def test_shard_map_dispatch_multi_device_subprocess():
 
 
 # ---------------------------------------------------------------------------
-# planner wiring: cost model shard term + service shard_pack
+# planner wiring: cost model shard term + the service's sharded pack
 # ---------------------------------------------------------------------------
 
 
@@ -426,6 +331,6 @@ def test_service_packs_shard_partition(monkeypatch):
                                rtol=1e-3, atol=1e-3)
     packed = [v[1] for v in planner._exec_cache.values()
               if v[0] == "pallas"]
-    assert packed and packed[0].shard_pack is not None  # shard_pack cached
-    ranges, sp, wb = packed[0].shard_pack
-    assert len(sp) == 2 and wb is None
+    assert packed and packed[0].route == "sharded"  # partition cached
+    ranges, sp = packed[0].shards
+    assert len(sp) == 2

@@ -2,10 +2,12 @@
 upper bound (vectorized vs loop reference, domination over exact per-row
 nnz(C), tightness on disjoint-column constructions), the ``CompactedC``
 round trip (bit-identical to ``spgemm_reference`` for both sparse-C
-kernel variants on integer-valued operands), the density auto-select in
-``ops.bcc_spgemm_tiled``, and the ``workload="chain"`` planner path
+kernel variants on integer-valued operands), the density routing of
+``ops.pack_spgemm_pattern``, and the ``workload="chain"`` planner path
 (A³ end-to-end with per-hop plan-cache hits on the second call).
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -14,7 +16,7 @@ try:
 except ImportError:          # pragma: no cover - container without hypothesis
     from _hypo_shim import given, settings, st
 
-from repro.core.formats import (COUNTER_UNITS, HostCSR, bcc_from_host,
+from repro.core.formats import (COUNTER_UNITS, HostCSR,
                                 compacted_c_counters, compacted_c_from_dense,
                                 compacted_c_table, compacted_c_to_host,
                                 symbolic_strip_nnz,
@@ -22,6 +24,10 @@ from repro.core.formats import (COUNTER_UNITS, HostCSR, bcc_from_host,
                                 tile_col_occupancy, tiled_csr_from_host)
 from repro.core.spgemm import spgemm_reference, symbolic_row_nnz
 from repro.kernels import ops
+from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs_sparse,
+                                          cluster_spgemm_pairs_sparse_db)
+
+from _packing import pack
 
 BR, BK, BN = 8, 16, 16
 
@@ -35,16 +41,15 @@ def int_host(n, m, density, seed):
     return HostCSR.from_dense(dense.astype(np.float32))
 
 
-def _pack(a, b):
-    bcc = bcc_from_host(a, block_r=BR, block_k=BK)
-    tiled = tiled_csr_from_host(b, block_k=BK, bn=BN)
-    stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
-    pairs = ops.build_live_pairs(bcc, tiled, stream)
-    return bcc, tiled, stream, pairs
+def _pack(a, b, **kw):
+    """The sparse-C pattern of ``a @ b`` and its value set."""
+    pattern = pack(a, b, block_k=BK, bn=BN, sparse_out=True, **kw)
+    return (pattern, *pattern.fill(a.data, b.data))
 
 
 def _strip_bound(a, b):
-    bcc, tiled, _, pairs = _pack(a, b)
+    pairs = pack(a, b, block_k=BK, bn=BN).pairs
+    tiled = tiled_csr_from_host(b, block_k=BK, bn=BN)
     nblocks = (a.nrows + BR - 1) // BR
     ub = symbolic_strip_nnz(pairs, tile_col_occupancy(tiled),
                             nblocks=nblocks, nnb=tiled.nnb)
@@ -142,10 +147,11 @@ def test_strip_bound_tight_for_disjoint_column_rows():
 @pytest.mark.parametrize("density", [0.0, 0.05, 0.25])
 def test_sparse_c_kernel_bit_identical(double_buffer, density):
     a = int_host(72, 72, density, seed=int(density * 100) + 7)
-    bcc, tiled, stream, pairs = _pack(a, a)
-    cc = ops.bcc_spgemm_sparse_c(bcc, tiled, interpret=True, stream=stream,
-                                 pairs=pairs, double_buffer=double_buffer,
-                                 epilogue="kernel")
+    pattern, values, tiled = _pack(a, a)
+    kernel = (cluster_spgemm_pairs_sparse_db if double_buffer
+              else cluster_spgemm_pairs_sparse)
+    cc = ops._sparse_c_kernel(dataclasses.replace(pattern, kernel=kernel),
+                              values, tiled)
     got = compacted_c_to_host(cc).to_dense()
     np.testing.assert_array_equal(got, spgemm_reference(a, a))
 
@@ -153,13 +159,9 @@ def test_sparse_c_kernel_bit_identical(double_buffer, density):
 @pytest.mark.pallas
 def test_sparse_c_xla_epilogue_bit_identical_to_kernel():
     a = int_host(64, 64, 0.08, seed=11)
-    bcc, tiled, stream, pairs = _pack(a, a)
-    kern = ops.bcc_spgemm_sparse_c(bcc, tiled, interpret=True,
-                                   stream=stream, pairs=pairs,
-                                   epilogue="kernel")
-    xla = ops.bcc_spgemm_sparse_c(bcc, tiled, interpret=True,
-                                  stream=stream, pairs=pairs,
-                                  epilogue="xla")
+    pattern, values, tiled = _pack(a, a)
+    kern = ops._sparse_c_kernel(pattern, values, tiled)
+    xla = ops._sparse_c_xla(pattern, values, tiled)
     np.testing.assert_array_equal(np.asarray(kern.table),
                                   np.asarray(xla.table))
     np.testing.assert_array_equal(np.asarray(kern.slabs),
@@ -171,12 +173,13 @@ def test_sparse_c_xla_epilogue_bit_identical_to_kernel():
 @pytest.mark.pallas
 def test_compacted_c_table_and_counters():
     a = int_host(48, 48, 0.06, seed=5)
-    bcc, tiled, _, pairs = _pack(a, a)
+    pattern, values, tiled = _pack(a, a)
+    pairs = pattern.pairs
     nblocks = (a.nrows + BR - 1) // BR
     table, nlive = compacted_c_table(pairs, nblocks=nblocks, nnb=tiled.nnb)
     assert table.shape == (nblocks * tiled.nnb,)
     assert int((np.asarray(table) > 0).sum()) == nlive
-    cc = ops.bcc_spgemm_sparse_c(bcc, tiled, interpret=True, pairs=pairs)
+    cc = pattern.run_sparse(values, tiled)
     cnt = compacted_c_counters(cc)
     assert set(cnt) <= set(COUNTER_UNITS)        # all declared with units
     assert cnt["c_bytes_sparse"] <= cnt["c_bytes_dense"]
@@ -210,7 +213,7 @@ def test_compacted_c_from_dense_roundtrip():
 
 
 # ---------------------------------------------------------------------------
-# ops auto-select: output-density routing
+# pack routing: output density
 # ---------------------------------------------------------------------------
 
 
@@ -219,19 +222,22 @@ def test_auto_select_routes_by_window_density():
     # sparse output → density under the threshold → the sparse-C tier
     # runs; forced dense must agree bit for bit either way
     a = int_host(80, 80, 0.03, seed=21)
-    bcc, tiled, stream, pairs = _pack(a, a)
+
+    def product(**threshold):
+        pattern = pack(a, a, block_k=BK, bn=BN, **threshold)
+        return pattern, np.asarray(pattern.run(*pattern.fill(a.data,
+                                                              a.data)))
+    pattern, auto = product()
     nblocks = (a.nrows + BR - 1) // BR
-    dens = ops.predict_c_window_density(pairs, nblocks=nblocks,
-                                        nnb=tiled.nnb)
+    dens = ops.predict_c_window_density(pattern.pairs, nblocks=nblocks,
+                                        nnb=(a.ncols + BN - 1) // BN)
     assert 0.0 <= dens <= 1.0
-    auto = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True,
-                                           stream=stream, pairs=pairs))
-    forced_dense = np.asarray(ops.bcc_spgemm_tiled(
-        bcc, tiled, interpret=True, stream=stream, pairs=pairs,
-        sparse_c=False))
-    forced_sparse = np.asarray(ops.bcc_spgemm_tiled(
-        bcc, tiled, interpret=True, stream=stream, pairs=pairs,
-        sparse_c=True))
+    assert pattern.route == ("sparse_c" if dens <= ops._SPARSE_C_DENSITY
+                             else "resident")
+    dense_route, forced_dense = product(_SPARSE_C_DENSITY=-1.0)
+    sparse_route, forced_sparse = product(_SPARSE_C_DENSITY=1.0)
+    assert dense_route.route == "resident"
+    assert sparse_route.route == "sparse_c"
     np.testing.assert_array_equal(auto, forced_dense)
     np.testing.assert_array_equal(auto, forced_sparse)
     np.testing.assert_array_equal(auto, spgemm_reference(a, a))

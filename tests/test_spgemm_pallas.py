@@ -24,6 +24,8 @@ from repro.kernels.cluster_spgemm import (cluster_spgemm_resident,
                                           cluster_spgemm_tiled)
 from repro.kernels.ref import cluster_spgemm_tiled_ref
 
+from _packing import pack, product
+
 pytestmark = pytest.mark.pallas
 
 requires_tpu = pytest.mark.skipif(not ops.on_tpu(),
@@ -38,12 +40,15 @@ def rand_host(n, m, density, seed):
     return HostCSR.from_dense(dense.astype(np.float32))
 
 
-def _run_tiled(a: HostCSR, b: HostCSR, *, block_r=8, block_k=16, bn=16,
-               resident=None) -> np.ndarray:
-    bcc = bcc_from_host(a, block_r=block_r, block_k=block_k)
-    tiled = tiled_csr_from_host(b, block_k=block_k, bn=bn)
-    return np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True,
-                                           resident=resident))
+def _run_tiled(a: HostCSR, b: HostCSR, *, block_k=16, bn=16,
+               resident=True, b_dtype=np.float32) -> np.ndarray:
+    """The pattern's product on the route its pack chooses; B's tile
+    store is pinned in VMEM (it fits the budget) unless ``resident`` is
+    false."""
+    budget = ops._RESIDENT_B_BUDGET if resident else 0
+    pattern = pack(a, b, block_k=block_k, bn=bn, b_dtype=b_dtype,
+                   _RESIDENT_B_BUDGET=budget)
+    return product(pattern, a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -75,7 +80,7 @@ def test_spgemm_tiled_empty_rows_and_empty_blocks():
     dense[39, 31] = 5.0
     a = HostCSR.from_dense(dense)
     b = rand_host(32, 24, 0.4, 7)
-    got = _run_tiled(a, b, block_r=8, block_k=8, bn=8)
+    got = _run_tiled(a, b, block_k=8, bn=8)
     np.testing.assert_allclose(got, spgemm_reference(a, b),
                                rtol=1e-4, atol=1e-4)
     assert np.all(got[8:16] == 0.0)
@@ -90,7 +95,7 @@ def test_spgemm_tiled_hub_column():
     dense_b[5, :] = 1.0                     # and a dense hub row
     a = rand_host(48, 48, 0.12, 12)
     b = HostCSR.from_dense(dense_b)
-    got = _run_tiled(a, b, block_r=8, block_k=16, bn=16)
+    got = _run_tiled(a, b, block_k=16, bn=16)
     np.testing.assert_allclose(got, spgemm_reference(a, b),
                                rtol=1e-4, atol=1e-4)
 
@@ -256,14 +261,6 @@ def test_cost_model_gates_pallas_off_tpu():
 # ---------------------------------------------------------------------------
 
 
-def _pairs_for(a, b, *, block_r=8, block_k=16, bn=16):
-    from repro.core.formats import bcc_from_host, tiled_csr_from_host
-    bcc = bcc_from_host(a, block_r=block_r, block_k=block_k)
-    tiled = tiled_csr_from_host(b, block_k=block_k, bn=bn)
-    stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
-    return bcc, tiled, stream, ops.build_live_pairs(bcc, tiled, stream)
-
-
 @settings(max_examples=20, deadline=None)
 @given(st.integers(4, 48), st.integers(4, 48), st.floats(0.0, 0.4),
        st.integers(0, 1000))
@@ -274,7 +271,11 @@ def test_property_live_pair_stream_matches_reference(n, m, density, seed):
     from repro.core.segment import rank_in_segment
     a = rand_host(n, m, density, seed)
     b = rand_host(m, n, density, seed + 31)
-    bcc, tiled, stream, got = _pairs_for(a, b)
+    got = pack(a, b, block_k=16, bn=16).pairs
+    # the loop oracle on the full host packs of A and B
+    bcc = bcc_from_host(a, block_r=8, block_k=16)
+    tiled = tiled_csr_from_host(b, block_k=16, bn=16)
+    stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
     step_live = rank_in_segment(np.asarray(stream[0], np.int64)) \
         < np.asarray(bcc.ntiles)[stream[0]]
     want = live_pair_stream_reference(
@@ -293,7 +294,7 @@ def test_property_live_pair_stream_matches_reference(n, m, density, seed):
 def test_live_pair_counters_units():
     from repro.core.formats import live_pair_counters
     a = rand_host(32, 32, 0.2, 70)
-    _, _, _, pairs = _pairs_for(a, a)
+    pairs = pack(a, a, block_k=16, bn=16).pairs
     cnt = live_pair_counters(pairs, block_r=8, block_k=16)
     blocks, js, slots, a_idx = (np.asarray(p) for p in pairs)
     assert cnt["grid_steps"] == blocks.shape[0]
@@ -308,19 +309,23 @@ def test_live_pair_counters_units():
 def test_compact_matches_padded_grid_bitwise():
     """Same accumulation order (s ascending within each strip) → the
     compacted grid reproduces the PR-3 padded grid bit-for-bit."""
+    from repro.kernels.cluster_spgemm import cluster_spgemm_pairs_db
     a = rand_host(40, 48, 0.15, 80)
     b = rand_host(48, 40, 0.15, 81)
-    from repro.core.formats import bcc_from_host, tiled_csr_from_host
-    bcc = bcc_from_host(a, block_r=8, block_k=16)
-    tiled = tiled_csr_from_host(b, block_k=16, bn=16)
-    legacy = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True,
-                                             compact=False, resident=True))
-    for kw in ({"resident": True}, {"resident": False,
-                                    "double_buffer": False},
-               {"resident": False, "double_buffer": True}):
-        got = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled, interpret=True,
-                                              compact=True, **kw))
-        np.testing.assert_array_equal(got, legacy)
+    padded = pack(a, b, block_k=16, bn=16, _COMPACT_C_STRIP_BUDGET=0)
+    assert padded.route == "padded"
+    legacy = product(padded, a, b)
+    for route, budget in (("resident", ops._RESIDENT_B_BUDGET),
+                          ("streamed", 0)):
+        pattern = pack(a, b, block_k=16, bn=16, _RESIDENT_B_BUDGET=budget)
+        assert pattern.route == route
+        np.testing.assert_array_equal(product(pattern, a, b), legacy)
+    # the double-buffered stream, which the pack chooses on a TPU only
+    values, tiled = pattern.fill(a.data, b.data)
+    got = np.asarray(cluster_spgemm_pairs_db(
+        *pattern.pairs, values, tiled.tiles, block_r=8, block_k=16, bn=16,
+        nblocks=5, nnb=tiled.nnb, interpret=True))
+    np.testing.assert_array_equal(got[:40, :40], legacy)
 
 
 def test_fully_dead_strip_is_zero_initialized():
@@ -333,8 +338,7 @@ def test_fully_dead_strip_is_zero_initialized():
     dense_b = np.zeros((32, 32), np.float32)
     dense_b[np.arange(8), np.arange(8)] = 2.0   # only B tile (0, 0) live
     a, b = HostCSR.from_dense(dense_a), HostCSR.from_dense(dense_b)
-    _, _, _, pairs = _pairs_for(a, b, block_k=16, bn=16)
-    slots = np.asarray(pairs[2])
+    slots = np.asarray(pack(a, b, block_k=16, bn=16).pairs[2])
     assert (slots == 0).sum() > 0              # sentinels exist
     got = _run_tiled(a, b, block_k=16, bn=16)
     want = spgemm_reference(a, b)
@@ -356,14 +360,10 @@ def test_bf16_tiles_parity_within_documented_tolerance(n, k, density, seed):
     dense_b = np.asarray(rand_host(k, n, density, seed + 100).to_dense())
     dense_b[:, min(3, n - 1)] = 1.0            # hub column
     b = HostCSR.from_dense(dense_b)
-    bcc = bcc_from_host(a, block_r=8, block_k=16)
-    tiled16 = tiled_csr_from_host(b, block_k=16, bn=16, dtype=jnp.bfloat16)
     want = spgemm_reference(a, b)
     scale = max(np.abs(want).max(), 1e-9)
-    for kw in ({"resident": True}, {"resident": False,
-                                    "double_buffer": False}):
-        got = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled16, interpret=True,
-                                              **kw))
+    for resident in (True, False):
+        got = _run_tiled(a, b, resident=resident, b_dtype=jnp.bfloat16)
         assert got.dtype == np.float32         # fp32 accumulate contract
         assert np.abs(got - want).max() / scale < 2e-2
 
@@ -375,9 +375,7 @@ def test_bf16_empty_rows_parity():
     a = HostCSR.from_dense(dense)
     b = rand_host(32, 24, 0.4, 7)
     import jax.numpy as jnp
-    bcc = bcc_from_host(a, block_r=8, block_k=8)
-    tiled16 = tiled_csr_from_host(b, block_k=8, bn=8, dtype=jnp.bfloat16)
-    got = np.asarray(ops.bcc_spgemm_tiled(bcc, tiled16, interpret=True))
+    got = _run_tiled(a, b, block_k=8, bn=8, b_dtype=jnp.bfloat16)
     want = spgemm_reference(a, b)
     scale = max(np.abs(want).max(), 1e-9)
     assert np.abs(got - want).max() / scale < 2e-2
@@ -392,16 +390,17 @@ def test_pairs_kernels_match_packed_oracle():
     from repro.kernels.ref import cluster_spgemm_pairs_ref
     a = rand_host(32, 32, 0.15, 20)
     b = rand_host(32, 32, 0.15, 21)
-    bcc, tiled, stream, pairs = _pairs_for(a, b)
+    pattern = pack(a, b, block_k=16, bn=16)
+    values, tiled = pattern.fill(a.data, b.data)
+    pairs = tuple(np.asarray(p) for p in pattern.pairs)
     kw = dict(block_r=8, block_k=16, bn=16,
               nblocks=(a.nrows + 7) // 8, nnb=tiled.nnb)
-    want = cluster_spgemm_pairs_ref(*pairs, stream[2],
+    want = cluster_spgemm_pairs_ref(*pairs, np.asarray(values),
                                     np.asarray(tiled.tiles), **kw)
     for kernel in (cluster_spgemm_pairs, cluster_spgemm_pairs_resident,
                    cluster_spgemm_pairs_db):
-        got = np.asarray(kernel(
-            *(np.asarray(p) for p in pairs), stream[2], tiled.tiles,
-            interpret=True, **kw))
+        got = np.asarray(kernel(*pairs, values, tiled.tiles,
+                                interpret=True, **kw))
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
@@ -422,15 +421,13 @@ def test_bench_kernels_counter_gates():
     from benchmarks.bench_kernels import check_gates
     ok = {"grid_steps_per_mxu_gm": 1.01, "a_bytes_ratio_compact_gm": 6.0,
           "b_bytes_ratio_routed_gm": 1.35, "b_bytes_bf16_ratio_gm": 2.0,
-          "b_tile_refetch_ratio_gm": 90.0, "shard_balance_worst": 1.05,
+          "shard_balance_worst": 1.05,
           "c_bytes_ratio_gm": 2.5}
     assert check_gates(ok) == []
     bad = dict(ok, grid_steps_per_mxu_gm=1.5)
     assert any("grid_steps_per_mxu_gm" in f for f in check_gates(bad))
     bad = dict(ok, c_bytes_ratio_gm=1.2)
     assert any("c_bytes_ratio_gm" in f for f in check_gates(bad))
-    bad = dict(ok, b_tile_refetch_ratio_gm=1.0)
-    assert any("b_tile_refetch_ratio_gm" in f for f in check_gates(bad))
     bad = dict(ok, shard_balance_worst=1.4)
     assert any("shard_balance_worst" in f for f in check_gates(bad))
     assert any("missing" in f for f in check_gates({}))
