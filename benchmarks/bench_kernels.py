@@ -69,7 +69,7 @@ from repro.core.formats import (COUNTER_UNITS, CompactedC, bcc_from_host,
                                 compacted_c_counters, compacted_c_table,
                                 compacted_c_to_host, csr_from_host,
                                 live_pair_counters, partition_balance,
-                                partition_pair_stream, tiled_csr_from_host,
+                                tiled_csr_from_host,
                                 tiled_live_tiles)
 from repro.core.reorder import reorder
 from repro.core.spgemm import (b_bytes_rowwise_binned, b_bytes_tiled,
@@ -82,7 +82,7 @@ from repro.kernels import ops
 from benchmarks.common import geomean, print_csv, tier_specs
 
 VMEM_BUDGET = 16 * 2**20
-BLOCK_R, BLOCK_K, BN = 8, 128, 128
+BLOCK_K, BN = 128, 128
 
 # counter-only acceptance thresholds (--gate / make bench-kernels)
 GATE_STEPS_PER_MXU = 1.1          # compacted grid: ≤ this, geomean
@@ -128,12 +128,14 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
             tb = b_bytes_tiled(live, BLOCK_K, BN)
             if best_b is None or tb < best_b:
                 best_name, best_b, best_live, best_mat = name, tb, live, ar
-        bcc = bcc_from_host(best_mat, block_r=BLOCK_R, block_k=BLOCK_K)
-        stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
-        tiled_b = tiled_csr_from_host(best_mat, BLOCK_K, BN)
+        # the serving layout: A's row blocks as high as the pack chose
         pattern = ops.pack_spgemm_pattern(best_mat, best_mat,
                                           block_k=BLOCK_K)
         pairs = pattern.pairs
+        block_r = pattern.a.block_r
+        bcc = bcc_from_host(best_mat, block_r=block_r, block_k=BLOCK_K)
+        stream = ops.bcc_compact_stream(bcc, cover_all_blocks=True)
+        tiled_b = tiled_csr_from_host(best_mat, BLOCK_K, BN)
         routed_b = min(xla_b, best_b)
         ratio_tiled = xla_b / max(best_b, 1)
         ratio_routed = xla_b / max(routed_b, 1)
@@ -146,20 +148,24 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
         # fetch per *stream step* (a_bytes_stream_legacy), under-reporting
         # the padded grid's A traffic nnb-fold; both are reported, the
         # per-grid-step figure is what the compacted ratio gates on.
-        slab_bytes = BLOCK_R * BLOCK_K * 4
+        slab_bytes = block_r * BLOCK_K * 4
         s_steps = int(stream[0].shape[0])
         padded_steps = tiled_b.nnb * s_steps
         a_bytes_padded = padded_steps * slab_bytes
         a_bytes_legacy = s_steps * slab_bytes
-        cnt = live_pair_counters(pairs, block_r=BLOCK_R, block_k=BLOCK_K,
+        cnt = live_pair_counters(pairs, block_r=block_r, block_k=BLOCK_K,
                                  bn=BN)
         a_ratio = a_bytes_padded / max(cnt["a_bytes"], 1)
-        nblocks = (best_mat.nrows + BLOCK_R - 1) // BLOCK_R
-        # multi-core partition: contiguous block ranges balanced by
-        # live-pair count — worst per-core load over the ideal split
-        _, shard_pairs = partition_pair_stream(
-            pairs, nblocks=nblocks, num_shards=BENCH_SHARDS)
-        balance = partition_balance(shard_pairs)
+        nblocks = (best_mat.nrows + block_r - 1) // block_r
+        # multi-core partition: the sharded route's own pack (its row
+        # blocks no taller than BENCH_SHARDS cores balance), contiguous
+        # block ranges balanced by live-pair count — worst per-core load
+        # over the ideal split
+        with mock.patch.object(ops, "pallas_shard_count",
+                               lambda: BENCH_SHARDS):
+            sharded = ops.pack_spgemm_pattern(best_mat, best_mat,
+                                              block_k=BLOCK_K)
+        balance = partition_balance(sharded.shards[1])
         # sparse-C output tier (ISSUE 6): C-side traffic, known before
         # the numeric phase — the live-pair stream pins the CompactedC
         # table, which pins the slab bytes; a structural (zero-slab)
@@ -173,15 +179,15 @@ def _spgemm_pallas_vs_xla(tier: str) -> dict:
         c_table, c_live = compacted_c_table(pairs, nblocks=nblocks,
                                             nnb=tiled_b.nnb)
         c_struct = CompactedC(
-            slabs=jnp.zeros((c_live + 1, BLOCK_R, BN), jnp.float32),
+            slabs=jnp.zeros((c_live + 1, block_r, BN), jnp.float32),
             table=c_table, nrows=best_mat.nrows, ncols=best_mat.ncols,
-            block_r=BLOCK_R, bn=BN)
+            block_r=block_r, bn=BN)
         c_cnt = compacted_c_counters(
             c_struct,
             c_nnz=int(symbolic_row_nnz(best_mat, best_mat).sum()))
         c_ratio = (c_cnt["c_bytes_dense"]
                    / max(c_cnt["c_bytes_sparse"], 1))
-        c_sparse_routed = c_density <= ops._SPARSE_C_DENSITY
+        c_sparse_routed = pattern.route == "sparse_c"
         # bf16 tile store: measured from the actually-packed stores (not
         # re-derived from the byte formula), so a regression in the bf16
         # packing plumbing shows up as a gate failure

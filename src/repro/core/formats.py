@@ -1014,6 +1014,53 @@ def select_block_k(h: HostCSR, *, bn: int = 128,
     return int(best_bk)
 
 
+def block_r_counts(h: HostCSR, table, *, block_k: int, nnb: int,
+                   heights: Sequence[int] = (8, 16, 32, 64, 128)
+                   ) -> dict[int, tuple[int, int, int]]:
+    """The stream lengths a pack of ``h @ B`` would build at each row-block
+    height of ``heights``, counted without building one: ``{r: (steps,
+    pairs, windows)}``.
+
+    ``steps`` is the length of A's compact stream (cover steps of empty
+    blocks and tail padding included), ``pairs`` that of its
+    :func:`live_pair_stream` against B's tile ``table`` (``block_k`` rows
+    by ``nnb`` column tiles; sentinels and tail padding included), and
+    ``windows`` the live ``(r, bn)`` windows of C. Every height is a
+    multiple of the first: one sort of A's live (block, k-tile) keys at
+    the first height gives them all, a block at height r being ``r /
+    heights[0]`` consecutive blocks of the first.
+
+    >>> h = HostCSR.from_dense(np.ones((32, 32), np.float32))
+    >>> block_r_counts(h, np.ones(2, np.int32), block_k=16, nnb=1,
+    ...                heights=(8, 16))
+    {8: (8, 8, 4), 16: (8, 8, 2)}
+    """
+    base = heights[0]
+    nk = (h.ncols + block_k - 1) // block_k
+    key = ((expand_indptr(h.indptr) // base) * nk
+           + h.indices.astype(np.int64) // block_k)
+    key = np.unique(key[boundary_mask(key)])     # a row's runs first
+    tbl = np.asarray(table).reshape(-1, nnb) > 0
+    per_row = tbl.sum(axis=1)                    # B's live tiles per k-row
+    row_start = np.concatenate([[0], np.cumsum(per_row)[:-1]])
+    tile_j = np.nonzero(tbl)[1]                  # their column tiles
+    out = {}
+    for r in heights:
+        nb = (h.nrows + r - 1) // r
+        coarse = np.unique(key // nk // (r // base) * nk + key % nk)
+        blk, kt = coarse // nk, coarse % nk
+        n = per_row[kt]
+        steps = coarse.size + nb - np.unique(blk).size
+        pairs = int(n.sum()) + nb - np.unique(blk[n > 0]).size
+        # one (block, j) per live pair: B's tile row kt, for each key
+        first = np.repeat(row_start[kt] - (np.cumsum(n) - n), n)
+        j = tile_j[first + np.arange(first.size)]
+        windows = np.unique(np.repeat(blk, n) * nnb + j).size
+        out[r] = (_round_up(max(steps, 1), 8), _round_up(pairs, 8),
+                  windows)
+    return out
+
+
 # ---------------------------------------------------------------------------
 # live-pair compacted grid (the Sp×Sp kernel's sparsity-compacted stream)
 # ---------------------------------------------------------------------------

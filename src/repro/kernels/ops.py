@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.formats import (BCC, BCCShape, CompactedC, HostCSR,
-                                TiledCSR, bcc_layout, compacted_c_counters,
+                                TiledCSR, bcc_layout, block_r_counts,
+                                compacted_c_counters,
                                 compacted_c_from_dense, compacted_c_table,
                                 live_pair_counters, live_pair_stream,
                                 partition_pair_stream, scatter_map,
@@ -43,12 +44,15 @@ __all__ = ["on_tpu", "pallas_shard_count", "stream_chunk", "bcc_spmm",
            "bcc_compact_stream", "bcc_compact_stream_reference",
            "bcc_spmm_compact", "predict_c_window_density",
            "compact_grid_ok_ncols", "bcc_spgemm_tiled",
-           "bcc_spgemm_sparse_c", "SpGEMMPattern", "pack_spgemm_pattern",
+           "bcc_spgemm_sparse_c", "SpGEMMPattern", "select_block_r",
+           "pack_spgemm_pattern",
            "pack_spmm_stream", "flash_mha", "fused_ssd"]
 
-# the serving path's blocking of the Sp×Sp operands: A's row block and
-# B's tile width
-_BLOCK_R, _BN = 8, 128
+# B's tile width on the serving path, and the row-block heights of A that
+# select_block_r tries for a Sp×Sp pattern: the first is the one the
+# route tests and the padded grid use
+_BN = 128
+_BLOCK_R_HEIGHTS = (8, 16, 32, 64, 128)
 
 # VMEM budget for pinning TiledCSR's tile store on-chip (leave headroom for
 # the A slab / C tile double buffers out of the 16 MiB core budget)
@@ -66,6 +70,15 @@ _SMEM_STREAM_BUDGET = 2**19
 # fp32, double-buffered by the pipeline): B matrices wide enough to blow
 # it fall back to the per-tile padded grid, whose C window is one tile
 _COMPACT_C_STRIP_BUDGET = 2 * 2**20
+
+# what a Sp×Sp grid step costs beyond its bytes and flops, in bytes of HBM
+# traffic (select_block_r)
+_STEP_OVERHEAD_BYTES = 192 * 2**10
+
+# row blocks per core the sharded route keeps at the least when it chooses
+# A's row-block height: the partition cuts the stream at block
+# boundaries, so a core with fewer, taller blocks balances worse
+_SHARD_BLOCKS = 8
 
 # predicted C window density (live (blk, j) windows / all windows) at or
 # below which pack_spgemm_pattern routes through the sparse-C output tier:
@@ -244,7 +257,7 @@ def bcc_spmm_compact(a: BCC | BCCShape, b: jax.Array, *, bn: int = 128,
     return out[: a.nrows, : n0]
 
 
-def compact_grid_ok_ncols(ncols: int, *, block_r: int = _BLOCK_R,
+def compact_grid_ok_ncols(ncols: int, *, block_r: int = _BLOCK_R_HEIGHTS[0],
                           bn: int = _BN) -> bool:
     """Whether the live-pair compacted grid applies to a B of ``ncols``
     columns: its C output window is a whole ``(block_r, nnb*bn)`` row
@@ -345,7 +358,7 @@ def _sparse_c_kernel(pattern: "SpGEMMPattern", values: jax.Array,
     values, c_slots, slots, a_idx, table = to_device(
         values, c_slots, slots, a_idx, table)
     with get_tracer().span("kernel_variant", variant="sparse_c",
-                           epilogue="kernel"):
+                           epilogue="kernel", block_r=a.block_r):
         slabs = pattern.kernel(c_slots, slots, a_idx, values, tiled.tiles,
                                block_r=a.block_r, block_k=a.block_k,
                                bn=tiled.bn, nslabs=int(nslabs),
@@ -414,6 +427,7 @@ def bcc_spgemm_tiled(pattern: "SpGEMMPattern", values: jax.Array,
     if p.route == "padded":
         block_ids, tile_ids, values = to_device(*p.stream_ids, values)
         with tracer.span("kernel_variant", variant="padded",
+                         block_r=a.block_r,
                          resident=p.kernel is cluster_spgemm_resident):
             out = p.kernel(block_ids, tile_ids, tiled.table, values,
                            tiled.tiles, **kw)
@@ -422,14 +436,15 @@ def bcc_spgemm_tiled(pattern: "SpGEMMPattern", values: jax.Array,
         ranges, shard_pairs = p.shards
         values, = to_device(values)
         with tracer.span("kernel_variant", variant="sharded",
-                         shards=len(shard_pairs)):
+                         block_r=a.block_r, shards=len(shard_pairs)):
             out = cluster_spgemm_pairs_sharded(
                 shard_pairs, ranges, values, tiled.tiles, kernel=p.kernel,
                 chunk=stream_chunk(4), **kw)
         _note_kernel_launch("sharded", **pair_kw)
     else:
         values, *pairs = to_device(values, *p.pairs)
-        with tracer.span("kernel_variant", variant=p.route):
+        with tracer.span("kernel_variant", variant=p.route,
+                         block_r=a.block_r):
             out = p.kernel(*pairs, values, tiled.tiles,
                            chunk=stream_chunk(4), **kw)
         _note_kernel_launch(p.route, **pair_kw)
@@ -567,24 +582,91 @@ def _value_map(pos: np.ndarray, src: np.ndarray | None, size: int) -> tuple:
     return take.astype(np.int32), dst.astype(np.int32)
 
 
+def select_block_r(counts: dict, *, route: str, nrows: int, block_k: int,
+                   nnb: int, bn: int = _BN, b_itemsize: int = 4,
+                   shards: int = 1) -> int:
+    """Row-block height of the Sp×Sp operands: the height of ``counts``
+    (:func:`repro.core.formats.block_r_counts`) at which a launch on
+    ``route`` costs least, its bytes, its MXU time and its grid steps all
+    counted in bytes of HBM traffic.
+
+    A taller block contracts each B tile it fetches against more rows of
+    A, so a pattern whose row blocks share k-tiles runs fewer pairs and
+    streams fewer B tiles. A pattern whose rows share none buys only
+    zero-filled A slabs. The bytes of a launch at height ``r``, and its
+    refill, as the kernels move them:
+
+    * A's slabs, ``steps·r·block_k·4``: written by the refill, read by the
+      kernel once per stream step on the dense-strip routes (a step's
+      pairs are adjacent) and once per pair on ``sparse_c`` (its stream
+      is window-major);
+    * B's tiles, ``pairs·block_k·bn·b_itemsize``: one DMA per pair;
+    * C: the dense row strips, ``nblocks·r·nnb·bn·4``, or on ``sparse_c``
+      the live windows, ``windows·r·bn·4``;
+    * the MXU's time, counted as the bytes moved in that time: a pair's
+      ``r·block_k·bn·2`` flops run as six bf16 passes at ``HIGHEST``
+      precision, at 197 TFLOP/s against 819 GB/s on a TPU v5e, are
+      ``r·block_k·bn/20`` bytes;
+    * ``_STEP_OVERHEAD_BYTES`` per pair, 192 KiB: 0.24 µs at 819 GB/s,
+      what a step costs beyond its bytes and flops (on a v5e the
+      sparse-C kernel ran 1,227,952 steps of height 8 in 0.402 s,
+      0.33 µs each, with 68 KiB of A and B each).
+
+    The padded route keeps the first height. The dense-strip routes keep
+    the C row strip ``r·nnb·bn·4`` within the strip budget
+    (:func:`compact_grid_ok_ncols`); the ``(r, bn)`` output window of
+    ``sparse_c`` does not bind. The ``sharded`` route also keeps
+    ``_SHARD_BLOCKS`` row blocks for each of its ``shards`` cores. Ties
+    keep the lower height.
+
+    >>> from repro.core.formats import block_r_counts, tiled_layout
+    >>> h = HostCSR.from_dense(np.ones((512, 512), np.float32))
+    >>> counts = block_r_counts(h, tiled_layout(h, 128, 128)[0],
+    ...                         block_k=128, nnb=4)
+    >>> [select_block_r(counts, route=r, nrows=512, block_k=128, nnb=4)
+    ...  for r in ("padded", "streamed", "sparse_c")]
+    [8, 128, 128]
+    """
+    sparse = route == "sparse_c"
+    best_r, best = None, None
+    for r, (steps, pairs, windows) in sorted(counts.items()):
+        if best_r is not None and (
+                route == "padded"
+                or not sparse and not compact_grid_ok_ncols(
+                    nnb * bn, block_r=r, bn=bn)
+                or route == "sharded"
+                and (nrows + r - 1) // r < _SHARD_BLOCKS * shards):
+            break
+        a_slab = r * block_k * 4
+        pair = (block_k * bn * b_itemsize + r * block_k * bn // 20
+                + _STEP_OVERHEAD_BYTES + (a_slab if sparse else 0))
+        c_rows = windows * bn if sparse else (nrows + r - 1) // r * nnb * bn
+        score = (steps * a_slab * (1 if sparse else 2) + pairs * pair
+                 + c_rows * r * 4)
+        if best is None or score < best:
+            best_r, best = r, score
+    return int(best_r)
+
+
 def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
                         a_src: np.ndarray | None = None,
                         b_src: np.ndarray | None = None,
                         b_dtype=jnp.float32,
                         sparse_out: bool = False) -> SpGEMMPattern:
     """Pack ``ap @ bh`` for the Sp×Sp kernels from the patterns alone, and
-    choose the route that launches it.
+    choose the route that launches it and A's row-block height.
 
     ``a_src``/``b_src`` map each nonzero of ``ap``/``bh`` to the nonzero
     of the operand as sent (``HostCSR.permuted``'s ``src``; ``None``: the
     same order), so :meth:`SpGEMMPattern.fill` takes the sent ``data``.
     Nothing is read back from the device: the stream ids, the live pairs
     and the shard partition come from the host layouts
-    (:func:`bcc_layout`, :func:`tiled_layout`), at the serving path's
-    blocking: ``block_r`` 8 and ``bn`` 128.
+    (:func:`bcc_layout`, :func:`tiled_layout`), with B's tiles ``bn``
+    128 wide.
 
     The route (:class:`SpGEMMPattern`) is chosen here, once, from what
-    the patterns and the platform show:
+    the patterns and the platform show, at the first height of
+    ``_BLOCK_R_HEIGHTS``:
 
     * B's width against the C row-strip budget
       (:func:`compact_grid_ok_ncols`): the live-pair grid, or the padded
@@ -593,30 +675,59 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
     * B's tile store against ``_RESIDENT_B_BUDGET``: pinned in VMEM, or
       streamed — behind the two-slot prefetch on a TPU;
     * :func:`pallas_shard_count`: sharded over the cores;
-    * C's predicted window density (:func:`predict_c_window_density`)
-      against ``_SPARSE_C_DENSITY``, unsharded: the sparse-C grid.
+    * C's window density, the live windows over all of them, against
+      ``_SPARSE_C_DENSITY``, unsharded: the sparse-C grid.
+
+    Then :func:`select_block_r` chooses the row-block height for that
+    route from the stream lengths every height would give
+    (:func:`repro.core.formats.block_r_counts`), and the pattern is
+    packed at it.
 
     ``sparse_out=True`` packs for :meth:`SpGEMMPattern.run_sparse`: the
     sparse-C route whatever C's predicted density, unsharded. B must then
     be narrow enough for the compacted grid.
     """
-    block_r, bn = _BLOCK_R, _BN
-    stream_ids, ntiles, a_pos, values_shape = _compact_layout(
-        ap, block_r, block_k)
+    bn = _BN
     table, tile_cap, b_pos = tiled_layout(bh, block_k, bn)
     tiles_shape = (tile_cap, block_k, bn)
-    a_map = _value_map(a_pos, a_src, math.prod(values_shape))
-    b_map = _value_map(b_pos, b_src, math.prod(tiles_shape))
-    nblocks = ntiles.shape[0]
     nnb = (bh.ncols + bn - 1) // bn
-    double_buffer = on_tpu()
+    counts = block_r_counts(ap, table, block_k=block_k, nnb=nnb,
+                            heights=_BLOCK_R_HEIGHTS)
+    base = _BLOCK_R_HEIGHTS[0]
     resident = (math.prod(tiles_shape) * jnp.dtype(b_dtype).itemsize
                 <= _RESIDENT_B_BUDGET)
-    pairs = shards = sparse_pairs = None
-    if not compact_grid_ok_ncols(nnb * bn, block_r=block_r, bn=bn):
+    n_shards = 1 if sparse_out else pallas_shard_count()
+    if not compact_grid_ok_ncols(nnb * bn, block_r=base, bn=bn):
         if sparse_out:
             raise ValueError(f"B of {bh.ncols} columns is too wide for the "
                              "sparse-C output tier")
+        route = "padded"
+        kernel = cluster_spgemm_resident if resident else cluster_spgemm_tiled
+    elif n_shards > 1:
+        route, kernel = "sharded", (cluster_spgemm_pairs_resident if resident
+                                    else cluster_spgemm_pairs_db if on_tpu()
+                                    else cluster_spgemm_pairs)
+    elif sparse_out or counts[base][2] <= _SPARSE_C_DENSITY * nnb * (
+            (ap.nrows + base - 1) // base):
+        route, kernel = "sparse_c", (cluster_spgemm_pairs_sparse_db
+                                     if on_tpu()
+                                     else cluster_spgemm_pairs_sparse)
+    else:
+        route, kernel = (
+            ("resident", cluster_spgemm_pairs_resident) if resident
+            else ("streamed_db", cluster_spgemm_pairs_db) if on_tpu()
+            else ("streamed", cluster_spgemm_pairs))
+    block_r = select_block_r(counts, route=route, nrows=ap.nrows,
+                             block_k=block_k, nnb=nnb, bn=bn,
+                             b_itemsize=jnp.dtype(b_dtype).itemsize,
+                             shards=n_shards)
+    stream_ids, ntiles, a_pos, values_shape = _compact_layout(
+        ap, block_r, block_k)
+    a_map = _value_map(a_pos, a_src, math.prod(values_shape))
+    b_map = _value_map(b_pos, b_src, math.prod(tiles_shape))
+    nblocks = ntiles.shape[0]
+    pairs = shards = sparse_pairs = None
+    if route == "padded":
         # the padded grid prefetches its whole stream and B's whole table
         prefetch_bytes = 4 * (2 * len(stream_ids[0]) + table.size)
         if prefetch_bytes > _SMEM_STREAM_BUDGET:
@@ -625,30 +736,20 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
                 f"its stream and B's tile table, over the "
                 f"{_SMEM_STREAM_BUDGET} B budget (C row strip of "
                 f"{nnb * bn} columns is too wide for the compacted grid)")
-        route = "padded"
-        kernel = cluster_spgemm_resident if resident else cluster_spgemm_tiled
     else:
         host_pairs = _live_pairs(stream_ids, ntiles, table, nnb=nnb,
                                  nblocks=nblocks)
-        route, kernel = (
-            ("resident", cluster_spgemm_pairs_resident) if resident
-            else ("streamed_db", cluster_spgemm_pairs_db) if double_buffer
-            else ("streamed", cluster_spgemm_pairs))
-        n_shards = 1 if sparse_out else pallas_shard_count()
-        if n_shards > 1:
+        if route == "sharded":
             ranges, shard_pairs = partition_pair_stream(
                 host_pairs, nblocks=nblocks, num_shards=n_shards)
-            route = "sharded"
             shards = (ranges, [to_device(*p) for p in shard_pairs])
-        elif sparse_out or predict_c_window_density(
-                host_pairs, nblocks=nblocks, nnb=nnb) <= _SPARSE_C_DENSITY:
-            route = "sparse_c"
-            kernel = (cluster_spgemm_pairs_sparse_db if double_buffer
-                      else cluster_spgemm_pairs_sparse)
+        elif route == "sparse_c":
             *streams, nslabs = _sparse_c_pairs(host_pairs, nblocks=nblocks,
                                                nnb=nnb)
             sparse_pairs = (*to_device(*streams), nslabs)
         pairs = to_device(*host_pairs)
+    obs_metrics.get_registry().counter("sxs_block_r", route=route,
+                                       block_r=str(block_r)).inc()
     table, *ids = to_device(table, *stream_ids)
     return SpGEMMPattern(
         a=BCCShape(ap.nrows, ap.ncols, block_r, block_k),

@@ -76,6 +76,9 @@ _HOST_METRICS: dict[str, tuple[str, str]] = {
     "kernel_launches": (
         "counter", "Pallas Sp×Sp and SpMM kernel dispatches, by variant "
         "label (count)"),
+    "sxs_block_r": (
+        "counter", "Sp×Sp pattern packs, by route and the row-block height "
+        "chosen for the pattern, block_r label (count)"),
     "chain_hops": (
         "counter", "chain-workload hops executed (count)"),
     "chain_carry_bytes": (

@@ -4,7 +4,8 @@ SMEM and VMEM over-use, unaligned slices. Each kernel compiles at its
 SMEM chunk length, over two chunks so the chunk loop is in the program,
 at the widths of a Graph500 scale-14 operand (n = 16384: 2048 row
 blocks, 128 column tiles) and every ``block_k`` of ``select_block_k``'s
-menu, with fp32 and bf16 B.
+menu, with fp32 and bf16 B, and the Sp×Sp kernels again at row blocks
+taller than 8.
 
 The topology is described inside a module fixture, never at import: only
 the test worker that runs this file loads the TPU compiler.
@@ -141,6 +142,44 @@ def test_sparse_c_kernel_compiles(one_chip, block_k, dtype):
         return cluster_spgemm_pairs_sparse_db(
             c_slots, slots, a_idx, a, b, block_r=BLOCK_R, block_k=block_k,
             bn=BN, nslabs=NBLOCKS * NNB, chunk=ops.stream_chunk(3))
+    _compile(fn, *_streams(3, 3, one_chip), a, b)
+
+
+# row blocks taller than 8 (``ops.select_block_r``): the dense-strip
+# kernels at a C row strip of the whole strip budget, 4,096 columns at
+# height 128 as in the revalue graph's A·A, and the sparse-C kernel at
+# the heights the R·A·P hops can take
+@pytest.mark.parametrize("kernel, block_r, block_k", [
+    (cluster_spgemm_pairs_db, 128, 512),
+    (cluster_spgemm_pairs_db, 64, 512),
+    (cluster_spgemm_pairs_resident, 128, 512)])
+def test_dense_pair_kernels_compile_tall_blocks(one_chip, kernel, block_r,
+                                                block_k):
+    nnb = ops._COMPACT_C_STRIP_BUDGET // (block_r * BN * 4)
+    assert ops.compact_grid_ok_ncols(nnb * BN, block_r=block_r)
+    if kernel is cluster_spgemm_pairs_resident:   # B at the VMEM budget
+        cap = ops._RESIDENT_B_BUDGET // (block_k * BN * 4)
+    else:
+        cap = TILE_CAP
+    a = _sds((256, block_r, block_k), jnp.float32, one_chip)
+    b = _sds((cap, block_k, BN), jnp.float32, one_chip)
+
+    def fn(blocks, js, slots, a_idx, a, b):
+        return kernel(blocks, js, slots, a_idx, a, b, block_r=block_r,
+                      block_k=block_k, bn=BN, nblocks=32, nnb=nnb,
+                      chunk=ops.stream_chunk(4))
+    _compile(fn, *_streams(4, 4, one_chip), a, b)
+
+
+@pytest.mark.parametrize("block_r", [32, 64, 128])
+def test_sparse_c_kernel_compiles_tall_windows(one_chip, block_r):
+    a = _sds((N_SLABS, block_r, 128), jnp.float32, one_chip)
+    b = _sds((TILE_CAP, 128, BN), jnp.float32, one_chip)
+
+    def fn(c_slots, slots, a_idx, a, b):
+        return cluster_spgemm_pairs_sparse_db(
+            c_slots, slots, a_idx, a, b, block_r=block_r, block_k=128,
+            bn=BN, nslabs=N_SLABS, chunk=ops.stream_chunk(3))
     _compile(fn, *_streams(3, 3, one_chip), a, b)
 
 

@@ -1,7 +1,8 @@
 """Pair streams launched in SMEM-sized chunks (interpret mode): every
 chunked kernel is bit-identical to its one-launch stream, including
 windows whose steps straddle a chunk boundary, a stream exactly one
-chunk long and an empty stream."""
+chunk long, an empty stream and row blocks 16 and 32 high. The packs
+keep row blocks 8 high unless a test says otherwise."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,7 +19,7 @@ from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs,
 from repro.kernels.cluster_spmm import cluster_spmm_compact
 from repro.core.formats import partition_pair_stream
 
-from _packing import pack, product
+from _packing import grouped, pack, product, scipy_product
 
 pytestmark = pytest.mark.pallas
 
@@ -63,6 +64,31 @@ def test_dense_pairs_chunked_bitwise(packed, kernel, chunk):
     whole = np.asarray(kernel(*args, **kw))
     got = np.asarray(kernel(*args, chunk=chunk, **kw))
     np.testing.assert_array_equal(got, whole)
+
+
+@pytest.mark.parametrize("g", [16, 32])
+@pytest.mark.parametrize("kernel", [cluster_spgemm_pairs,
+                                    cluster_spgemm_pairs_db])
+def test_dense_pairs_chunked_at_a_selected_height(kernel, g):
+    """A pattern whose rows come in groups of ``g`` packs at row blocks
+    ``g`` high (``ops.select_block_r``): its dense-strip stream, chunked
+    so a strip straddles a boundary, is bit-identical to one launch and
+    matches float64 scipy."""
+    a, b = grouped(256, g, 16, g), rand_host(256, 48, 0.3, g + 1)
+    pattern = pack(a, b, block_k=16, bn=16, block_r=None,
+                   _SPARSE_C_DENSITY=-1.0)
+    assert pattern.a.block_r == g
+    values, tiled = pattern.fill(a.data, b.data)
+    pairs = pattern.pairs
+    assert _straddles(pairs[0], 16)
+    args = (*pairs, values, tiled.tiles)
+    kw = dict(block_r=g, block_k=16, bn=16, nblocks=256 // g,
+              nnb=tiled.nnb, interpret=True)
+    whole = np.asarray(kernel(*args, **kw))
+    got = np.asarray(kernel(*args, chunk=16, **kw))
+    np.testing.assert_array_equal(got, whole)
+    np.testing.assert_allclose(got, scipy_product(a, b), rtol=1e-5,
+                               atol=1e-5)
 
 
 @pytest.mark.parametrize("short", [0, 8])

@@ -8,8 +8,9 @@ Covers:
     (``bcc_from_host`` → ``bcc_compact_stream``, ``tiled_csr_from_host``),
     and its product is the launch on the full pack's arrays, over value
     sets that change and come back, a permuted plan, an A·B pair, empty
-    row blocks with tail padding, a repeated (row, col) entry and bf16 B
-    tiles;
+    row blocks with tail padding, a repeated (row, col) entry, bf16 B
+    tiles and patterns packed at row blocks 16 and 32 high, each refill
+    of those also against float64 scipy;
   * the route is chosen once, at the pack, from what the pattern shows:
     one case per route the CPU can take, each against
     ``spgemm_reference``;
@@ -35,6 +36,8 @@ from repro.planner import Planner
 from repro.planner.features import fingerprint
 from repro.planner.plan_cache import Plan
 from repro.resilience.policy import ResiliencePolicy
+
+from _packing import grouped, merge, scipy_product
 
 pytestmark = pytest.mark.pallas
 
@@ -96,15 +99,22 @@ def _case(name):
         return _with_repeat(), None, None, f32
     if name == "bf16":
         return _random(64, 64, 0.2, 7), None, None, jnp.bfloat16
+    if name in HEIGHTS:
+        g = HEIGHTS[name]
+        return grouped(2048, g, 128, g), merge(2048, 128, g), None, f32
     raise KeyError(name)
 
 
-CASES = ("values", "perm", "ab", "empty_blocks", "repeat", "bf16")
+# cases whose patterns select row blocks taller than 8, and the height
+HEIGHTS = {"height16": 16, "height32": 32}
+CASES = ("values", "perm", "ab", "empty_blocks", "repeat", "bf16",
+         *HEIGHTS)
 
 
-def _full_pack(a, b, perm, dtype):
+def _full_pack(a, b, perm, dtype, block_r):
     """The operands packed whole on the host, as a full pack does: A's
-    compact stream slabs, read back from its BCC, and B's TiledCSR."""
+    compact stream slabs of ``block_r`` rows, read back from its BCC, and
+    B's TiledCSR."""
     if perm is None:
         ap = a
     elif b is None:
@@ -113,8 +123,9 @@ def _full_pack(a, b, perm, dtype):
         ap = a.permute_rows(perm)
     bh = ap if b is None else b
     bk = select_block_k(bh)
-    values = ops.bcc_compact_stream(bcc_from_host(ap, block_k=bk),
-                                    cover_all_blocks=True)[2]
+    values = ops.bcc_compact_stream(
+        bcc_from_host(ap, block_r=block_r, block_k=bk),
+        cover_all_blocks=True)[2]
     return values, tiled_csr_from_host(bh, block_k=bk, dtype=dtype)
 
 
@@ -164,7 +175,8 @@ def test_refilled_product_is_bit_identical_to_a_full_pack(case):
         bi = None if b is None else _revalued(b, seed + 10)
         got = planner.execute(plan, ai, bi)
         (_, pattern, (_, values, tiled)), = planner._exec_cache.values()
-        full_values, full_tiled = _full_pack(ai, bi, perm, dtype)
+        full_values, full_tiled = _full_pack(ai, bi, perm, dtype,
+                                             pattern.a.block_r)
         np.testing.assert_array_equal(np.asarray(values), full_values)
         np.testing.assert_array_equal(np.asarray(tiled.tiles),
                                       np.asarray(full_tiled.tiles))
@@ -175,6 +187,24 @@ def test_refilled_product_is_bit_identical_to_a_full_pack(case):
         np.testing.assert_array_equal(
             got, _unpermuted(np.asarray(full), bi, perm))
     assert len(planner._exec_cache) == 1
+
+
+@pytest.mark.parametrize("case", list(HEIGHTS))
+def test_refill_at_a_selected_height_matches_float64_scipy(case):
+    """The pattern packs at the height its row groups select, and every
+    refill of it, new values or old ones again, is scipy's product."""
+    get_registry().reset()
+    a, b, _, _ = _case(case)
+    planner = _planner()
+    plan = _plan(a)
+    for seed in (0, 1, 0):
+        ai, bi = _revalued(a, seed), _revalued(b, seed + 10)
+        got = planner.execute(plan, ai, bi)
+        (_, pattern, _), = planner._exec_cache.values()
+        assert pattern.a.block_r == HEIGHTS[case]
+        np.testing.assert_allclose(got, scipy_product(ai, bi), rtol=1e-5,
+                                   atol=1e-6)
+    assert _count("exec_cache_refills") == 2
 
 
 def test_empty_blocks_case_has_cover_steps_tail_padding_and_sparse_c():

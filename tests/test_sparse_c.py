@@ -2,9 +2,10 @@
 upper bound (vectorized vs loop reference, domination over exact per-row
 nnz(C), tightness on disjoint-column constructions), the ``CompactedC``
 round trip (bit-identical to ``spgemm_reference`` for both sparse-C
-kernel variants on integer-valued operands), the density routing of
-``ops.pack_spgemm_pattern``, and the ``workload="chain"`` planner path
-(A³ end-to-end with per-hop plan-cache hits on the second call).
+kernel variants on integer-valued operands, at row blocks 8, 16 and 32
+high), the density routing of ``ops.pack_spgemm_pattern``, and the
+``workload="chain"`` planner path (A³ end-to-end with per-hop plan-cache
+hits on the second call; two hops at the heights their patterns select).
 """
 import dataclasses
 
@@ -27,7 +28,7 @@ from repro.kernels import ops
 from repro.kernels.cluster_spgemm import (cluster_spgemm_pairs_sparse,
                                           cluster_spgemm_pairs_sparse_db)
 
-from _packing import pack
+from _packing import grouped, merge, pack, scipy_product
 
 BR, BK, BN = 8, 16, 16
 
@@ -154,6 +155,26 @@ def test_sparse_c_kernel_bit_identical(double_buffer, density):
                               values, tiled)
     got = compacted_c_to_host(cc).to_dense()
     np.testing.assert_array_equal(got, spgemm_reference(a, a))
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("double_buffer", [False, True])
+@pytest.mark.parametrize("g", [16, 32])
+def test_sparse_c_kernel_at_a_selected_height(g, double_buffer):
+    """A pattern whose rows come in groups of ``g`` packs its sparse-C
+    stream at ``(g, bn)`` windows; the kernel's slabs are float64 scipy's
+    product, bit for bit on integer values."""
+    a, b = grouped(256, g, BK, g, integer=True), int_host(256, 48, 0.3, g)
+    pattern = pack(a, b, block_k=BK, bn=BN, block_r=None, sparse_out=True)
+    assert pattern.route == "sparse_c" and pattern.a.block_r == g
+    values, tiled = pattern.fill(a.data, b.data)
+    kernel = (cluster_spgemm_pairs_sparse_db if double_buffer
+              else cluster_spgemm_pairs_sparse)
+    cc = ops._sparse_c_kernel(dataclasses.replace(pattern, kernel=kernel),
+                              values, tiled)
+    assert cc.block_r == g
+    np.testing.assert_array_equal(compacted_c_to_host(cc).to_dense(),
+                                  scipy_product(a, b))
 
 
 @pytest.mark.pallas
@@ -310,6 +331,34 @@ def test_chain_sparse_hop_forced_pallas_bit_identical():
     assert any(v[0] == "chain" for v in p._exec_cache.values())
     h1b = p._chain_hop(plan1, a, None)
     np.testing.assert_array_equal(h1b.to_dense(), ref @ ref)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("g", [16, 32])
+def test_chain_two_hops_at_selected_heights(g, monkeypatch):
+    """``A·(A·M)`` on Pallas hops, both through the sparse-C tier: hop 1
+    packs at height ``g``, hop 2 at the height its intermediate selects,
+    the intermediate stays on the device (``_DeviceCSR``) until the
+    carry, and the product is float64 scipy's, bit for bit."""
+    from repro.planner import service
+    from repro.planner.cost_model import Candidate
+    from repro.planner.plan_cache import PlanCache
+    from repro.planner.service import Planner
+    monkeypatch.setattr(service, "_CHAIN_DENSE_C_BUDGET", 0)
+    a = grouped(2048, g, 128, g, integer=True)
+    m = merge(2048, 128, g)
+    p = Planner(cache=PlanCache(),
+                candidates=(Candidate("original", "pallas"),))
+    carried, to_host = [], service._DeviceCSR.to_host
+    monkeypatch.setattr(service._DeviceCSR, "to_host", lambda self: (
+        carried.append(self.shape) or to_host(self)))
+    c, plans = p.execute_chain(a, (a, m))
+    assert [pl.scheme for pl in plans] == ["pallas", "pallas"]
+    heights = [v[1].a.block_r for v in p._exec_cache.values()
+               if v[0] == "chain"]
+    assert len(heights) == 2 and heights[0] == g and min(heights) > 8
+    assert carried == [(2048, 1024), (2048, 1024)]
+    np.testing.assert_array_equal(c.to_dense(), scipy_product(a, a, m))
 
 
 def test_engine_chain_requests():
