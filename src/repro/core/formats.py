@@ -30,6 +30,7 @@ from repro.core.segment import (boundary_mask, expand_indptr, key_table,
 
 __all__ = [
     "HostCSR",
+    "Masked",
     "BlockDiagPack",
     "block_diag_csr",
     "block_diag_csr_reference",
@@ -62,6 +63,8 @@ __all__ = [
     "symbolic_strip_nnz",
     "symbolic_strip_nnz_reference",
     "compacted_c_table",
+    "compacted_c_positions",
+    "mask_windows",
     "compacted_c_from_dense",
     "compacted_c_to_host",
     "compacted_c_counters",
@@ -241,6 +244,20 @@ class HostCSR:
         return (self.indptr.size * ptr_bytes
                 + self.indices.size * index_bytes
                 + self.data.size * value_bytes)
+
+
+@dataclasses.dataclass(frozen=True)
+class Masked:
+    """The right operand of a masked product request: ``submit(a,
+    Masked(b, mask))`` asks for ``(a · b) ∘ mask``, the values of the
+    product at the nonzeros of ``mask`` times ``mask``'s values, and
+    nothing else of it. ``b=None`` is the square ``a · a``. ``mask`` is a
+    :class:`HostCSR` of the product's shape; the answer is a
+    :class:`HostCSR` on exactly its pattern, with explicit zeros where
+    no product term lands."""
+
+    b: "HostCSR | None"
+    mask: HostCSR
 
 
 # ---------------------------------------------------------------------------
@@ -1015,8 +1032,9 @@ def select_block_k(h: HostCSR, *, bn: int = 128,
 
 
 def block_r_counts(h: HostCSR, table, *, block_k: int, nnb: int,
-                   heights: Sequence[int] = (8, 16, 32, 64, 128)
-                   ) -> dict[int, tuple[int, int, int]]:
+                   heights: Sequence[int] = (8, 16, 32, 64, 128),
+                   mask: HostCSR | None = None, bn: int = 128
+                   ) -> dict[int, tuple[int, ...]]:
     """The stream lengths a pack of ``h @ B`` would build at each row-block
     height of ``heights``, counted without building one: ``{r: (steps,
     pairs, windows)}``.
@@ -1029,6 +1047,14 @@ def block_r_counts(h: HostCSR, table, *, block_k: int, nnb: int,
     multiple of the first: one sort of A's live (block, k-tile) keys at
     the first height gives them all, a block at height r being ``r /
     heights[0]`` consecutive blocks of the first.
+
+    With a ``mask`` (the product's shape, B's tiles ``bn`` wide), the
+    counts are those of the masked stream: only the pairs whose ``(r,
+    bn)`` window of C holds a nonzero of the mask
+    (:func:`mask_windows`, the ``window_live`` of
+    :func:`live_pair_stream`), and the windows they reach; each entry
+    then ends with the unmasked stream's ``pairs`` at that height:
+    ``{r: (steps, pairs, windows, pairs_unmasked)}``.
 
     >>> h = HostCSR.from_dense(np.ones((32, 32), np.float32))
     >>> block_r_counts(h, np.ones(2, np.int32), block_k=16, nnb=1,
@@ -1055,10 +1081,29 @@ def block_r_counts(h: HostCSR, table, *, block_k: int, nnb: int,
         # one (block, j) per live pair: B's tile row kt, for each key
         first = np.repeat(row_start[kt] - (np.cumsum(n) - n), n)
         j = tile_j[first + np.arange(first.size)]
-        windows = np.unique(np.repeat(blk, n) * nnb + j).size
+        wkey = np.repeat(blk, n) * nnb + j
+        unmasked = ()
+        if mask is not None:
+            unmasked = (_round_up(pairs, 8),)
+            wkey = wkey[mask_windows(mask, block_r=r, bn=bn, nblocks=nb,
+                                     nnb=nnb)[wkey]]
+            pairs = wkey.size + nb - np.unique(wkey // nnb).size
+        windows = np.unique(wkey).size
         out[r] = (_round_up(max(steps, 1), 8), _round_up(pairs, 8),
-                  windows)
+                  windows, *unmasked)
     return out
+
+
+def mask_windows(mask: HostCSR, *, block_r: int, bn: int, nblocks: int,
+                 nnb: int) -> np.ndarray:
+    """``(nblocks * nnb,)`` bool: the ``(block_r, bn)`` windows of C that
+    hold a nonzero of ``mask``, window ``blk * nnb + j``. A masked
+    product computes only these (``live_pair_stream``'s
+    ``window_live``)."""
+    live = np.zeros(nblocks * nnb, dtype=bool)
+    live[expand_indptr(mask.indptr) // block_r * nnb
+         + mask.indices.astype(np.int64) // bn] = True
+    return live
 
 
 # ---------------------------------------------------------------------------
@@ -1067,7 +1112,7 @@ def block_r_counts(h: HostCSR, table, *, block_k: int, nnb: int,
 
 
 def live_pair_stream(block_ids, tile_ids, table, *, nnb: int, nblocks: int,
-                     step_live=None, pad_to: int = 8
+                     step_live=None, window_live=None, pad_to: int = 8
                      ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
                                 np.ndarray]:
     """Intersect A's compact (block, k-tile) stream with B's tile table.
@@ -1097,6 +1142,10 @@ def live_pair_stream(block_ids, tile_ids, table, *, nnb: int, nblocks: int,
       step_live: optional (S,) bool — False marks synthetic stream steps
         (``cover_all_blocks`` zero slabs, tail padding) whose pairs would
         multiply a zero A slab; they are dropped from the pair stream.
+      window_live: optional (nblocks * nnb,) bool — False marks C windows
+        ``(block, j)`` that no one reads (a masked product's windows
+        with no nonzero of the mask, :func:`mask_windows`); their pairs
+        are dropped too, and a block left with none gets its sentinel.
 
     Returns ``(blocks, js, slots, a_idx)`` int32 arrays of equal length:
     output strip, column strip, B tile slot (0 = no MXU issue) and A
@@ -1110,6 +1159,8 @@ def live_pair_stream(block_ids, tile_ids, table, *, nnb: int, nblocks: int,
         step_live = np.ones(s_total, dtype=bool)
     step_live = np.asarray(step_live, dtype=bool)
     tbl = table.reshape(-1, nnb)
+    wins = (None if window_live is None
+            else np.asarray(window_live, dtype=bool).reshape(nblocks, nnb))
     # chunked intersection: the dense (S, nnb) expansion is exactly the
     # padded-grid footprint this builder exists to kill — bound the
     # transient to ~16 MiB of int32 per chunk, concatenating only the
@@ -1120,6 +1171,8 @@ def live_pair_stream(block_ids, tile_ids, table, *, nnb: int, nblocks: int,
         hi = min(lo + chunk, s_total)
         slots_c = tbl[tile_ids[lo:hi]]                    # (chunk, nnb)
         live_c = (slots_c > 0) & step_live[lo:hi, None]
+        if wins is not None:
+            live_c &= wins[block_ids[lo:hi]]
         sc, jc = np.nonzero(live_c)      # row-major: (s, j) ascending
         s_parts.append(sc + lo)
         j_parts.append(jc)
@@ -1160,7 +1213,8 @@ def live_pair_stream(block_ids, tile_ids, table, *, nnb: int, nblocks: int,
 
 
 def live_pair_stream_reference(block_ids, tile_ids, table, *, nnb: int,
-                               nblocks: int, step_live=None, pad_to: int = 8
+                               nblocks: int, step_live=None,
+                               window_live=None, pad_to: int = 8
                                ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
                                           np.ndarray]:
     """Loop reference for :func:`live_pair_stream` (test oracle)."""
@@ -1177,6 +1231,9 @@ def live_pair_stream_reference(block_ids, tile_ids, table, *, nnb: int,
             continue
         for j in range(nnb):
             slot = int(table[int(tile_ids[s]) * nnb + j])
+            if window_live is not None and not window_live[
+                    int(block_ids[s]) * nnb + j]:
+                continue
             if slot > 0:
                 blocks.append(int(block_ids[s]))
                 js.append(j)
@@ -1524,6 +1581,26 @@ def compacted_c_table(pairs, *, nblocks: int, nnb: int
     key = blocks[live].astype(np.int64) * nnb + js[live].astype(np.int64)
     ukey = np.unique(key)
     return key_table(ukey, nblocks * nnb, base=1), int(ukey.size)
+
+
+def compacted_c_positions(table, rows, cols, *, block_r: int, bn: int,
+                          nnb: int) -> np.ndarray:
+    """Where C's entry ``(rows[i], cols[i])`` sits in the flattened
+    :class:`CompactedC` slabs of ``table``; an entry of a dead window
+    reads the reserved zero slab. int64.
+
+    >>> table, _ = compacted_c_table(([0], [1], [3], [0]), nblocks=1,
+    ...                              nnb=2)
+    >>> compacted_c_positions(table, np.array([2, 2]), np.array([5, 1]),
+    ...                       block_r=4, bn=4, nnb=2).tolist()
+    [25, 0]
+    """
+    rows = np.asarray(rows, dtype=np.int64)
+    cols = np.asarray(cols, dtype=np.int64)
+    slab = np.asarray(table, dtype=np.int64)[rows // block_r * nnb
+                                             + cols // bn]
+    return np.where(slab > 0, (slab * block_r + rows % block_r) * bn
+                    + cols % bn, 0)
 
 
 def compacted_c_from_dense(dense, table, *, nrows: int, ncols: int,

@@ -19,11 +19,13 @@ import numpy as np
 from repro.core.formats import (BCC, BCCShape, CompactedC, HostCSR,
                                 TiledCSR, bcc_layout, block_r_counts,
                                 compacted_c_counters,
-                                compacted_c_from_dense, compacted_c_table,
+                                compacted_c_from_dense,
+                                compacted_c_positions, compacted_c_table,
                                 live_pair_counters, live_pair_stream,
+                                mask_windows,
                                 partition_pair_stream, scatter_map,
                                 tiled_layout)
-from repro.core.segment import rank_in_segment
+from repro.core.segment import expand_indptr, rank_in_segment
 from repro.core.transfer import to_device, to_host
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import get_tracer
@@ -45,7 +47,7 @@ __all__ = ["on_tpu", "pallas_shard_count", "stream_chunk", "bcc_spmm",
            "bcc_spmm_compact", "predict_c_window_density",
            "compact_grid_ok_ncols", "bcc_spgemm_tiled",
            "bcc_spgemm_sparse_c", "SpGEMMPattern", "select_block_r",
-           "pack_spgemm_pattern",
+           "pack_spgemm_pattern", "int32_positions",
            "pack_spmm_stream", "flash_mha", "fused_ssd"]
 
 # B's tile width on the serving path, and the row-block heights of A that
@@ -270,7 +272,7 @@ def compact_grid_ok_ncols(ncols: int, *, block_r: int = _BLOCK_R_HEIGHTS[0],
 
 
 def _live_pairs(stream, ntiles: np.ndarray, table: np.ndarray, *, nnb: int,
-                nblocks: int) -> tuple:
+                nblocks: int, window_live: np.ndarray | None = None) -> tuple:
     """Intersect A's compact stream with B's tile ``table`` into the
     live-pair compacted grid (the pair kernels' input), on the host.
 
@@ -278,13 +280,15 @@ def _live_pairs(stream, ntiles: np.ndarray, table: np.ndarray, *, nnb: int,
     empty blocks (``ntiles`` is A's live tile count per block) and the
     tail padding — are masked out of the pair expansion (their slabs are
     all-zero; the pair grid re-covers their blocks with its own
-    zero-slot sentinels).
+    zero-slot sentinels). ``window_live`` keeps only the pairs of those
+    C windows (a masked product's).
     """
     block_ids, tile_ids = np.asarray(stream[0]), np.asarray(stream[1])
     step_live = rank_in_segment(block_ids.astype(np.int64)) \
         < ntiles[block_ids]
     return live_pair_stream(block_ids, tile_ids, table, nnb=nnb,
-                            nblocks=nblocks, step_live=step_live)
+                            nblocks=nblocks, step_live=step_live,
+                            window_live=window_live)
 
 
 def predict_c_window_density(pairs, *, nblocks: int, nnb: int) -> float:
@@ -358,7 +362,8 @@ def _sparse_c_kernel(pattern: "SpGEMMPattern", values: jax.Array,
     values, c_slots, slots, a_idx, table = to_device(
         values, c_slots, slots, a_idx, table)
     with get_tracer().span("kernel_variant", variant="sparse_c",
-                           epilogue="kernel", block_r=a.block_r):
+                           epilogue="kernel", block_r=a.block_r,
+                           **_mask_attrs(pattern)):
         slabs = pattern.kernel(c_slots, slots, a_idx, values, tiled.tiles,
                                block_r=a.block_r, block_k=a.block_k,
                                bn=tiled.bn, nslabs=int(nslabs),
@@ -368,6 +373,16 @@ def _sparse_c_kernel(pattern: "SpGEMMPattern", values: jax.Array,
                      block_r=a.block_r, bn=tiled.bn)
     _note_kernel_launch("sparse_c", cc=out)
     return out
+
+
+def _mask_attrs(pattern: "SpGEMMPattern") -> dict:
+    """The ``kernel_variant`` span's account of a masked pattern's pairs:
+    those launched and those of the unmasked stream at the same
+    height."""
+    if pattern.mask_pairs is None:
+        return {}
+    kept, unmasked = pattern.mask_pairs
+    return {"masked": True, "pairs": kept, "pairs_unmasked": unmasked}
 
 
 def _sparse_c_xla(pattern: "SpGEMMPattern", values: jax.Array,
@@ -444,7 +459,7 @@ def bcc_spgemm_tiled(pattern: "SpGEMMPattern", values: jax.Array,
     else:
         values, *pairs = to_device(values, *p.pairs)
         with tracer.span("kernel_variant", variant=p.route,
-                         block_r=a.block_r):
+                         block_r=a.block_r, **_mask_attrs(p)):
             out = p.kernel(*pairs, values, tiled.tiles,
                            chunk=stream_chunk(4), **kw)
         _note_kernel_launch(p.route, **pair_kw)
@@ -509,6 +524,12 @@ class SpGEMMPattern:
     over the cores) or ``sparse_c`` (the window-major sparse-C grid).
     ``kernel`` is the Pallas kernel the route launches (each core's, for
     ``sharded``).
+
+    A pattern packed with a mask (``sparse_c`` only) also holds
+    ``mask_pos``, where each nonzero of the mask, in the order it was
+    sent, sits in the flattened sparse-C slabs, and ``mask_pairs``, the
+    lengths of its pruned live-pair stream and of the unmasked one at
+    the same height.
     """
 
     a: BCCShape
@@ -526,6 +547,8 @@ class SpGEMMPattern:
     values_shape: tuple
     tiles_shape: tuple
     tiles_dtype: object
+    mask_pos: jax.Array | None = None
+    mask_pairs: tuple | None = None
 
     def fill(self, a_data, b_data=None) -> tuple[jax.Array, TiledCSR]:
         """A's stream values and B's tiles for one value set, from the
@@ -629,7 +652,7 @@ def select_block_r(counts: dict, *, route: str, nrows: int, block_k: int,
     """
     sparse = route == "sparse_c"
     best_r, best = None, None
-    for r, (steps, pairs, windows) in sorted(counts.items()):
+    for r, (steps, pairs, windows, *_) in sorted(counts.items()):
         if best_r is not None and (
                 route == "padded"
                 or not sparse and not compact_grid_ok_ncols(
@@ -652,7 +675,10 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
                         a_src: np.ndarray | None = None,
                         b_src: np.ndarray | None = None,
                         b_dtype=jnp.float32,
-                        sparse_out: bool = False) -> SpGEMMPattern:
+                        sparse_out: bool = False,
+                        mask: HostCSR | None = None,
+                        mask_src: np.ndarray | None = None
+                        ) -> SpGEMMPattern:
     """Pack ``ap @ bh`` for the Sp×Sp kernels from the patterns alone, and
     choose the route that launches it and A's row-block height.
 
@@ -686,13 +712,24 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
     ``sparse_out=True`` packs for :meth:`SpGEMMPattern.run_sparse`: the
     sparse-C route whatever C's predicted density, unsharded. B must then
     be narrow enough for the compacted grid.
+
+    A ``mask`` (a :class:`HostCSR` of the product's shape, in the
+    operands' order; ``mask_src`` maps its nonzeros to the mask as sent,
+    like ``a_src``) packs the masked product ``(ap @ bh) ∘ mask`` on the
+    sparse-C route: the height is chosen on the counts of the masked
+    stream, the live pairs keep only the C windows that hold a nonzero
+    of the mask before the window-major stream is built, and the
+    pattern records where each nonzero of the mask sits in the slabs
+    (``mask_pos``). Each pack counts its kept and pruned pairs in
+    ``sxs_mask_pairs``.
     """
     bn = _BN
+    sparse_out = sparse_out or mask is not None
     table, tile_cap, b_pos = tiled_layout(bh, block_k, bn)
     tiles_shape = (tile_cap, block_k, bn)
     nnb = (bh.ncols + bn - 1) // bn
     counts = block_r_counts(ap, table, block_k=block_k, nnb=nnb,
-                            heights=_BLOCK_R_HEIGHTS)
+                            heights=_BLOCK_R_HEIGHTS, mask=mask, bn=bn)
     base = _BLOCK_R_HEIGHTS[0]
     resident = (math.prod(tiles_shape) * jnp.dtype(b_dtype).itemsize
                 <= _RESIDENT_B_BUDGET)
@@ -737,8 +774,10 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
                 f"{_SMEM_STREAM_BUDGET} B budget (C row strip of "
                 f"{nnb * bn} columns is too wide for the compacted grid)")
     else:
-        host_pairs = _live_pairs(stream_ids, ntiles, table, nnb=nnb,
-                                 nblocks=nblocks)
+        host_pairs = _live_pairs(
+            stream_ids, ntiles, table, nnb=nnb, nblocks=nblocks,
+            window_live=None if mask is None else mask_windows(
+                mask, block_r=block_r, bn=bn, nblocks=nblocks, nnb=nnb))
         if route == "sharded":
             ranges, shard_pairs = partition_pair_stream(
                 host_pairs, nblocks=nblocks, num_shards=n_shards)
@@ -748,8 +787,22 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
                                                nnb=nnb)
             sparse_pairs = (*to_device(*streams), nslabs)
         pairs = to_device(*host_pairs)
-    obs_metrics.get_registry().counter("sxs_block_r", route=route,
-                                       block_r=str(block_r)).inc()
+    reg = obs_metrics.get_registry()
+    reg.counter("sxs_block_r", route=route, block_r=str(block_r)).inc()
+    mask_pos = mask_pairs = None
+    if mask is not None:
+        kept, unmasked = int(host_pairs[0].size), counts[block_r][3]
+        mask_pairs = (kept, unmasked)
+        reg.counter("sxs_mask_pairs", pairs="kept").inc(kept)
+        reg.counter("sxs_mask_pairs", pairs="pruned").inc(unmasked - kept)
+        pos = compacted_c_positions(
+            streams[3], expand_indptr(mask.indptr), mask.indices,
+            block_r=block_r, bn=bn, nnb=nnb)
+        if mask_src is not None:           # into the order as sent
+            sent = np.empty_like(pos)
+            sent[np.asarray(mask_src)] = pos
+            pos = sent
+        mask_pos, = to_device(int32_positions(pos))
     table, *ids = to_device(table, *stream_ids)
     return SpGEMMPattern(
         a=BCCShape(ap.nrows, ap.ncols, block_r, block_k),
@@ -758,7 +811,16 @@ def pack_spgemm_pattern(ap: HostCSR, bh: HostCSR, *, block_k: int,
         shards=shards, sparse_pairs=sparse_pairs,
         a_map=to_device(*a_map), b_map=to_device(*b_map),
         values_shape=values_shape, tiles_shape=tiles_shape,
-        tiles_dtype=jnp.dtype(b_dtype))
+        tiles_dtype=jnp.dtype(b_dtype), mask_pos=mask_pos,
+        mask_pairs=mask_pairs)
+
+
+def int32_positions(pos: np.ndarray) -> np.ndarray:
+    """Slab positions as int32, the index type of the device gathers."""
+    if pos.size and int(pos.max()) >= 2**31:
+        raise ValueError("sparse-C slab store too large for int32 "
+                         "positions")
+    return pos.astype(np.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -7,7 +7,8 @@ import numpy as np
 
 __all__ = ["cluster_spmm_ref", "cluster_spmm_compact_ref",
            "cluster_spgemm_tiled_ref", "cluster_spgemm_pairs_ref",
-           "cluster_spgemm_pairs_sharded_ref", "flash_attention_ref"]
+           "cluster_spgemm_pairs_sharded_ref", "flash_attention_ref",
+           "masked_spgemm_ref"]
 
 
 def cluster_spmm_ref(tile_ids, a_values, b, *, block_r, block_k,
@@ -126,3 +127,14 @@ def flash_attention_ref(q, k, v, *, causal: bool = True,
         logits = jnp.where(mask, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def masked_spgemm_ref(a, b, mask) -> np.ndarray:
+    """Oracle for the masked product ``(a · b) ∘ mask`` (``b=None``:
+    ``a · a``) of :class:`repro.core.formats.HostCSR` operands: dense
+    float32 ``jax.numpy`` at the highest matmul precision, no kernel, no
+    cache. Returns the dense product, zero off the mask's pattern."""
+    b = a if b is None else b
+    with jax.default_matmul_precision("highest"):
+        c = jnp.asarray(a.to_dense()) @ jnp.asarray(b.to_dense())
+        return np.asarray(c * jnp.asarray(mask.to_dense()))

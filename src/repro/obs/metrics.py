@@ -79,6 +79,9 @@ _HOST_METRICS: dict[str, tuple[str, str]] = {
     "sxs_block_r": (
         "counter", "Sp×Sp pattern packs, by route and the row-block height "
         "chosen for the pattern, block_r label (count)"),
+    "sxs_mask_pairs": (
+        "counter", "live pairs of masked Sp×Sp packs, kept or pruned by the "
+        "mask (pairs label), counted once a pack (count)"),
     "chain_hops": (
         "counter", "chain-workload hops executed (count)"),
     "chain_carry_bytes": (

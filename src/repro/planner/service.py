@@ -192,6 +192,12 @@ def _apply_plan_perm(a: HostCSR, plan: Plan, *, symmetric: bool) -> HostCSR:
 # sparse-C route returns C sparse
 _CHAIN_DENSE_C_BUDGET = 256 * 2**20
 
+# the largest dense C (rows × columns × 4 B) a masked product may be read
+# from when it cannot run on the sparse-C tier: the dense product lives
+# on the device beside the operands and again on the host, and a masked
+# product of a graph's size would not fit (n = 65,536 is 16 GiB)
+_MASKED_DENSE_C_BUDGET = 2**30
+
 
 def chain_order(shapes: Sequence[tuple], nnzs: Sequence[int]):
     """The association of the product ``M₀·M₁·…·M_m`` from the operands'
@@ -255,6 +261,11 @@ def _take(slabs, positions):
     return slabs.reshape(-1)[positions]
 
 
+@jax.jit
+def _masked_take(slabs, positions, mask_values):
+    return slabs.reshape(-1)[positions] * mask_values
+
+
 @dataclasses.dataclass(frozen=True)
 class _ProductPattern:
     """The symbolic pattern of a sparse-C hop's product, in the original
@@ -286,9 +297,6 @@ class _ProductPattern:
         rows = blk[w].astype(np.int64) * cc.block_r + rr
         cols = j[w].astype(np.int64) * cc.bn + col
         pos = (slab[w] * cc.block_r + rr) * cc.bn + col
-        if pos.size and pos[-1] >= 2**31:
-            raise ValueError("sparse-C slab store too large for int32 "
-                             "positions")
         if perm is not None:
             p = np.asarray(perm, dtype=np.int64)
             rows = p[rows]
@@ -298,13 +306,26 @@ class _ProductPattern:
         order = np.lexsort((cols, rows))
         indptr = np.zeros(nrows + 1, np.int64)
         np.cumsum(np.bincount(rows, minlength=nrows), out=indptr[1:])
-        positions, = to_device(pos[order].astype(np.int32))
+        positions, = to_device(kernel_ops.int32_positions(pos[order]))
         return cls(indptr, cols[order].astype(np.int32), (nrows, ncols),
                    positions)
 
-    def take(self, cc) -> jax.Array:
-        """The product's nonzeros from its slabs, on the device."""
-        return _take(cc.slabs, self.positions)
+    @classmethod
+    def of_mask(cls, pattern, mask: HostCSR) -> "_ProductPattern":
+        """The masked product's pattern: the mask's own, in the order it
+        was sent, at the slab positions the pack recorded for it
+        (``SpGEMMPattern.mask_pos``); nothing runs."""
+        return cls(np.array(mask.indptr), np.array(mask.indices),
+                   mask.shape, pattern.mask_pos)
+
+    def take(self, cc, mask_values: Optional[jax.Array] = None
+             ) -> jax.Array:
+        """The product's nonzeros from its slabs, on the device; times
+        ``mask_values`` entry by entry when given (one jitted gather and
+        multiply)."""
+        if mask_values is None:
+            return _take(cc.slabs, self.positions)
+        return _masked_take(cc.slabs, self.positions, mask_values)
 
 
 class _DeviceCSR:
@@ -1075,6 +1096,83 @@ class Planner:
         self.auditor.record(plan, kernel_s)
         return _DeviceCSR(vals, product)
 
+    # -- masked products (submit(a, Masked(b, mask))) ------------------------
+
+    def execute_masked(self, plan: Plan, a: HostCSR, b: Optional[HostCSR],
+                       mask: HostCSR) -> HostCSR:
+        """The masked product ``(a · (b or a)) ∘ mask``: the product's
+        values at the nonzeros of ``mask`` times ``mask``'s values, as a
+        :class:`HostCSR` on exactly ``mask``'s pattern (explicit zeros
+        where no product term lands). ``plan`` is ``a``'s Sp×Sp plan; its
+        permutation applies to the mask as to the product.
+
+        A Pallas plan runs the sparse-C tier on an exec entry of its own
+        (:meth:`_pattern_slot` with the mask): the pack keeps only the
+        live pairs of C windows that hold a nonzero of the mask, the
+        kernel writes only those windows, and one jitted op gathers the
+        values at the mask's positions and multiplies them by its values
+        on the device (the ``mask`` span); only ``nnz(mask)`` values come
+        back. Other plans, a B too wide for the sparse-C grid, and — with
+        the ladder armed — a failing sparse-C launch take the dense
+        product of :meth:`execute` and read the mask's entries from it,
+        where that dense C is within ``_MASKED_DENSE_C_BUDGET``; a wider
+        one is refused (``ValueError``), or the launch's own error
+        raised, rather than run out of memory.
+        """
+        policy = self.resilience
+        dense_fits = 4 * mask.nrows * mask.ncols <= _MASKED_DENSE_C_BUDGET
+        if plan.scheme == "pallas" and kernel_ops.compact_grid_ok_ncols(
+                (a if b is None else b).ncols):
+            try:
+                return self._masked_sparse(plan, a, b, mask)
+            except Exception as e:       # noqa: BLE001 — ladder catches all
+                if not (policy.ladder and dense_fits):
+                    raise
+                policy.breaker.record_failure(policy.triple(
+                    plan.fingerprint, plan.scheme, plan.reorder))
+                policy.record_incident(
+                    fingerprint=plan.fingerprint, workload=plan.workload,
+                    scheme=plan.scheme, reorder=plan.reorder,
+                    site=self._classify_failure(e), error=e,
+                    fallback="dense_route")
+                obs_metrics.get_registry().counter(
+                    "serve_fallbacks", scheme=plan.scheme).inc()
+        elif not dense_fits:
+            raise ValueError(
+                f"masked product of shape {mask.shape} off the sparse-C "
+                f"tier would read a dense C of {4 * mask.nrows * mask.ncols}"
+                f" B, over the {_MASKED_DENSE_C_BUDGET} B budget")
+        dense = self.execute(plan, a, b)
+        rows = np.repeat(np.arange(mask.nrows), mask.row_nnz())
+        return HostCSR(mask.indptr, mask.indices,
+                       dense[rows, mask.indices] * mask.data, mask.shape)
+
+    def _masked_sparse(self, plan: Plan, a: HostCSR, b: Optional[HostCSR],
+                       mask: HostCSR) -> HostCSR:
+        """:meth:`execute_masked` on the sparse-C tier."""
+        tracer = get_tracer()
+        with tracer.span("execute", fingerprint=plan.fingerprint,
+                         scheme=plan.scheme, reorder=plan.reorder,
+                         workload="masked"):
+            entry, slot, _ = self._pattern_slot(plan, a, b, mask=mask)
+            pattern, product = entry[1], entry[3]
+            _, values, tiled, mask_values = slot
+            with tracer.span("kernel", scheme=plan.scheme,
+                             variant="sparse_c"):
+                t0 = time.perf_counter()
+                cc = pattern.run_sparse(values, tiled)
+                with tracer.span("sync"):
+                    cc = jax.block_until_ready(cc)
+                kernel_s = time.perf_counter() - t0
+            self.auditor.record(plan, kernel_s)
+            with tracer.span("mask"):
+                vals = product.take(cc, mask_values)
+                if tracer.enabled:
+                    jax.block_until_ready(vals)
+            out = _DeviceCSR(vals, product).to_host()
+            self._check_finite(plan, out.data)
+        return out
+
     def _build_runner(self, plan: Plan, a: HostCSR,
                       b: HostCSR | np.ndarray | None):
         dense_b = isinstance(b, np.ndarray) or (
@@ -1196,7 +1294,8 @@ class Planner:
                                 plan.perm, rows_only=b is not None)
 
     def _pattern_slot(self, plan: Plan, a: HostCSR, b: HostCSR | None, *,
-                      sparse_out: bool = False) -> tuple:
+                      sparse_out: bool = False,
+                      mask: Optional[HostCSR] = None) -> tuple:
         """The exec entry of the Pallas product ``a · (b or a)`` and the
         value slot holding ``a``'s and ``b``'s values on the device.
 
@@ -1211,40 +1310,69 @@ class Planner:
         kernel. A request builds its own slot and launches on it, so
         concurrent value sets never mix. ``sparse_out`` packs for the
         sparse-C tier (a chain hop's entry, apart from the dense one) and
-        adds the product's :class:`_ProductPattern`.
+        adds the product's :class:`_ProductPattern`. A ``mask`` packs the
+        masked product ``(a · b) ∘ mask`` (:meth:`execute_masked`) on an
+        entry of its own, also keyed by the mask's fingerprint, whose
+        product pattern is the mask's; its value slot also holds the
+        mask's values on the device, the very array of ``a``'s values
+        when the two are equal.
 
         Returns ``(entry, slot, filled)``, ``filled`` true when this call
         packed or refilled."""
         squared = b is None
-        kind = "chain" if sparse_out else "pallas"
-        ck = (f"{plan.fingerprint}|{_plan_digest(plan)}|"
-              + ("chain|" if sparse_out else "")
+        a_vd = _value_digest(a)
+        if mask is not None:
+            kind, pack_kind = "masked", "masked"
+            tag = "masked|" + (plan.fingerprint if mask is a
+                               else fingerprint(mask)) + "|"
+        elif sparse_out:
+            kind, pack_kind, tag = "chain", "sparse_c", "chain|"
+        else:
+            kind, pack_kind, tag = "pallas", "sq" if squared else "ab", ""
+        ck = (f"{plan.fingerprint}|{_plan_digest(plan)}|{tag}"
               + ("sq" if squared else f"ab|{fingerprint(b)}"))
-        vk = (_value_digest(a) if squared
-              else f"{_value_digest(a)}|{_value_digest(b)}")
+        vk = a_vd if squared else f"{a_vd}|{_value_digest(b)}"
+        if mask is not None:
+            m_vd = a_vd if mask is a else _value_digest(mask)
+            vk = f"{vk}|m{m_vd}"
         b_data = None if squared else b.data
         tracer = get_tracer()
+
+        def fill(pattern) -> tuple:
+            if mask is None:
+                return (vk, *pattern.fill(a.data, b_data))
+            if m_vd == a_vd:               # one upload serves both
+                a_dev, = to_device(a.data)
+                return (vk, *pattern.fill(a_dev, b_data), a_dev)
+            return (vk, *pattern.fill(a.data, b_data),
+                    *to_device(mask.data))
+
         entry = self._exec_cache.get(ck)
         if entry is None:
             with tracer.span("pack", fingerprint=plan.fingerprint,
-                             scheme=plan.scheme,
-                             kind=("sparse_c" if sparse_out
-                                   else "sq" if squared else "ab")):
+                             scheme=plan.scheme, kind=pack_kind):
                 _faults.maybe_fault("pack")
                 ap, src = a, None
+                mp, msrc = mask, None
                 if plan.perm is not None:
                     ap, src = a.permuted(plan.perm, symmetric=squared)
+                    if mask is not None:
+                        mp, msrc = mask.permuted(plan.perm,
+                                                 symmetric=squared)
                 bh = ap if squared else b
                 pattern = kernel_ops.pack_spgemm_pattern(
                     ap, bh, block_k=select_block_k(bh), a_src=src,
                     b_src=src if squared else None,
-                    b_dtype=self.pallas_b_dtype, sparse_out=sparse_out)
+                    b_dtype=self.pallas_b_dtype, sparse_out=sparse_out,
+                    mask=mp, mask_src=msrc)
                 # a list: the value slot is replaced in place
                 entry = [kind, pattern, None]
-                if sparse_out:
+                if mask is not None:
+                    entry.append(_ProductPattern.of_mask(pattern, mask))
+                elif sparse_out:
                     entry.append(_ProductPattern.of(
                         pattern, a, None if squared else b, plan.perm))
-                slot = (vk, *pattern.fill(a.data, b_data))
+                slot = fill(pattern)
                 entry[2] = slot
                 self._exec_put(ck, entry)
             self._note_pack()
@@ -1254,10 +1382,10 @@ class Planner:
             self._note_hit(entry[0])
             return entry, slot, False
         # the old values leave the device before the new ones arrive
-        entry[2] = (None, None, None)
+        entry[2] = (None,) * len(slot)
         with tracer.span("pack", fingerprint=plan.fingerprint,
                          scheme=plan.scheme, kind="refill"):
-            slot = (vk, *pattern.fill(a.data, b_data))
+            slot = fill(pattern)
         entry[2] = slot
         obs_metrics.get_registry().counter("exec_cache_refills").inc()
         return entry, slot, True

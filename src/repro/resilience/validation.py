@@ -109,8 +109,9 @@ def validate_request_pair(a, b=None, *, skip=None) -> None:
     """The :meth:`SpGEMMServer.submit` boundary check: ``a`` (always a
     sparse CSR), plus ``b`` when present — a second CSR (shape-chained),
     a tuple or list of CSR operands (a chain ``a · b[0] · … · b[-1]``,
-    each checked and the whole shape chain with it) or a dense SpMM
-    operand.
+    each checked and the whole shape chain with it), a masked product's
+    ``Masked(b, mask)`` (its ``b``, or ``a`` again when ``None``, and a
+    CSR ``mask`` of the product's shape) or a dense SpMM operand.
 
     ``skip`` is an optional ``obj -> bool`` predicate (the policy's
     validation memo): a True return skips that object's O(nnz) content
@@ -121,6 +122,30 @@ def validate_request_pair(a, b=None, *, skip=None) -> None:
     if skip is None or not skip(a):
         validate_host_csr(a, "a")
     if b is None:
+        return
+    if hasattr(b, "mask"):              # Masked(b, mask)
+        inner, mask = b.b, b.mask
+        if inner is None and a.shape[0] != a.shape[1]:
+            raise InvalidOperandError(
+                "shape", "a masked square needs a square a", shape=a.shape)
+        for m, name in ((inner, "b"), (mask, "mask")):
+            if m is None or m is a:
+                continue
+            if not hasattr(m, "indptr"):
+                raise InvalidOperandError(
+                    "shape", f"a masked product's {name} must be a CSR "
+                    "operand")
+            if skip is None or not skip(m):
+                validate_host_csr(m, name)
+        if inner is not None and a.shape[1] != inner.shape[0]:
+            raise InvalidOperandError(
+                "shape", "a.ncols must equal b.nrows",
+                a_ncols=a.shape[1], b_nrows=inner.shape[0])
+        want = (a.shape[0], (a if inner is None else inner).shape[1])
+        if tuple(mask.shape) != want:
+            raise InvalidOperandError(
+                "shape", "mask must have the product's shape",
+                expected=want, got=tuple(mask.shape))
         return
     if isinstance(b, (tuple, list)):    # a chain of CSR operands
         if not b or not all(hasattr(m, "indptr") for m in b):

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.formats import HostCSR
+from repro.core.formats import HostCSR, Masked
 from repro.models.transformer import decode_step, init_cache, prefill
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import get_tracer
@@ -33,7 +33,7 @@ from repro.planner.service import Planner
 from repro.resilience.errors import InvalidOperandError
 from repro.resilience.validation import validate_request_pair
 
-__all__ = ["make_serve_step", "ServingEngine", "SpGEMMServer"]
+__all__ = ["make_serve_step", "ServingEngine", "SpGEMMServer", "Masked"]
 
 
 def make_serve_step(cfg, *, sample: bool = False,
@@ -58,11 +58,11 @@ def make_serve_step(cfg, *, sample: bool = False,
 
 @dataclasses.dataclass
 class SpGEMMResponse:
-    result: "np.ndarray | HostCSR"  # HostCSR for chain requests (sparse C)
+    result: "np.ndarray | HostCSR"  # HostCSR for chain and masked requests
     fingerprint: str
     reorder: str
     scheme: str
-    workload: str              # a2 | spmm | chain — planned kernel family
+    workload: str              # a2 | spmm | chain | masked
     kernel_path: str           # "pallas" (MXU tiled kernel) or "xla"
     plan_cache_hit: bool
     plan_s: float              # planning + preprocessing wall time (0-ish on hit)
@@ -106,7 +106,7 @@ class SpGEMMServer:
         self.plan_hits = 0
 
     def submit(self, a: HostCSR,
-               b: HostCSR | np.ndarray | tuple | None = None, *,
+               b: HostCSR | np.ndarray | tuple | Masked | None = None, *,
                reuse_hint: Optional[int] = None,
                hops: Optional[int] = None) -> SpGEMMResponse:
         """Plan (or fetch the cached plan for) ``a``, then execute a·b.
@@ -129,6 +129,14 @@ class SpGEMMServer:
         ``plan_cache_hit`` true only when *every* hop hit the cache (the
         steady serving state for a recurring chain) and ``kernel_path``
         ``"pallas"`` only when every hop was planned on it.
+
+        :class:`~repro.core.formats.Masked` as ``b`` asks for the masked
+        product ``(a · b.b) ∘ b.mask`` (``b.b=None``: ``a · a``), the
+        kernel of triangle counting, under workload ``"masked"``: it is
+        planned as the Sp×Sp product it is (``"a2"``) and run by
+        :meth:`repro.planner.service.Planner.execute_masked`, which
+        computes only the C windows the mask reads; ``result`` is a
+        :class:`HostCSR` on exactly the mask's pattern.
 
         Each request runs under a ``request`` span, after a ``validate``
         span for the operand checks (its trace id is returned as
@@ -160,6 +168,7 @@ class SpGEMMServer:
             raise ValueError("chain requests take b=None (A^k workload)")
         workload = ("chain" if hops is not None
                     or isinstance(b, (tuple, list))
+                    else "masked" if isinstance(b, Masked)
                     else "spmm" if (b is not None
                                     and not isinstance(b, HostCSR))
                     else "a2")
@@ -179,7 +188,9 @@ class SpGEMMServer:
                                 field=e.field).inc()
                     raise
                 policy.mark_validated(a)
-                for m in (b if isinstance(b, (tuple, list)) else (b,)):
+                for m in (b if isinstance(b, (tuple, list))
+                          else (b.b, b.mask) if isinstance(b, Masked)
+                          else (b,)):
                     if hasattr(m, "indptr"):
                         policy.mark_validated(m)
         with tracer.span("request", tenant=self.tenant,
@@ -225,9 +236,13 @@ class SpGEMMServer:
                                  if degraded else ""))
         t0 = time.perf_counter()
         plan = self.planner.plan(a, hint, measure=self.measure,
-                                 workload=workload)
+                                 workload="a2" if workload == "masked"
+                                 else workload)
         t1 = time.perf_counter()
-        out = jax.block_until_ready(self.planner.execute(plan, a, b))
+        if workload == "masked":
+            out = self.planner.execute_masked(plan, a, b.b, b.mask)
+        else:
+            out = jax.block_until_ready(self.planner.execute(plan, a, b))
         t2 = time.perf_counter()
         if plan.from_cache:
             self.plan_hits += 1

@@ -58,7 +58,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from repro.core.formats import HostCSR
+from repro.core.formats import HostCSR, Masked
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import get_tracer
 from repro.planner.features import fingerprint
@@ -501,11 +501,22 @@ class AsyncSpGEMMServer:
 
     def _coalesce_key(self, fp: str, a, b, hops) -> str:
         """Identity key for single-flight result sharing: pattern AND
-        values of every operand — each of a chain's ``b`` too — plus the
-        workload shape. Requests that differ only in values share
-        plan/pack through the planner's caches instead."""
+        values of every operand — each of a chain's ``b`` and a masked
+        product's mask too — plus the workload shape. Requests that
+        differ only in values share plan/pack through the planner's
+        caches instead."""
         try:
-            if b is None:
+            a_vd = _value_digest(a)
+
+            def part(m) -> str:
+                if m is None:
+                    return "sq"
+                if m is a:
+                    return f"{fp}|{a_vd}"
+                return f"{fingerprint(m)}|{_value_digest(m)}"
+            if isinstance(b, Masked):
+                bpart = f"masked|{part(b.b)}|{part(b.mask)}"
+            elif b is None:
                 bpart = f"sq|h{hops if hops is not None else 0}"
             elif isinstance(b, HostCSR):
                 bpart = f"csr|{fingerprint(b)}|{_value_digest(b)}"
@@ -520,7 +531,7 @@ class AsyncSpGEMMServer:
                 bpart = f"dense|{d.hexdigest()}"
         except Exception:                     # un-digestable operand:
             return ""                         # never coalesce, still serve
-        return f"{fp}|{_value_digest(a)}|{bpart}"
+        return f"{fp}|{a_vd}|{bpart}"
 
     # -- calibration refresh -------------------------------------------------
 

@@ -13,6 +13,7 @@ import scipy.sparse as sp
 from repro.core import formats
 from repro.core.formats import (HostCSR, block_r_counts, select_block_k,
                                 tiled_layout)
+from repro.core.segment import rank_in_segment
 from repro.core.suite import gen_kron
 from repro.kernels import ops
 from repro.obs.metrics import get_registry
@@ -44,12 +45,13 @@ def _picks(a, b, block_k):
             for route in ("streamed", "sparse_c")]
 
 
-@pytest.mark.parametrize("fn", [formats.block_r_counts, ops.select_block_r],
+@pytest.mark.parametrize("fn", [formats.block_r_counts, ops.select_block_r,
+                                formats.compacted_c_positions],
                          ids=lambda f: f.__name__)
 def test_docstring_examples(fn):
     runner = doctest.DocTestRunner()
     for test in doctest.DocTestFinder().find(fn, globs=vars(
-            formats if fn is formats.block_r_counts else ops)):
+            ops if fn is ops.select_block_r else formats)):
         runner.run(test)
     result = runner.summarize(verbose=False)
     assert result.attempted > 0 and result.failed == 0
@@ -203,3 +205,73 @@ def test_pack_records_and_reports_the_height(g, traced):
     variant, = [s for s in traced.spans() if s.name == "kernel_variant"]
     assert variant.attrs["block_r"] == g
     np.testing.assert_array_equal(got, scipy_product(a, b))
+
+
+def _masked_case(name):
+    """(A, B, mask, block_k, bn) of a masked product whose streams are
+    counted: the mask's windows cut some pairs at every height."""
+    if name == "kron_lower":
+        # A·A reaches every window; its lower triangle, none above
+        a = gen_kron(9, edge_factor=8, seed=2)
+        low = _host(sp.tril(sp.csr_matrix((a.data, a.indices, a.indptr),
+                                          shape=a.shape)))
+        return a, a, low, 128, 128
+    if name == "rect":
+        return (_rect(90, 200, 0.04, 1), _rect(200, 70, 0.05, 2),
+                _rect(90, 70, 0.02, 12), 16, 16)
+    if name == "empty_blocks":
+        mask = _rect(200, 50, 0.01, 13).to_dense()
+        mask[100:] = 0.0
+        return (_with_empty_blocks(), _rect(300, 50, 0.1, 5),
+                HostCSR.from_dense(mask), 32, 16)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", ["kron_lower", "rect", "empty_blocks"])
+def test_masked_counts_equal_the_pruned_streams_at_every_height(case):
+    """With a mask, the counts are the lengths of the live-pair stream a
+    masked pack builds (only the pairs of windows holding a nonzero of
+    the mask) and the windows it reaches, never more than unmasked."""
+    a, b, mask, block_k, bn = _masked_case(case)
+    table = tiled_layout(b, block_k, bn)[0]
+    nnb = (b.ncols + bn - 1) // bn
+    full = block_r_counts(a, table, block_k=block_k, nnb=nnb)
+    counts = block_r_counts(a, table, block_k=block_k, nnb=nnb, mask=mask,
+                            bn=bn)
+    assert any(counts[r][1] < full[r][1] for r in HEIGHTS)
+    for r, (steps, pairs, windows, unmasked) in counts.items():
+        assert steps == full[r][0] and pairs <= full[r][1]
+        assert unmasked == full[r][1]
+        ids, ntiles, _, _ = ops._compact_layout(a, r, block_k)
+        nblocks = ntiles.shape[0]
+        live = formats.mask_windows(mask, block_r=r, bn=bn,
+                                    nblocks=nblocks, nnb=nnb)
+        stream = ops._live_pairs(ids, ntiles, table, nnb=nnb,
+                                 nblocks=nblocks, window_live=live)
+        ref = formats.live_pair_stream_reference(
+            np.asarray(ids[0]), np.asarray(ids[1]), table, nnb=nnb,
+            nblocks=nblocks, window_live=live,
+            step_live=rank_in_segment(ids[0].astype(np.int64))
+            < ntiles[ids[0]])
+        for x, y in zip(stream, ref):
+            np.testing.assert_array_equal(x, y)
+        assert pairs == stream[0].size, r
+        assert windows == round(ops.predict_c_window_density(
+            stream, nblocks=nblocks, nnb=nnb) * nblocks * nnb), r
+
+
+def test_masked_pack_chooses_its_height_on_the_masked_counts():
+    """A masked pack takes the sparse-C route at the height
+    ``select_block_r`` gives the masked counts, and records the lengths
+    of its stream and of the unmasked one at that height."""
+    a, b, mask, block_k, bn = _masked_case("kron_lower")
+    table = tiled_layout(b, block_k, bn)[0]
+    nnb = (b.ncols + bn - 1) // bn
+    counts = block_r_counts(a, table, block_k=block_k, nnb=nnb, mask=mask)
+    want = ops.select_block_r(counts, route="sparse_c", nrows=a.nrows,
+                              block_k=block_k, nnb=nnb)
+    pattern = ops.pack_spgemm_pattern(a, b, block_k=block_k, mask=mask)
+    assert pattern.route == "sparse_c" and pattern.a.block_r == want
+    unmasked = block_r_counts(a, table, block_k=block_k, nnb=nnb)
+    assert pattern.mask_pairs == (counts[want][1], unmasked[want][1])
+    assert counts[want][3] == unmasked[want][1]

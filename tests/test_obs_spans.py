@@ -216,6 +216,48 @@ def test_transfer_bytes_of_the_a2_pattern_pack(traced):
     assert _bytes(spans, "fetch", "kernel") == resp.result.nbytes
 
 
+def test_masked_path_spans_and_counter(traced):
+    """A masked square ``submit(L, Masked(None, L))``: the pack counts its
+    kept and pruned pairs in ``sxs_mask_pairs``; a request with new
+    values uploads L's values once (the mask's are the same array),
+    launches with ``masked``, ``pairs`` and ``pairs_unmasked`` on its
+    ``kernel_variant`` span, gathers and multiplies under ``mask``, and
+    fetches nnz(L) values."""
+    from repro.core.formats import Masked
+    from repro.obs.metrics import get_registry
+    d = _graph(n=300, density=0.02).to_dense()
+    low = HostCSR.from_dense(np.tril(d + d.T) + np.eye(300, dtype=np.float32))
+    reg = get_registry()
+    reg.reset()
+    srv = _server(low, "a2")
+    try:
+        srv.submit_wait(low, Masked(None, low), reuse_hint=HINT)
+        snap = reg.snapshot()
+        kept = snap[reg._key("sxs_mask_pairs", {"pairs": "kept"})]
+        pruned = snap[reg._key("sxs_mask_pairs", {"pairs": "pruned"})]
+        pattern = next(iter(srv.server.planner._exec_cache.values()))[1]
+        assert (kept, kept + pruned) == pattern.mask_pairs
+        traced.clear()
+        sent = _revalued(low, 4)
+        resp = srv.submit_wait(sent, Masked(None, sent), reuse_hint=HINT)
+    finally:
+        srv.close()
+    spans = traced.spans()
+    assert resp.workload == "masked"
+    kv, = [s for s in spans if s.name == "kernel_variant"]
+    assert kv.attrs["masked"] is True
+    assert (kv.attrs["pairs"], kv.attrs["pairs_unmasked"]) \
+        == pattern.mask_pairs
+    mask, = [s for s in spans if s.name == "mask"]
+    by_id = {s.span_id: s for s in spans}
+    assert by_id[mask.parent_id].name == "execute"
+    assert _bytes(spans, "upload") == _bytes(spans, "upload", "pack") \
+        == low.nnz * 4
+    assert _bytes(spans, "fetch") == low.nnz * 4
+    assert reg.snapshot()[reg._key("sxs_mask_pairs", {"pairs": "kept"})] \
+        == kept
+
+
 def test_fresh_jit_compiles_once_in_a_trace_of_its_own(traced):
     def triple_plus_one(v):
         return v * 3.0 + 1.0
